@@ -55,6 +55,7 @@ def main() -> None:
     selected = (args.only.split(",") if args.only else list(suites))
 
     rows: list = []
+    failed: list = []
     print("name,us_per_call,derived")
     for name in selected:
         t0 = time.time()
@@ -63,9 +64,12 @@ def main() -> None:
             suites[name](rows)
             for r in rows[start:]:
                 print(",".join(str(x) for x in r), flush=True)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - report, run the rest, fail
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
         print(f"# {name} done in {time.time() - t0:.0f}s", file=sys.stderr)
+    if failed:
+        sys.exit(f"{len(failed)} suite(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

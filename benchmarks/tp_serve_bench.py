@@ -1,16 +1,20 @@
 """Tensor-parallel serving benchmark -> BENCH_tp_serve.json.
 
 Runs the W4 GQA serving workload on the continuous engine at TP=1/2/4 over
-a forced 4-device CPU host mesh: measured tokens/s per width (orientation
-only on CPU — four virtual devices share the same socket and the psums are
-memcpys, so TP *costs* time here), greedy-token identity asserted against
+the first four devices: measured tokens/s per width (orientation only on
+CPU — four virtual devices share the same socket and the psums are
+memcpys, so TP *costs* time there), greedy-token identity asserted against
 TP=1, and the deployment story the placement actually buys: per-device
 bytes for packed weights and KV pools from the live buffer shardings —
 on a real mesh that is the per-device HBM footprint, which is what lets a
 norm-tweaked W4 checkpoint of a model N x too big for one device serve at
 all (the paper's low-bit deployment regime at scale).
 
-    PYTHONPATH=src:. python benchmarks/tp_serve_bench.py
+It runs on whatever devices JAX finds (four are needed). The CPU
+rehearsal gives the CPU backend four virtual devices:
+
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        PYTHONPATH=src:. python benchmarks/tp_serve_bench.py
 """
 from __future__ import annotations
 
@@ -18,19 +22,12 @@ import json
 import os
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (_flags +
-                               " --xla_force_host_platform_device_count=4"
-                               ).strip()
+import jax
+import numpy as np
 
-import jax                                                    # noqa: E402
-import numpy as np                                            # noqa: E402
-
-from repro.configs import TINY                                # noqa: E402
-from repro.models.transformer import init_lm                  # noqa: E402
-from repro.serve.engine import ContinuousEngine               # noqa: E402
+from repro.configs import TINY
+from repro.models.transformer import init_lm
+from repro.serve.engine import ContinuousEngine
 
 N_SLOTS = 4
 N_REQUESTS = 12
@@ -78,7 +75,7 @@ def run_engine(cfg, params, work, tp):
 
 
 def main():
-    assert len(jax.devices()) >= 4, "needs XLA-forced 4 CPU devices"
+    assert len(jax.devices()) >= 4, "needs four devices (see the docstring)"
     cfg = make_cfg()
     params = init_lm(cfg, jax.random.PRNGKey(0))
     work = make_workload(cfg)

@@ -133,6 +133,19 @@ def awq_block(block: dict, taps: dict, *, bits: int,
     return block
 
 
+def host_cpu() -> jax.Device:
+    """The host CPU device. GPTQ factors its K x K Hessians there: the
+    dense Cholesky and triangular solve need several K x K buffers of
+    scratch, which on an accelerator compete with the model being
+    calibrated for device memory, and on the host only with host memory."""
+    try:
+        return jax.local_devices(backend="cpu")[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "GPTQ factors its Hessians on the host CPU backend, which this "
+            "JAX process does not have: add 'cpu' to JAX_PLATFORMS") from e
+
+
 def quantize_block(block: dict, taps: Optional[dict], *, method: str = "gptq",
                    bits: int = 4, group_size: int = -1, act_bits: int = 0,
                    alpha: float = 0.5, damp: float = 0.01,
@@ -161,7 +174,7 @@ def quantize_block(block: dict, taps: Optional[dict], *, method: str = "gptq",
                 h = hessian_from_inputs(x)
             qt, _ = gptq_quantize(w, h, bits=bits, group_size=group_size,
                                   damp=damp, actorder=actorder,
-                                  act_bits=act_bits)
+                                  act_bits=act_bits, factor_device=host_cpu())
         else:  # rtn | smoothquant (weights via RTN after folding)
             qt = rtn_quantize(w, bits=bits, group_size=group_size,
                               act_bits=act_bits)

@@ -24,17 +24,35 @@ def hessian_from_inputs(x: jax.Array) -> jax.Array:
     return 2.0 * x2.T @ x2
 
 
-def _upper_cholesky(a: jax.Array) -> jax.Array:
-    """U upper-triangular with a = Uᵀ U:  a = L Lᵀ  =>  U = Lᵀ."""
-    return jnp.linalg.cholesky(a).T
+@jax.jit
+def _inverse_upper_cholesky(h: jax.Array) -> jax.Array:
+    """U upper-triangular with h⁻¹ = Uᵀ U (batched over leading dims),
+    without forming h⁻¹: with P the index reversal and P h P = L Lᵀ,
+    h⁻¹ = (P L⁻ᵀ P)(P L⁻¹ P), and P L⁻¹ P is upper-triangular."""
+    k = h.shape[-1]
+    l_rev = jnp.linalg.cholesky(h[..., ::-1, ::-1])
+    eye = jnp.broadcast_to(jnp.eye(k, dtype=h.dtype), h.shape)
+    l_inv = jax.lax.linalg.triangular_solve(l_rev, eye, left_side=True,
+                                            lower=True)
+    return l_inv[..., ::-1, ::-1]
+
+
+def _factor(hf: jax.Array, device) -> jax.Array:
+    """`_inverse_upper_cholesky` on `device` (None: where hf lives), U
+    returned where hf lives."""
+    if device is None:
+        return _inverse_upper_cholesky(hf)
+    u = _inverse_upper_cholesky(jax.device_put(hf, device))
+    return jax.device_put(u, hf.sharding)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "group_size", "actorder"))
-def gptq_quantize_array(w: jax.Array, h: jax.Array, *, bits: int,
-                        group_size: int = -1, damp: float = 0.01,
-                        actorder: bool = False):
-    """Returns (q int32 (K,N) on the symmetric grid, scale (G,N), err)."""
-    k, n = w.shape
+def _gptq_setup(w: jax.Array, h: jax.Array, *, bits: int, group_size: int,
+                damp: float, actorder: bool):
+    """Everything before the factorization: dead inputs pinned, group
+    scales from the original weights, act-order permutation, damping.
+    Returns (dead-zeroed f32 w, scale (G, N), perm, damped permuted H)."""
+    k = w.shape[0]
     wf = w.astype(jnp.float32)
     hf = h.astype(jnp.float32)
 
@@ -46,22 +64,22 @@ def gptq_quantize_array(w: jax.Array, h: jax.Array, *, bits: int,
 
     # static group scales from the original weights
     scale = compute_scales(wf, bits, group_size)                  # (G, N)
-    g = scale.shape[0]
-    rows_per_g = k // g
-    row_scale = jnp.repeat(scale, rows_per_g, axis=0)             # (K, N)
 
     perm = jnp.argsort(-jnp.diag(hf)) if actorder else jnp.arange(k)
-    inv_perm = jnp.argsort(perm)
-    wf = wf[perm]
-    row_scale_p = row_scale[perm]
     hf = hf[perm][:, perm]
-
     mean_diag = jnp.mean(jnp.diag(hf))
-    hf = hf + damp * mean_diag * jnp.eye(k)
+    return wf, scale, perm, hf + damp * mean_diag * jnp.eye(k)
 
-    hinv = jnp.linalg.inv(hf)
-    u = _upper_cholesky(hinv)                                     # (K, K)
 
+@functools.partial(jax.jit, static_argnames=("bits",))
+def _gptq_sweep(wf: jax.Array, scale: jax.Array, perm: jax.Array,
+                u: jax.Array, *, bits: int):
+    """Row-serial quantization in `perm` order, each row's rounding error
+    propagated to the later rows through U. Returns (q int32 (K, N) in
+    the original row order, mse against wf)."""
+    k, n = wf.shape
+    row_scale = jnp.repeat(scale, k // scale.shape[0], axis=0)    # (K, N)
+    row_scale_p = row_scale[perm]
     qmax = qmax_for_bits(bits)
     rows = jnp.arange(k)
 
@@ -79,30 +97,46 @@ def gptq_quantize_array(w: jax.Array, h: jax.Array, *, bits: int,
                                                    i, 0)
         return wbuf, qbuf
 
-    _, qbuf = jax.lax.fori_loop(0, k, body, (wf, jnp.zeros((k, n), jnp.int32)))
-    qbuf = qbuf[inv_perm]
-
+    _, qbuf = jax.lax.fori_loop(0, k, body,
+                                (wf[perm], jnp.zeros((k, n), jnp.int32)))
+    qbuf = qbuf[jnp.argsort(perm)]
     deq = qbuf.astype(jnp.float32) * row_scale
-    err = jnp.mean((deq - jnp.where(dead[:, None], 0.0, w.astype(jnp.float32))) ** 2)
-    return qbuf, scale, err
+    return qbuf, jnp.mean((deq - wf) ** 2)
+
+
+def gptq_quantize_array(w: jax.Array, h: jax.Array, *, bits: int,
+                        group_size: int = -1, damp: float = 0.01,
+                        actorder: bool = False, factor_device=None):
+    """Returns (q int32 (K,N) on the symmetric grid, scale (G,N), err).
+    `factor_device`: where the Hessian is factored; None keeps it with h
+    (and the function traceable under jit/vmap)."""
+    wf, scale, perm, hf = _gptq_setup(w, h, bits=bits, group_size=group_size,
+                                      damp=damp, actorder=actorder)
+    q, err = _gptq_sweep(wf, scale, perm, _factor(hf, factor_device),
+                         bits=bits)
+    return q, scale, err
 
 
 def gptq_quantize(w: jax.Array, h: jax.Array, *, bits: int,
                   group_size: int = -1, damp: float = 0.01,
-                  actorder: bool = False, act_bits: int = 0):
+                  actorder: bool = False, act_bits: int = 0,
+                  factor_device=None):
     """GPTQ for a (K, N) linear or stacked (E, K, N) experts.
 
-    `h`: (K, K) or (E, K, K). Returns (QuantizedTensor, mse_err).
+    `h`: (K, K) or (E, K, K). `factor_device` as in `gptq_quantize_array`.
+    Returns (QuantizedTensor, mse_err).
     """
     if w.ndim == 3:
-        fn = jax.vmap(lambda wi, hi: gptq_quantize_array(
-            wi, hi, bits=bits, group_size=group_size, damp=damp,
-            actorder=actorder))
-        q, scale, err = fn(w, h)
+        wf, scale, perm, hf = jax.vmap(functools.partial(
+            _gptq_setup, bits=bits, group_size=group_size, damp=damp,
+            actorder=actorder))(w, h)
+        q, err = jax.vmap(functools.partial(_gptq_sweep, bits=bits))(
+            wf, scale, perm, _factor(hf, factor_device))
         qw = jax.vmap(lambda qi: pack(qi, bits))(q)
         return QuantizedTensor(qw, scale, bits, group_size, tuple(w.shape),
                                act_bits), jnp.mean(err)
     q, scale, err = gptq_quantize_array(w, h, bits=bits, group_size=group_size,
-                                        damp=damp, actorder=actorder)
+                                        damp=damp, actorder=actorder,
+                                        factor_device=factor_device)
     return QuantizedTensor(pack(q, bits), scale, bits, group_size,
                            tuple(w.shape), act_bits), err

@@ -26,14 +26,7 @@ from jax.experimental import pallas as pl
 from repro.core.quant.types import pack_layout
 from repro.kernels.template import (MatmulSpec, matmul_grid, matmul_in_specs,
                                     matmul_out_spec, make_matmul_kernel,
-                                    packed_tile_rows, scale_blockspec,
-                                    scale_tile, unpack_tile)
-
-# re-exported for the kernel modules (and tests) that historically imported
-# the shared packed-walk helpers from here; they live in template.py now
-__all__ = ["dequant_matmul_pallas", "packed_tile_rows", "scale_tile",
-           "unpack_tile", "_scale_blockspec"]
-_scale_blockspec = scale_blockspec
+                                    scale_operand)
 
 _SPEC = MatmulSpec("dequant_matmul", epilogue="dequant_bf16")
 
@@ -59,9 +52,8 @@ def dequant_matmul_pallas(x: jax.Array, qw: jax.Array, scale: jax.Array, *,
     return pl.pallas_call(
         make_matmul_kernel(_SPEC, bits=bits, bk=bk),
         grid=matmul_grid(_SPEC, e=1, m=m, n=n, k=k, bm=bm, bn=bn, bk=bk),
-        in_specs=matmul_in_specs(_SPEC, bits=bits, group_size=group_size,
-                                 **dims),
+        in_specs=matmul_in_specs(_SPEC, bits=bits, **dims),
         out_specs=matmul_out_spec(_SPEC, bm=bm, bn=bn),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, qw, scale.astype(jnp.float32))
+    )(x, qw, scale_operand(scale, k=k, bk=bk))

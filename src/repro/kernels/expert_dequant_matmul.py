@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.template import (MatmulSpec, matmul_grid, matmul_in_specs,
-                                    matmul_out_spec, make_matmul_kernel)
+                                    matmul_out_spec, make_matmul_kernel,
+                                    scale_operand)
 
 _SPEC = MatmulSpec("expert_dequant_matmul", epilogue="dequant_bf16",
                    expert_dim=True)
@@ -49,9 +50,8 @@ def expert_dequant_matmul_pallas(x: jax.Array, qw: jax.Array,
     return pl.pallas_call(
         make_matmul_kernel(_SPEC, bits=bits, bk=bk),
         grid=matmul_grid(_SPEC, e=e, m=m, n=n, k=k, bm=bm, bn=bn, bk=bk),
-        in_specs=matmul_in_specs(_SPEC, bits=bits, group_size=group_size,
-                                 **dims),
+        in_specs=matmul_in_specs(_SPEC, bits=bits, **dims),
         out_specs=matmul_out_spec(_SPEC, bm=bm, bn=bn),
         out_shape=jax.ShapeDtypeStruct((e, m, n), jnp.float32),
         interpret=interpret,
-    )(x, qw, scale.astype(jnp.float32))
+    )(x, qw, scale_operand(scale, k=k, bk=bk))

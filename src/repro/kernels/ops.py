@@ -16,12 +16,15 @@ step instead of padding tokens up to prefill tiles.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.quant.types import (QuantizedTensor, pack_layout,
+from repro.core.quant.types import (QuantizedTensor, dequantize, pack_layout,
                                     quantize_activation)
-from repro.debug_flags import dequant_impl, strict_kernels
+from repro.debug_flags import dequant_impl
 from repro.kernels import autotune, ref
 from repro.kernels.channel_stats import channel_stats_pallas
 from repro.kernels.dequant_matmul import dequant_matmul_pallas
@@ -107,24 +110,56 @@ def _plan_tiles(m: int, k: int, n: int, qt: QuantizedTensor,
 
 def dequant_matmul(x: jax.Array, qt: QuantizedTensor, *, out_dtype=None,
                    bm: int = 128, bn: int = 256, bk: int = 256) -> jax.Array:
-    """x: (M, K) @ packed (K, N) -> (M, N). Pads M to the tile size."""
-    out_dtype = out_dtype or x.dtype
+    """x: (M, K) @ packed (K, N) -> (M, N). Pads M to the tile size.
+
+    Differentiable in x — norm tweaking takes the gradient of its loss
+    through the packed layer. Pallas kernels define no VJP, so the
+    backward multiplies the cotangent by the dequantized weight."""
+    return _dequant_matmul_vjp(
+        x, qt.qw, qt.scale, (qt.bits, qt.group_size, tuple(qt.shape)),
+        jnp.dtype(out_dtype or x.dtype), (bm, bn, bk))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _dequant_matmul_vjp(x, qw, scale, meta, out_dtype, tiles):
+    bits, group_size, _ = meta
+    bm, bn, bk = tiles
     m, k = x.shape
-    plan = _plan_tiles(m, k, qt.n, qt, bm, bn, bk, kind="dequant")
+    plan = autotune.matmul_plan("dequant", m, k, qw.shape[-1], bits=bits,
+                                group_size=group_size, bm=bm, bn=bn, bk=bk)
     if plan is None:
-        y = ref.dequant_matmul_ref(x, qt.qw, qt.scale, bits=qt.bits,
-                                   group_size=qt.group_size, k=k)
+        y = ref.dequant_matmul_ref(x, qw, scale, bits=bits,
+                                   group_size=group_size, k=k)
         return y.astype(out_dtype)
     bm_, bn_, bk_ = plan
     pad_m = (-m) % bm_
     if pad_m:
         x = jnp.pad(x, ((0, pad_m), (0, 0)))
-    y = dequant_matmul_pallas(x, qt.qw, qt.scale, bits=qt.bits,
-                              group_size=qt.group_size, bm=bm_, bn=bn_,
+    y = dequant_matmul_pallas(x, qw, scale, bits=bits,
+                              group_size=group_size, bm=bm_, bn=bn_,
                               bk=bk_, interpret=_interpret())
     if pad_m:
         y = y[:m]
     return y.astype(out_dtype)
+
+
+def _dequant_matmul_fwd(x, qw, scale, meta, out_dtype, tiles):
+    return (_dequant_matmul_vjp(x, qw, scale, meta, out_dtype, tiles),
+            (x, qw, scale))
+
+
+def _dequant_matmul_bwd(meta, out_dtype, tiles, res, g):
+    x, qw, scale = res
+    bits, group_size, shape = meta
+    w = dequantize(QuantizedTensor(qw, scale, bits, group_size, shape),
+                   jnp.float32)
+    gx = jnp.dot(g.astype(jnp.float32), w.T,
+                 preferred_element_type=jnp.float32)
+    return (gx.astype(x.dtype), np.zeros(qw.shape, jax.dtypes.float0),
+            jnp.zeros_like(scale))
+
+
+_dequant_matmul_vjp.defvjp(_dequant_matmul_fwd, _dequant_matmul_bwd)
 
 
 def expert_dequant_matmul(x: jax.Array, qt: QuantizedTensor, *,
@@ -211,30 +246,8 @@ def expert_w8a8_matmul(x: jax.Array, qt: QuantizedTensor, *, out_dtype=None,
     return (y * xs).astype(out_dtype)
 
 
-# trace-time pallas -> reference fallbacks, per op name. A kernel that fails
-# to *lower* (bad tile regime on an exotic shape, backend gap) raises while
-# the jit is being traced — serving can survive that by building the
-# reference path into the same computation instead. The counter makes the
-# degradation observable; REPRO_STRICT_KERNELS=1 (set in the kernel-parity
-# CI job) disables the net so a broken kernel fails loudly there, never
-# silently passing parity via its own oracle.
-DISPATCH_FALLBACKS: dict[str, int] = {"paged_attention": 0,
-                                      "paged_attention_verify": 0,
-                                      "paged_attention_prefill": 0}
-
-
 def _kv_dtype(k_scale_pool) -> str:
     return "int8" if k_scale_pool is not None else "bf16"
-
-
-def _kernel_fallback(name: str, kernel_fn, ref_fn):
-    try:
-        return kernel_fn()
-    except Exception:
-        if strict_kernels():
-            raise
-        DISPATCH_FALLBACKS[name] += 1
-        return ref_fn()
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -251,8 +264,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     Under tensor-parallel serving this op is invoked *per shard* inside the
     engine's shard_map: the pools arrive with the shard-local kv-head slice
     (KVH/tp) while block tables and fill counts are replicated scalars
-    (scalar-prefetch inputs are never sharded), so the grid simply shrinks
-    along its KVH axis — attention is head-independent and the kernel needs
+    (scalar-prefetch inputs are never sharded), so the kernel's head loop
+    simply shrinks to KVH/tp — attention is head-independent and the kernel needs
     no TP awareness. H here is the shard-local head count; the GQA group
     width H/KVH is TP-invariant because legal widths divide n_kv_heads."""
     s, h, hd = q.shape
@@ -264,19 +277,13 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                                     k_scale_pool, v_scale_pool,
                                     window=window, tile=tile)
     else:
-        o = _kernel_fallback(
-            "paged_attention",
-            lambda: paged_attention_pallas(
-                qg, k_pool, v_pool, block_table, kv_len, k_scale_pool,
-                v_scale_pool, window=window, tile=tile,
-                interpret=_interpret()),
-            lambda: ref.paged_attention_ref(
-                qg, k_pool, v_pool, block_table, kv_len, k_scale_pool,
-                v_scale_pool, window=window, tile=tile))
+        o = paged_attention_pallas(qg, k_pool, v_pool, block_table, kv_len,
+                                   k_scale_pool, v_scale_pool, window=window,
+                                   tile=tile, interpret=_interpret())
     return o.reshape(s, h, v_pool.shape[-1]).astype(out_dtype or q.dtype)
 
 
-def _paged_rows_read(name: str, ref_fn, q: jax.Array, k_pool: jax.Array,
+def _paged_rows_read(ref_fn, q: jax.Array, k_pool: jax.Array,
                      v_pool: jax.Array, block_table: jax.Array,
                      kv_len: jax.Array, *, k_scale_pool=None,
                      v_scale_pool=None, window=None,
@@ -299,15 +306,10 @@ def _paged_rows_read(name: str, ref_fn, q: jax.Array, k_pool: jax.Array,
                    k_scale_pool, v_scale_pool, window=window, tile=tile,
                    m_rows=m)
     else:
-        o = _kernel_fallback(
-            name,
-            lambda: paged_attention_pallas(
-                qg, k_pool, v_pool, block_table, kv_len, k_scale_pool,
-                v_scale_pool, window=window, tile=tile, m_rows=m,
-                interpret=_interpret()),
-            lambda: ref_fn(
-                qg, k_pool, v_pool, block_table, kv_len, k_scale_pool,
-                v_scale_pool, window=window, tile=tile, m_rows=m))
+        o = paged_attention_pallas(qg, k_pool, v_pool, block_table, kv_len,
+                                   k_scale_pool, v_scale_pool, window=window,
+                                   tile=tile, m_rows=m,
+                                   interpret=_interpret())
     hd_v = v_pool.shape[-1]
     o = o.reshape(s, kvh, m, g, hd_v).transpose(0, 2, 1, 3, 4)
     return o.reshape(s, m, h, hd_v).astype(out_dtype or q.dtype)
@@ -322,9 +324,9 @@ def paged_attention_verify(q: jax.Array, k_pool: jax.Array,
     draft-proposed tail tokens of each slot (see `_paged_rows_read`).
     M == 1 is exactly the decode read (`paged_attention`)."""
     return _paged_rows_read(
-        "paged_attention_verify", ref.paged_attention_ref, q, k_pool, v_pool,
-        block_table, kv_len, k_scale_pool=k_scale_pool,
-        v_scale_pool=v_scale_pool, window=window, out_dtype=out_dtype)
+        ref.paged_attention_ref, q, k_pool, v_pool, block_table, kv_len,
+        k_scale_pool=k_scale_pool, v_scale_pool=v_scale_pool, window=window,
+        out_dtype=out_dtype)
 
 
 def paged_attention_prefill(q: jax.Array, k_pool: jax.Array,
@@ -343,9 +345,9 @@ def paged_attention_prefill(q: jax.Array, k_pool: jax.Array,
     the same page walk as decode instead of materializing a contiguous
     (S, width*page_size, ...) HBM view."""
     return _paged_rows_read(
-        "paged_attention_prefill", ref.paged_attention_prefill_ref, q,
-        k_pool, v_pool, block_table, kv_len, k_scale_pool=k_scale_pool,
-        v_scale_pool=v_scale_pool, window=window, out_dtype=out_dtype)
+        ref.paged_attention_prefill_ref, q, k_pool, v_pool, block_table,
+        kv_len, k_scale_pool=k_scale_pool, v_scale_pool=v_scale_pool,
+        window=window, out_dtype=out_dtype)
 
 
 def channel_stats(x: jax.Array):

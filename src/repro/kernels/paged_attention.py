@@ -4,10 +4,10 @@ The serving read hot path (vLLM/PagedAttention-style). Instead of gathering
 every slot's pages into a contiguous ``(S, maxp*page_size, ...)`` HBM view
 and running a dense einsum (the PR-1 path, which reads — and for int8 KV
 materializes in bf16 — the *provisioned* window regardless of fill), the
-kernel walks the block table directly: per (slot, kv-head) grid cell it
-streams one page tile per grid step HBM->VMEM, dequantizes int8 K/V inline
-from the scale pools (which ride the same block table), and folds the tile
-into an online-softmax accumulator held in VMEM scratch. Pages beyond a
+kernel walks the block table directly: per slot grid cell it streams one
+page tile (all kv heads) per grid step HBM->VMEM, dequantizes int8 K/V
+inline from the scale pools (which ride the same block table), and folds
+the tile into per-head online-softmax accumulators held in VMEM scratch. Pages beyond a
 slot's fill count — and, under sliding-window attention, pages wholly
 behind the window — are never touched: their grid steps are routed to the
 scratch page by the index map and skipped by ``pl.when``, so HBM traffic
@@ -16,8 +16,8 @@ scales with *live* tokens, not ``maxp*page_size`` padding.
 Template instance: the page-walk body, liveness predicate, index maps and
 ``PrefetchScalarGridSpec`` all come from `kernels/template.py`
 (:class:`PagedSpec`); only the ``pl.pallas_call`` site lives here. The
-grid is ``(S, KVH, W * tiles_per_page)``, the page-walk axis innermost so
-the (m, l, acc) scratch accumulators carry across one cell's pages.
+grid is ``(S, W * tiles_per_page)``, the page-walk axis innermost so the
+per-head (m, l, acc) scratch accumulators carry across one slot's pages.
 
 Multi-row regime (``m_rows > 1``) serves two callers through one body:
   * spec-decode *verify* — the draft's last ``m_rows`` tokens of a slot
@@ -39,7 +39,7 @@ order, same f32 accumulation) so interpret-mode runs are bit-comparable
 with the jnp reference on CPU.
 
 Tensor parallelism (serve/engine.py shard_map): the kernel runs per shard
-on the local kv-head slice of the pools — the grid's KVH axis shrinks to
+on the local kv-head slice of the pools — the block's KVH dim shrinks to
 KVH/tp while the scalar-prefetched block table / fill counts stay
 replicated, and per-head online softmax needs no cross-shard collective
 (the psum lives at the attention output projection, outside the kernel).

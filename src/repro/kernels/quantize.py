@@ -14,12 +14,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.quant.types import pack_layout, qmax_for_bits
-from repro.kernels.template import packed_tile_rows, scale_blockspec
+from repro.kernels.template import (packed_tile_rows, scale_blockspec,
+                                    scale_operand)
 
 
 def _quantize_kernel(w_ref, scale_ref, o_ref, *, bits: int, bk: int):
     w = w_ref[...].astype(jnp.float32)                 # (bk, bn)
-    s = scale_ref[...]                                 # (gb, bn)
+    s = scale_ref[0]                                   # (gb, bn)
     gb, bn = s.shape
     qmax = qmax_for_bits(bits)
     ws = (w.reshape(gb, bk // gb, bn) / s[:, None, :]).reshape(bk, bn)
@@ -55,7 +56,7 @@ def quantize_pack_pallas(w: jax.Array, scale: jax.Array, *, bits: int,
     assert k % bk == 0 and n % bn == 0 and bk % vpg == 0
 
     # reuse the dequant scale indexing, adding a dummy leading grid dim
-    sspec = scale_blockspec(group_size, k, g, bk, bn)
+    sspec = scale_blockspec(k, g, bk, bn)
     sspec2 = pl.BlockSpec(sspec.block_shape,
                           lambda kk, j: sspec.index_map(0, j, kk))
 
@@ -69,4 +70,4 @@ def quantize_pack_pallas(w: jax.Array, scale: jax.Array, *, bits: int,
         out_shape=jax.ShapeDtypeStruct((packed_tile_rows(k, bits), n),
                                        jnp.uint8),
         interpret=interpret,
-    )(w, scale.astype(jnp.float32))
+    )(w, scale_operand(scale, k=k, bk=bk))

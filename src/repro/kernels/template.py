@@ -72,11 +72,14 @@ def unpack_tile(qw: jax.Array, bits: int, bk: int) -> jax.Array:
     if (bpg, vpg) == (1, 1):
         u = qw
     else:
+        # shift in int32: Mosaic has no vector shifts on 8-bit lanes, and
+        # every packed word (<= 24 bits) is non-negative, so arithmetic
+        # and logical shifts agree
         if bpg == 1:
-            word = qw
+            word = qw.astype(jnp.int32)
         else:
             # multi-byte group (W3): rebuild the little-endian word first
-            grp = qw.astype(jnp.uint32).reshape(bk // vpg, bpg, bn)
+            grp = qw.astype(jnp.int32).reshape(bk // vpg, bpg, bn)
             word = grp[:, 0, :]
             for b in range(1, bpg):
                 word = word | (grp[:, b, :] << (8 * b))
@@ -95,21 +98,35 @@ def scale_tile(q: jax.Array, s: jax.Array, bk: int) -> jax.Array:
             s[:, None, :]).reshape(bk, bn)
 
 
-def scale_blockspec(group_size: int, k: int, g: int, bk: int, bn: int):
-    """BlockSpec walking a (G, N) scale tensor alongside (bk, bn) K tiles:
-    one broadcast row (per-channel), whole groups per block, or whole
-    blocks per group."""
-    if g == 1:
-        return pl.BlockSpec((1, bn), lambda i, j, kk: (0, j))
+def scale_rows(k: int, g: int, bk: int) -> int:
+    """Scale rows one (bk, bn) K tile needs: bk // group_size when a tile
+    holds several groups, else 1 (per-channel, or a group spans tiles)."""
+    return max(1, bk * g // k)
+
+
+def scale_operand(scale: jax.Array, *, k: int, bk: int) -> jax.Array:
+    """(..., G, N) scales -> (..., G // r, r, N) f32 with r = scale_rows.
+
+    Mosaic takes a block only when its last two dims are multiples of
+    (8, 128) or span the array; a (r, bn) slice of a (G, N) scale is
+    neither once 1 < G. Splitting G so that each K tile's r rows are a
+    whole trailing (r, N) plane keeps the block legal for every group
+    size without any in-kernel dynamic slicing."""
+    g, n = scale.shape[-2:]
+    r = scale_rows(k, g, bk)
+    return scale.astype(jnp.float32).reshape(
+        scale.shape[:-2] + (g // r, r, n))
+
+
+def scale_blockspec(k: int, g: int, bk: int, bn: int):
+    """BlockSpec walking a `scale_operand` alongside (bk, bn) K tiles: K
+    step kk reads the r scale rows of K range [kk*bk, (kk+1)*bk) — whole
+    groups per block (r > 1), or the one group the block sits in (r == 1,
+    including per-channel)."""
+    r = scale_rows(k, g, bk)
     gs = k // g
-    if gs >= bk:
-        assert gs % bk == 0
-        return pl.BlockSpec((1, bn), lambda i, j, kk: (kk * bk // gs, j))
-    assert bk % gs == 0
-    gpb = bk // gs
-    # index_map is in BLOCK units: kv-block kk covers scale rows
-    # [kk*gpb, (kk+1)*gpb) == block row kk of a (gpb, bn) block
-    return pl.BlockSpec((gpb, bn), lambda i, j, kk: (kk, j))
+    return pl.BlockSpec((1, r, bn),
+                        lambda i, j, kk: (kk * bk // (gs * r), 0, j))
 
 
 def lift_expert(s: pl.BlockSpec) -> pl.BlockSpec:
@@ -152,9 +169,9 @@ def make_matmul_kernel(spec: MatmulSpec, *, bits: int, bk: int):
             o_ref[...] = jnp.zeros_like(o_ref)
 
         if spec.expert_dim:
-            x, qw, s = x_ref[0], qw_ref[0], scale_ref[0]
+            x, qw, s = x_ref[0], qw_ref[0], scale_ref[0, 0]
         else:
-            x, qw, s = x_ref[...], qw_ref[...], scale_ref[...]
+            x, qw, s = x_ref[...], qw_ref[...], scale_ref[0]
         q = unpack_tile(qw, bits, bk)                  # (bk, bn) int32
         if spec.epilogue == "dequant_bf16":
             w = scale_tile(q, s, bk)                   # (bk, bn) f32
@@ -191,14 +208,14 @@ def matmul_grid(spec: MatmulSpec, *, e: int, m: int, n: int, k: int,
     return (e,) + base if spec.expert_dim else base
 
 
-def matmul_in_specs(spec: MatmulSpec, *, bits: int, group_size: int, k: int,
-                    g: int, bm: int, bn: int, bk: int):
+def matmul_in_specs(spec: MatmulSpec, *, bits: int, k: int, g: int, bm: int,
+                    bn: int, bk: int):
     """[x, packed qw, scale] block specs for the (M, N, K) grid walk."""
     specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((packed_tile_rows(bk, bits), bn),
                      lambda i, j, kk: (kk, j)),
-        scale_blockspec(group_size, k, g, bk, bn),
+        scale_blockspec(k, g, bk, bn),
     ]
     if spec.expert_dim:
         specs = [lift_expert(s) for s in specs]
@@ -256,22 +273,15 @@ def tile_live(spec: PagedSpec, s, t, bt, kl):
     return live
 
 
-def page_map(spec: PagedSpec):
-    """Index map for the K/V pool tiles of grid cell (s, h, t)."""
-    def index(s, h, t, bt, kl):
+def page_map(spec: PagedSpec, ndim: int):
+    """Index map for the pool tiles of grid cell (s, t): the page-walk
+    step's page (the scratch page when dead) and sub-tile, every kv head.
+    ndim 4 walks the K/V pools, ndim 3 their int8 scale pools."""
+    def index(s, t, bt, kl):
         w, sub, _ = _tile_coords(t, page_size=spec.page_size, tile=spec.tile)
         live = tile_live(spec, s, t, bt, kl)
         page = jnp.where(live, jnp.maximum(bt[s, w], 0), 0)
-        return page, sub, h, 0
-    return index
-
-
-def scale_map(spec: PagedSpec):
-    def index(s, h, t, bt, kl):
-        w, sub, _ = _tile_coords(t, page_size=spec.page_size, tile=spec.tile)
-        live = tile_live(spec, s, t, bt, kl)
-        page = jnp.where(live, jnp.maximum(bt[s, w], 0), 0)
-        return page, sub, h
+        return (page, sub) + (0,) * (ndim - 2)
     return index
 
 
@@ -288,7 +298,7 @@ def make_paged_kernel(spec: PagedSpec, *, sm_scale: float, n_steps: int):
         else:
             o_ref, m_scr, l_scr, acc_scr = rest
         s_i = pl.program_id(0)
-        t_i = pl.program_id(2)
+        t_i = pl.program_id(1)
         kl = kl_ref[s_i]
         _, _, base = _tile_coords(t_i, page_size=page_size, tile=tile)
         live = tile_live(spec, s_i, t_i, bt_ref, kl_ref)
@@ -299,14 +309,13 @@ def make_paged_kernel(spec: PagedSpec, *, sm_scale: float, n_steps: int):
             l_scr[...] = jnp.zeros_like(l_scr)
             acc_scr[...] = jnp.zeros_like(acc_scr)
 
-        @pl.when(live)
-        def _compute():
-            q = q_ref[0, 0].astype(jnp.float32)              # (R, hd)
-            k = k_ref[0, :, 0, :]                            # (tile, hd)
-            v = v_ref[0, :, 0, :]                            # (tile, hd_v)
+        def _fold_head(h):
+            q = q_ref[0, h].astype(jnp.float32)              # (R, hd)
+            k = k_ref[0, :, h, :]                            # (tile, hd)
+            v = v_ref[0, :, h, :]                            # (tile, hd_v)
             if spec.quant:
-                kf = k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]
-                vf = v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
+                kf = k.astype(jnp.float32) * ks_ref[0][:, h:h + 1]
+                vf = v.astype(jnp.float32) * vs_ref[0][:, h:h + 1]
             else:
                 kf = k.astype(jnp.float32)
                 vf = v.astype(jnp.float32)
@@ -325,7 +334,7 @@ def make_paged_kernel(spec: PagedSpec, *, sm_scale: float, n_steps: int):
             if window is not None:
                 valid &= pos > (lim - 1 - window)
             s = jnp.where(valid, s, NEG)
-            m_prev = m_scr[...]                              # (R, 1)
+            m_prev = m_scr[h]                                # (R, 1)
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             corr = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)                           # (R, tile)
@@ -336,47 +345,57 @@ def make_paged_kernel(spec: PagedSpec, *, sm_scale: float, n_steps: int):
             # and masked columns underflow to exactly 0.0 anyway:
             # bit-identical.
             p = jnp.where(valid, p, 0.0)
-            l_scr[...] = (l_scr[...] * corr +
-                          jnp.sum(p, axis=-1, keepdims=True))
-            acc_scr[...] = acc_scr[...] * corr + jnp.dot(
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * corr + jnp.dot(
                 p, vf, preferred_element_type=jnp.float32)
-            m_scr[...] = m_new
+            m_scr[h] = m_new
+
+        @pl.when(live)
+        def _compute():
+            # one (tile, KVH, hd) block carries every kv head of the page
+            # tile (the only block of the (P, page, KVH, hd) pool Mosaic
+            # accepts); each head folds its own slice into its own
+            # accumulators, in the walk order of the per-head reference
+            for h in range(q_ref.shape[1]):
+                _fold_head(h)
 
         @pl.when(t_i == n_steps - 1)
         def _finalize():
             # empty slots (kv_len == 0) never accumulate: l stays 0 and the
             # guarded divide emits exact zeros (the engine discards them)
-            o_ref[0, 0] = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+            o_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
 
     return kernel
 
 
 def paged_grid_spec(spec: PagedSpec, *, s: int, kvh: int, rows: int, hd: int,
                     hd_v: int, n_steps: int):
-    """PrefetchScalarGridSpec for the (S, KVH, page-walk) grid: block table
-    + fill counts scalar-prefetched so index maps chase page ids before
-    each tile's DMA, (m, l, acc) accumulators in VMEM scratch."""
+    """PrefetchScalarGridSpec for the (S, page-walk) grid: block table +
+    fill counts scalar-prefetched so index maps chase page ids before each
+    tile's DMA, per-kv-head (m, l, acc) accumulators in VMEM scratch. Each
+    pool block spans all KVH heads of one page tile, so its last two dims
+    equal the pool's and every tile streams once per slot."""
     tile = spec.tile
     in_specs = [
-        pl.BlockSpec((1, 1, rows, hd),
-                     lambda s_, h_, t_, bt, kl: (s_, h_, 0, 0)),
-        pl.BlockSpec((1, tile, 1, hd), page_map(spec)),
-        pl.BlockSpec((1, tile, 1, hd_v), page_map(spec)),
+        pl.BlockSpec((1, kvh, rows, hd),
+                     lambda s_, t_, bt, kl: (s_, 0, 0, 0)),
+        pl.BlockSpec((1, tile, kvh, hd), page_map(spec, 4)),
+        pl.BlockSpec((1, tile, kvh, hd_v), page_map(spec, 4)),
     ]
     if spec.quant:
         in_specs += [
-            pl.BlockSpec((1, tile, 1), scale_map(spec)),
-            pl.BlockSpec((1, tile, 1), scale_map(spec)),
+            pl.BlockSpec((1, tile, kvh), page_map(spec, 3)),
+            pl.BlockSpec((1, tile, kvh), page_map(spec, 3)),
         ]
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s, kvh, n_steps),
+        grid=(s, n_steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows, hd_v),
-                               lambda s_, h_, t_, bt, kl: (s_, h_, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, rows, hd_v),
+                               lambda s_, t_, bt, kl: (s_, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((rows, 1), jnp.float32),      # running max
-            pltpu.VMEM((rows, 1), jnp.float32),      # running denominator
-            pltpu.VMEM((rows, hd_v), jnp.float32),   # output accumulator
+            pltpu.VMEM((kvh, rows, 1), jnp.float32),      # running max
+            pltpu.VMEM((kvh, rows, 1), jnp.float32),      # running denominator
+            pltpu.VMEM((kvh, rows, hd_v), jnp.float32),   # output accumulator
         ],
     )

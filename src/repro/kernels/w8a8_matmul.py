@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.template import (MatmulSpec, matmul_grid, matmul_in_specs,
-                                    matmul_out_spec, make_matmul_kernel)
+                                    matmul_out_spec, make_matmul_kernel,
+                                    scale_operand)
 
 _SPEC = MatmulSpec("w8a8_matmul", epilogue="int8_mxu")
 
@@ -55,9 +56,8 @@ def w8a8_matmul_pallas(xq: jax.Array, qw: jax.Array, scale: jax.Array, *,
     return pl.pallas_call(
         make_matmul_kernel(_SPEC, bits=bits, bk=bk),
         grid=matmul_grid(_SPEC, e=1, m=m, n=n, k=k, bm=bm, bn=bn, bk=bk),
-        in_specs=matmul_in_specs(_SPEC, bits=bits, group_size=group_size,
-                                 **dims),
+        in_specs=matmul_in_specs(_SPEC, bits=bits, **dims),
         out_specs=matmul_out_spec(_SPEC, bm=bm, bn=bn),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(xq, qw, scale.astype(jnp.float32))
+    )(xq, qw, scale_operand(scale, k=k, bk=bk))

@@ -94,6 +94,7 @@ def make_workload(cfg, args):
 
 
 def run_continuous(cfg, params, work, args):
+    """Warm, replay `work` and print the report; returns (engine, report)."""
     # per-slot capacity must cover a bucket-padded prompt plus max decode,
     # or the bucket-length warm-up requests below would overflow it
     plen_max = max(len(it.prompt) for it in work)
@@ -229,6 +230,7 @@ def run_continuous(cfg, params, work, args):
             print(f"  WARNING: {len(over)} variant(s) exceeded the "
                   "per-variant compile budget (see repro.analysis.sanitize)")
     print("request 0:", done[0].tokens)
+    return eng, report
 
 
 def run_static(cfg, params, work, args):
@@ -251,7 +253,7 @@ def run_static(cfg, params, work, args):
           f"({total / dt:.1f} tok/s, incl. compile)")
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b",
                     choices=["tiny"] + list_archs())
@@ -341,7 +343,11 @@ def main():
     ap.add_argument("--max-new-min", type=int, default=8)
     ap.add_argument("--max-new-max", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.enc_dec:

@@ -61,7 +61,7 @@ from repro.distributed.sharding import TP_AXIS, sharding_ctx
 from repro.models.config import ModelConfig
 from repro.models.transformer import (init_cache, lm_decode, lm_forward,
                                       lm_prefill, lm_verify)
-from repro.serve.faults import FaultInjected, FaultPlan
+from repro.serve.faults import FaultInjected, FaultPlan, KernelFaultInjected
 from repro.serve.kvcache import (POOL_KEYS, PagePool, PageSpec,
                                  default_page_spec, pool_head_dim)
 from repro.serve.sampling import (sample, spec_accept_greedy,
@@ -1518,7 +1518,8 @@ class ContinuousEngine:
                 # is consumed as a no-op
                 self._kernel_fault = False
                 if self.cfg.paged_attn_impl != "gather":
-                    raise RuntimeError("injected kernel dispatch failure")
+                    raise KernelFaultInjected(
+                        "injected kernel dispatch failure")
             if self.tp > 1:
                 return _paged_decode_scan_tp_jit(
                     self.cfg, self.mesh, *args, **kw)
@@ -1526,17 +1527,13 @@ class ContinuousEngine:
 
         try:
             toks, alive, self.cache = dispatch()
-        except FaultInjected:
-            raise
-        except Exception:
-            # kernel-dispatch failure (trace/lowering raises before the
-            # donated cache is consumed — execution-time donation makes
-            # the retry safe): permanently fall back to the gather oracle
-            # paged-attention path and retry once. Correctness is
-            # bitwise-identical (gather is the fused kernel's oracle);
-            # only bandwidth is lost, and the counter makes it visible.
-            if self.cfg.paged_attn_impl == "gather":
-                raise
+        except KernelFaultInjected:
+            # the injected kernel_fault (raised before the donated cache is
+            # consumed, so the retry is safe): permanently fall back to the
+            # gather oracle paged-attention path and retry once. Tokens are
+            # bitwise-identical (gather is the fused kernel's oracle); only
+            # bandwidth is lost, and the counter makes it visible. A real
+            # lowering error is not caught: it propagates.
             self.cfg = self.cfg.replace(paged_attn_impl="gather")
             self.n_kernel_fallbacks += 1
             toks, alive, self.cache = dispatch()

@@ -25,9 +25,11 @@ graceful-degradation path:
                   a stream on garbage KV.
   latency_spike   jump the virtual clock, aging every queued request at
                   once (deadline shedding and aging promotion both fire).
-  kernel_fault    fail the next fused decode dispatch; the engine must
-                  fall back fused -> gather paged attention and keep the
-                  token stream identical.
+  kernel_fault    fail the next fused decode dispatch with
+                  ``KernelFaultInjected``; the engine must fall back
+                  fused -> gather paged attention and keep the token
+                  stream identical. It is the only failure the engine
+                  answers that way: a real lowering error propagates.
 
 The driver (`run_resilient`) owns the plan across crashes: a
 `step_exception` that fired is dropped from the plan handed to the
@@ -52,6 +54,12 @@ class FaultInjected(RuntimeError):
     def __init__(self, fault: "Fault"):
         super().__init__(f"injected fault: {fault}")
         self.fault = fault
+
+
+class KernelFaultInjected(RuntimeError):
+    """An injected kernel_fault — the simulated fused-dispatch failure the
+    engine answers by degrading to the gather read. Only this exception
+    triggers that fallback: a real lowering error propagates."""
 
 
 @dataclasses.dataclass(frozen=True)

@@ -280,6 +280,24 @@ def test_kernel_fault_falls_back_with_identical_tokens(tiny_lm):
     _assert_drained(eng)
 
 
+def test_real_decode_dispatch_error_propagates(tiny_lm, monkeypatch):
+    """Only the injected kernel_fault degrades fused -> gather: any other
+    dispatch failure (a kernel that does not lower) must surface, not be
+    served through the oracle path."""
+    from repro.serve import engine as engine_mod
+
+    def broken(*a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    eng = _engine(CFG, tiny_lm, paged_attn="fused")
+    _submit_mixed(eng, CFG.vocab_size)
+    monkeypatch.setattr(engine_mod, "_paged_decode_scan_jit", broken)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        eng.run(max_steps=2000)
+    assert eng.n_kernel_fallbacks == 0
+    assert eng.cfg.paged_attn_impl == "fused"
+
+
 # ------------------------------------------------- deadlines / shedding
 
 def test_deadline_shed_cancel_completed_disjoint(tiny_lm):

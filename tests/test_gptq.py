@@ -68,3 +68,30 @@ def test_gptq_dead_columns_survive():
     h = hessian_from_inputs(x)
     qt, _ = gptq_quantize(w, h, bits=4)
     assert np.all(np.isfinite(np.asarray(dequantize(qt))))
+
+
+def test_gptq_factor_device_matches_in_place_and_jits():
+    """Factoring on the host CPU gives the same codes as factoring where
+    the Hessian lives; the in-place form stays traceable under jit."""
+    key = jax.random.PRNGKey(5)
+    x = jax.random.normal(key, (128, 32)) * jnp.linspace(0.1, 2.0, 32)
+    w = jax.random.normal(key, (32, 16)) * 0.2
+    h = hessian_from_inputs(x)
+    cpu = jax.local_devices(backend="cpu")[0]
+    q_host, s_host, _ = gptq_quantize_array(w, h, bits=4, group_size=16,
+                                            factor_device=cpu)
+    q_jit, s_jit, _ = jax.jit(lambda w, h: gptq_quantize_array(
+        w, h, bits=4, group_size=16))(w, h)
+    np.testing.assert_array_equal(np.asarray(q_host), np.asarray(q_jit))
+    np.testing.assert_array_equal(np.asarray(s_host), np.asarray(s_jit))
+
+
+def test_host_cpu_without_cpu_backend_raises(monkeypatch):
+    from repro.core.quant import blockquant
+
+    def no_cpu(backend=None):
+        raise RuntimeError(f"Unknown backend {backend}")
+
+    monkeypatch.setattr(blockquant.jax, "local_devices", no_cpu)
+    with pytest.raises(RuntimeError, match="add 'cpu' to JAX_PLATFORMS"):
+        blockquant.host_cpu()
