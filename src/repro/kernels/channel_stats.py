@@ -46,6 +46,7 @@ def channel_stats_pallas(x: jax.Array, *, bt: int = 256, bc: int = 256,
         out_shape=[jax.ShapeDtypeStruct((1, c), jnp.float32),
                    jax.ShapeDtypeStruct((1, c), jnp.float32)],
         interpret=interpret,
+        name="channel_stats_pallas",
     )(x)
     mean = s[0] / t
     var = sq[0] / t - mean * mean

@@ -56,4 +56,5 @@ def dequant_matmul_pallas(x: jax.Array, qw: jax.Array, scale: jax.Array, *,
         out_specs=matmul_out_spec(_SPEC, bm=bm, bn=bn),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="dequant_matmul_pallas",
     )(x, qw, scale_operand(scale, k=k, bk=bk))

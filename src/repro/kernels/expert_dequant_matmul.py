@@ -54,4 +54,5 @@ def expert_dequant_matmul_pallas(x: jax.Array, qw: jax.Array,
         out_specs=matmul_out_spec(_SPEC, bm=bm, bn=bn),
         out_shape=jax.ShapeDtypeStruct((e, m, n), jnp.float32),
         interpret=interpret,
+        name="expert_dequant_matmul_pallas",
     )(x, qw, scale_operand(scale, k=k, bk=bk))

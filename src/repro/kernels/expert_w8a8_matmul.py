@@ -56,4 +56,5 @@ def expert_w8a8_matmul_pallas(xq: jax.Array, qw: jax.Array, scale: jax.Array,
         out_specs=matmul_out_spec(_SPEC, bm=bm, bn=bn),
         out_shape=jax.ShapeDtypeStruct((e, c, n), jnp.float32),
         interpret=interpret,
+        name="expert_w8a8_matmul_pallas",
     )(xq, qw, scale_operand(scale, k=k, bk=bk))

@@ -96,4 +96,5 @@ def paged_attention_pallas(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                                   hd_v=hd_v, n_steps=n_steps),
         out_shape=jax.ShapeDtypeStruct((s, kvh, rows, hd_v), jnp.float32),
         interpret=interpret,
+        name="paged_attention_pallas",
     )(block_table.astype(jnp.int32), kv_len.astype(jnp.int32), *args)

@@ -70,4 +70,5 @@ def quantize_pack_pallas(w: jax.Array, scale: jax.Array, *, bits: int,
         out_shape=jax.ShapeDtypeStruct((packed_tile_rows(k, bits), n),
                                        jnp.uint8),
         interpret=interpret,
+        name="quantize_pack_pallas",
     )(w, scale_operand(scale, k=k, bk=bk))

@@ -60,4 +60,5 @@ def w8a8_matmul_pallas(xq: jax.Array, qw: jax.Array, scale: jax.Array, *,
         out_specs=matmul_out_spec(_SPEC, bm=bm, bn=bn),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="w8a8_matmul_pallas",
     )(xq, qw, scale_operand(scale, k=k, bk=bk))

@@ -51,6 +51,7 @@ import numpy as np
 from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro import spans
 from repro.analysis.sanitize import note_trace
 from repro.core.quant.types import QuantizedTensor, localize_quantized
 from repro.debug_flags import debug_enabled
@@ -775,6 +776,12 @@ class ContinuousEngine:
         # pages pinned by pool_exhaust faults: [release_step, [pages]]
         self._fault_holds: list[list] = []
         self.n_decode_steps = 0
+        self.n_decode_tokens = 0     # tokens emitted by decode blocks/rounds
+        # KV pages the occupied slots hold, and those holding live tokens,
+        # summed at the end of every step: their ratio is the share of the
+        # reserved pool that holds data
+        self.n_pages_held = 0
+        self.n_pages_live = 0
         self.n_prefills = 0
         self.n_prefill_tokens = 0    # real prompt tokens actually prefilled
         self.n_shared_tokens = 0     # prompt tokens served from the prefix cache
@@ -788,6 +795,9 @@ class ContinuousEngine:
         self.n_spec_emitted = 0      # tokens emitted by spec rounds
         self.spec_accept_sum = np.zeros(n_slots, np.int64)   # per-slot n_emit
         self.spec_round_count = np.zeros(n_slots, np.int64)  # per-slot rounds
+        # requests _emit stamped during the current step (run() restamps
+        # them with its clock once the step has returned)
+        self._stamped: list[Request] = []
 
     # -------------------------------------------------------- TP placement
     _TP_COL = ("attn/wq/w", "attn/wk/w", "attn/wv/w", "attn/wukv/w",
@@ -1018,7 +1028,8 @@ class ContinuousEngine:
             self._prefilling[slot] = req
 
     # -------------------------------------------- engine snapshot / restore
-    _SNAP_COUNTERS = ("n_decode_steps", "n_prefills", "n_prefill_tokens",
+    _SNAP_COUNTERS = ("n_decode_steps", "n_decode_tokens", "n_pages_held",
+                      "n_pages_live", "n_prefills", "n_prefill_tokens",
                       "n_shared_tokens", "n_spilled_pages",
                       "n_restored_pages", "n_spec_rounds", "n_draft_tokens",
                       "n_spec_emitted", "n_kernel_fallbacks",
@@ -1274,51 +1285,80 @@ class ContinuousEngine:
         expired deadlines, admit new requests, advance every mid-prefill
         slot by one chunk (batched by chunk bucket), then run one fused
         block of decode steps over all decoding slots. Returns False when
-        there was nothing to do."""
+        there was nothing to do. Its phases are host spans (repro.spans)."""
         step_idx = self.n_steps_total
         self.n_steps_total += 1
-        self._apply_faults(step_idx, now)    # may raise FaultInjected
-        did = self._enforce_deadlines(now)
-        for slot, req in self.sched.admit(now):
-            if req.spill is not None:
-                # re-admission of a preempted request: scatter its spilled
-                # KV back and resume (decode or mid-prompt prefill) — no
-                # token is ever re-prefilled, the stream picks up exactly
-                # where the eviction cut it
+        self._stamped = []
+        with spans.span(spans.STEP, step=step_idx):
+            did = self._admit(step_idx, now)
+            if self._prefilling:
                 did = True
-                self._restore_slot(slot, req, now)
-                continue
-            # a prefix hit starts the prefill past the shared pages — the
-            # cache already holds positions 0..n_shared-1 for this prompt
-            self.cur_len[slot] = req.n_shared
-            self.n_shared_tokens += req.n_shared
-            self._prefilling[slot] = req
-        if self._prefilling:
-            did = True
-            self._prefill_tick(now)
-        act = np.nonzero(self.active)[0]
-        if act.size:
-            did = True
-            if self.spec_decode:
-                self._spec_block(self.active.copy(), now)
-            else:
-                toks, alive = self._decode_block()            # (K, n_slots)
-                for t in range(toks.shape[0]):
-                    for slot in act:
-                        req = self.sched.slots[slot]
-                        if req is None:                       # retired
-                            continue
-                        if not alive[t, slot]:
-                            # sentinel fired: the token at (and after) this
-                            # step is garbage; the scan already froze the
-                            # slot, so only this retire remains
-                            self._quarantine(slot, req,
-                                             "nonfinite_logits", now)
-                            continue
-                        self._emit(slot, req, int(toks[t, slot]), now)
-        if self.debug:
-            self._debug_check()
+                self._prefill_tick(now)
+            act = np.nonzero(self.active)[0]
+            if act.size:
+                did = True
+                if self.spec_decode:
+                    self._spec_block(self.active.copy(), now)
+                else:
+                    toks, alive = self._decode_block()        # (K, n_slots)
+                    self._emit_block(toks, alive, act, now)
+            occupied = np.asarray([r is not None for r in self.sched.slots])
+            self.n_pages_held += int((self.pool.tables[occupied] >= 0).sum())
+            self.n_pages_live += int(
+                (-(-self.cur_len[occupied] // self.spec.page_size)).sum())
+            if self.debug:
+                self._debug_check()
         return did
+
+    def _admit(self, step_idx: int, now: float) -> bool:
+        """Fire this step's faults, shed/cancel expired deadlines, and map
+        queued requests onto free slots; returns whether a deadline or a
+        restore changed anything."""
+        with spans.span(spans.ADMIT) as sp:
+            self._apply_faults(step_idx, now)    # may raise FaultInjected
+            did = self._enforce_deadlines(now)
+            fresh = []
+            for slot, req in self.sched.admit(now):
+                if req.spill is not None:
+                    # re-admission of a preempted request: scatter its
+                    # spilled KV back and resume (decode or mid-prompt
+                    # prefill) — no token is ever re-prefilled, the stream
+                    # picks up exactly where the eviction cut it
+                    did = True
+                    self._restore_slot(slot, req, now)
+                    continue
+                # a prefix hit starts the prefill past the shared pages —
+                # the cache already holds positions 0..n_shared-1
+                self.cur_len[slot] = req.n_shared
+                self.n_shared_tokens += req.n_shared
+                self._prefilling[slot] = req
+                fresh.append(req.rid)
+            if fresh:
+                sp.set_metadata(rids=spans.ids(fresh))
+        return did
+
+    def _emit_block(self, toks: np.ndarray, alive: np.ndarray,
+                    act: np.ndarray, now: float) -> None:
+        """Hand a decode block's (K, n_slots) tokens to the slots `act`
+        that decoded it, in step order: emit, retire at max_new/EOS, or
+        quarantine where the sentinel fired."""
+        with spans.span(spans.EMIT) as sp:
+            n = 0
+            for t in range(toks.shape[0]):
+                for slot in act:
+                    req = self.sched.slots[slot]
+                    if req is None:                           # retired
+                        continue
+                    if not alive[t, slot]:
+                        # sentinel fired: the token at (and after) this
+                        # step is garbage; the scan already froze the
+                        # slot, so only this retire remains
+                        self._quarantine(slot, req, "nonfinite_logits", now)
+                        continue
+                    self._emit(slot, req, int(toks[t, slot]), now)
+                    n += 1
+            self.n_decode_tokens += n
+            sp.set_metadata(tokens=n)
 
     def run(self, *, clock=None, max_steps: Optional[int] = None):
         """Drain every submitted request; returns the requests that finished
@@ -1331,6 +1371,10 @@ class ContinuousEngine:
         resumes mid-trace with arrival gating intact, and back-to-back
         run() calls keep monotonic time (latency_spike faults advance it
         too; reset ``engine.t = 0`` to re-zero between measured runs).
+        Under a wall clock a token reaches the caller when the step that
+        made it returns, after the rest of that step's decode block, so
+        `first_token_at` / `finished_at` stamped in a step are set to a
+        clock reading taken then.
         """
         import time as _time
 
@@ -1340,6 +1384,13 @@ class ContinuousEngine:
                 raise RuntimeError(f"serve loop exceeded {max_steps} steps")
             now = clock() if clock is not None else float(self.t)
             did = self.step(now)
+            if clock is not None and self._stamped:
+                done_at = clock()
+                for req in self._stamped:
+                    if req.first_token_at == now:
+                        req.first_token_at = done_at
+                    if req.finished_at == now:
+                        req.finished_at = done_at
             if did or clock is None:
                 steps += 1
                 # virtual time must tick even when idle (arrival gating),
@@ -1401,55 +1452,62 @@ class ContinuousEngine:
         rows that complete their prompt sample a first token and switch
         the slot to decoding."""
         batch = len(items)
-        toks = np.zeros((batch, padded), np.int32)
-        pos = np.full((batch, padded), -1, np.int32)
-        for row, (slot, req, start, end) in enumerate(items):
-            n = end - start
-            toks[row, padded - n:] = req.prompt[start:end]
-            pos[row, padded - n:] = np.arange(start, end, dtype=np.int32)
-        slots = np.asarray([slot for slot, _, _, _ in items], np.int32)
-        if has_ctx:
-            # pow2-bucketed read width over the deepest chunk end, so the
-            # gathered context scales with fill, not provisioned max_len
-            kv_end = np.asarray([end for _, _, _, end in items], np.int32)
-            width = self._read_width(int(kv_end.max()))
-            paged = {"bt_rows": jnp.asarray(np.ascontiguousarray(
-                         self.pool.tables[slots][:, :width])),
-                     "slots": jnp.asarray(slots),
-                     "kv_len": jnp.asarray(kv_end)}
-        else:
-            paged = {"bt_rows": jnp.asarray(self.pool.tables[slots]),
-                     "slots": jnp.asarray(slots)}
-        if self.tp > 1:
-            logits, self.cache = _paged_prefill_tp_jit(
-                self.cfg, self.mesh, self.params, jnp.asarray(toks),
-                self.cache, jnp.asarray(pos), paged)
-        else:
-            logits, self.cache = _paged_prefill_jit(
-                self.cfg, self.params, jnp.asarray(toks), self.cache,
-                jnp.asarray(pos), paged)
-        if self.spec_decode:
-            # mirror the chunk into the draft cache so draft decode starts
-            # from the same fill state (its logits are discarded — the
-            # first token is always the target's)
-            _, self.draft_cache = _paged_prefill_jit(
-                self.cfg, self.draft_params, jnp.asarray(toks),
-                self.draft_cache, jnp.asarray(pos), paged)
-        self.n_prefills += 1
-        self.n_prefill_tokens += sum(end - start for _, _, start, end in items)
-        finish = []
-        for row, (slot, req, start, end) in enumerate(items):
-            self.cur_len[slot] = end
-            if end >= req.n_prompt:
-                finish.append(row)
-        if not finish:
-            return
-        keys = jnp.stack([jax.random.fold_in(self._first_key, items[row][1].rid)
-                          for row in finish])
-        first, fin_ok = _sample_first_jit(
-            logits[jnp.asarray(finish)], keys,
-            temperature=self.temperature, top_k=self.top_k)
-        first, fin_ok = np.asarray(first), np.asarray(fin_ok)
+        kv_end = np.asarray([end for _, _, _, end in items], np.int32)
+        # pow2-bucketed read width over the deepest chunk end, so the
+        # gathered context scales with fill, not provisioned max_len
+        width = (self._read_width(int(kv_end.max())) if has_ctx
+                 else self.spec.max_pages)
+        with spans.span(spans.PREFILL, rows=batch, bucket=padded,
+                        ctx=int(has_ctx), width=width):
+            toks = np.zeros((batch, padded), np.int32)
+            pos = np.full((batch, padded), -1, np.int32)
+            for row, (slot, req, start, end) in enumerate(items):
+                n = end - start
+                toks[row, padded - n:] = req.prompt[start:end]
+                pos[row, padded - n:] = np.arange(start, end, dtype=np.int32)
+            slots = np.asarray([slot for slot, _, _, _ in items], np.int32)
+            if has_ctx:
+                paged = {"bt_rows": jnp.asarray(np.ascontiguousarray(
+                             self.pool.tables[slots][:, :width])),
+                         "slots": jnp.asarray(slots),
+                         "kv_len": jnp.asarray(kv_end)}
+            else:
+                paged = {"bt_rows": jnp.asarray(self.pool.tables[slots]),
+                         "slots": jnp.asarray(slots)}
+            if self.tp > 1:
+                logits, self.cache = _paged_prefill_tp_jit(
+                    self.cfg, self.mesh, self.params, jnp.asarray(toks),
+                    self.cache, jnp.asarray(pos), paged)
+            else:
+                logits, self.cache = _paged_prefill_jit(
+                    self.cfg, self.params, jnp.asarray(toks), self.cache,
+                    jnp.asarray(pos), paged)
+            if self.spec_decode:
+                # mirror the chunk into the draft cache so draft decode
+                # starts from the same fill state (its logits are discarded
+                # — the first token is always the target's)
+                _, self.draft_cache = _paged_prefill_jit(
+                    self.cfg, self.draft_params, jnp.asarray(toks),
+                    self.draft_cache, jnp.asarray(pos), paged)
+            self.n_prefills += 1
+            self.n_prefill_tokens += sum(end - start
+                                         for _, _, start, end in items)
+            finish = []
+            for row, (slot, req, start, end) in enumerate(items):
+                self.cur_len[slot] = end
+                if end >= req.n_prompt:
+                    finish.append(row)
+            if not finish:
+                return
+            keys = jnp.stack([jax.random.fold_in(self._first_key,
+                                                 items[row][1].rid)
+                              for row in finish])
+            first, fin_ok = _sample_first_jit(
+                logits[jnp.asarray(finish)], keys,
+                temperature=self.temperature, top_k=self.top_k)
+        with spans.span(spans.PREFILL_WAIT,
+                        rids=spans.ids(items[row][1].rid for row in finish)):
+            first, fin_ok = np.asarray(first), np.asarray(fin_ok)
         for tok, okf, row in zip(first, fin_ok, finish):
             slot, req, _, _ = items[row]
             del self._prefilling[slot]
@@ -1474,72 +1532,78 @@ class ContinuousEngine:
         K adapts to the smallest remaining budget among active requests
         (pow2-capped at decode_block) so slots retire exactly at a block
         boundary instead of idling through overshoot steps."""
-        act = self.active.copy()
-        self._key, sk = jax.random.split(self._key)
-        # min over *decoding* slots only: a mid-prefill request has its
-        # whole max_new outstanding and must not shrink everyone's block
-        remaining = min(req.max_new - len(req.tokens)
-                        for slot, req in enumerate(self.sched.slots)
-                        if req is not None and act[slot])
-        k_steps = min(self.decode_block,
-                      1 << (max(remaining, 1).bit_length() - 1))
-        # bucket the attention read width (pow2 pages over the deepest slot
-        # at block end) so shallow traffic doesn't pay max_len-wide gathers
-        width = self._read_width(int(self.cur_len[act].max()) + k_steps)
-        # host mirrors feed the jit directly — int32 end-to-end, no cast
-        # boundary where an int64 length could silently truncate
-        assert (self.cur_len.dtype == np.int32
-                and self.last_tok.dtype == np.int32), \
-            "engine host state drifted off the int32 jit contract"
-        poison = np.zeros(self.n_slots, bool)
-        if self._poison_slots:
-            for s in self._poison_slots:
-                poison[s] = True
-            self._poison_slots.clear()
-        kw = dict(k_steps=k_steps, page_size=self.spec.page_size,
-                  temperature=self.temperature, top_k=self.top_k)
+        with spans.span(spans.DECODE) as sp:
+            act = self.active.copy()
+            self._key, sk = jax.random.split(self._key)
+            # min over *decoding* slots only: a mid-prefill request has
+            # its whole max_new outstanding and must not shrink everyone's
+            # block
+            remaining = min(req.max_new - len(req.tokens)
+                            for slot, req in enumerate(self.sched.slots)
+                            if req is not None and act[slot])
+            k_steps = min(self.decode_block,
+                          1 << (max(remaining, 1).bit_length() - 1))
+            # bucket the attention read width (pow2 pages over the deepest
+            # slot at block end) so shallow traffic doesn't pay
+            # max_len-wide gathers
+            width = self._read_width(int(self.cur_len[act].max()) + k_steps)
+            # host mirrors feed the jit directly — int32 end-to-end, no cast
+            # boundary where an int64 length could silently truncate
+            assert (self.cur_len.dtype == np.int32
+                    and self.last_tok.dtype == np.int32), \
+                "engine host state drifted off the int32 jit contract"
+            poison = np.zeros(self.n_slots, bool)
+            if self._poison_slots:
+                for s in self._poison_slots:
+                    poison[s] = True
+                self._poison_slots.clear()
+            kw = dict(k_steps=k_steps, page_size=self.spec.page_size,
+                      temperature=self.temperature, top_k=self.top_k)
 
-        def dispatch():
-            # .copy(): the transfer of a host buffer may be deferred past
-            # this call's (async) dispatch, and the engine mutates these
-            # mirrors right after — handing jax the live array is a data
-            # race (the old .astype(int32) made an incidental copy; keep
-            # an explicit one). Rebuilt per attempt: donated buffers must
-            # not be reused by the fallback retry.
-            args = (self.params, self.cache,
-                    jnp.asarray(self.last_tok.copy()),
-                    jnp.asarray(self.cur_len.copy()), jnp.asarray(act),
-                    jnp.asarray(self.pool.tables[:, :width].copy()), sk,
-                    jnp.asarray(poison))
-            if self._kernel_fault:
-                # simulates the *fused* kernel failing to dispatch; once
-                # the engine has already degraded to the gather oracle
-                # there is no fused path left to fail, so the injection
-                # is consumed as a no-op
-                self._kernel_fault = False
-                if self.cfg.paged_attn_impl != "gather":
-                    raise KernelFaultInjected(
-                        "injected kernel dispatch failure")
-            if self.tp > 1:
-                return _paged_decode_scan_tp_jit(
-                    self.cfg, self.mesh, *args, **kw)
-            return _paged_decode_scan_jit(self.cfg, *args, **kw)
+            def dispatch():
+                # .copy(): the transfer of a host buffer may be deferred
+                # past this call's (async) dispatch, and the engine mutates
+                # these mirrors right after — handing jax the live array is
+                # a data race (the old .astype(int32) made an incidental
+                # copy; keep an explicit one). Rebuilt per attempt: donated
+                # buffers must not be reused by the fallback retry.
+                args = (self.params, self.cache,
+                        jnp.asarray(self.last_tok.copy()),
+                        jnp.asarray(self.cur_len.copy()), jnp.asarray(act),
+                        jnp.asarray(self.pool.tables[:, :width].copy()), sk,
+                        jnp.asarray(poison))
+                if self._kernel_fault:
+                    # simulates the *fused* kernel failing to dispatch;
+                    # once the engine has already degraded to the gather
+                    # oracle there is no fused path left to fail, so the
+                    # injection is consumed as a no-op
+                    self._kernel_fault = False
+                    if self.cfg.paged_attn_impl != "gather":
+                        raise KernelFaultInjected(
+                            "injected kernel dispatch failure")
+                if self.tp > 1:
+                    return _paged_decode_scan_tp_jit(
+                        self.cfg, self.mesh, *args, **kw)
+                return _paged_decode_scan_jit(self.cfg, *args, **kw)
 
-        try:
-            toks, alive, self.cache = dispatch()
-        except KernelFaultInjected:
-            # the injected kernel_fault (raised before the donated cache is
-            # consumed, so the retry is safe): permanently fall back to the
-            # gather oracle paged-attention path and retry once. Tokens are
-            # bitwise-identical (gather is the fused kernel's oracle); only
-            # bandwidth is lost, and the counter makes it visible. A real
-            # lowering error is not caught: it propagates.
-            self.cfg = self.cfg.replace(paged_attn_impl="gather")
-            self.n_kernel_fallbacks += 1
-            toks, alive, self.cache = dispatch()
-        self.cur_len[act] += k_steps
-        self.n_decode_steps += k_steps
-        return np.asarray(toks), np.asarray(alive)
+            try:
+                toks, alive, self.cache = dispatch()
+            except KernelFaultInjected:
+                # the injected kernel_fault (raised before the donated cache
+                # is consumed, so the retry is safe): permanently fall back
+                # to the gather oracle paged-attention path and retry once.
+                # Tokens are bitwise-identical (gather is the fused kernel's
+                # oracle); only bandwidth is lost, and the counter makes it
+                # visible. A real lowering error is not caught: it
+                # propagates.
+                self.cfg = self.cfg.replace(paged_attn_impl="gather")
+                self.n_kernel_fallbacks += 1
+                toks, alive, self.cache = dispatch()
+            self.cur_len[act] += k_steps
+            self.n_decode_steps += k_steps
+            sp.set_metadata(k=k_steps, slots=int(act.sum()), width=width)
+        with spans.span(spans.DECODE_WAIT):
+            return np.asarray(toks), np.asarray(alive)
 
     def _spec_block(self, act: np.ndarray, now: float) -> None:
         """One speculative round over all decoding slots.
@@ -1551,46 +1615,54 @@ class ContinuousEngine:
         degenerates to a single-row verify, i.e. plain decode). k adapts to
         the smallest remaining budget among active slots (pow2-bucketed
         like _decode_block to bound the compiled-shape count)."""
-        self._key, sk = jax.random.split(self._key)
-        remaining = min(req.max_new - len(req.tokens)
-                        for slot, req in enumerate(self.sched.slots)
-                        if req is not None and act[slot])
-        k_eff = min(self.spec_k, max(remaining - 1, 0))
-        if k_eff:
-            k_eff = 1 << (k_eff.bit_length() - 1)
-        m = k_eff + 1
-        width = self._read_width(int(self.cur_len[act].max()) + m)
-        assert (self.cur_len.dtype == np.int32
-                and self.last_tok.dtype == np.int32), \
-            "engine host state drifted off the int32 jit contract"
-        out, n_emit, self.cache, self.draft_cache = _spec_block_jit(
-            self.cfg, self.params, self.draft_params, self.cache,
-            self.draft_cache, jnp.asarray(self.last_tok.copy()),
-            jnp.asarray(self.cur_len.copy()), jnp.asarray(act),
-            jnp.asarray(self.pool.tables[:, :width].copy()), sk,
-            k_steps=k_eff, page_size=self.spec.page_size,
-            temperature=self.temperature, top_k=self.top_k)
-        out = np.asarray(out)
-        n_emit = np.asarray(n_emit)
+        with spans.span(spans.DECODE) as sp:
+            self._key, sk = jax.random.split(self._key)
+            remaining = min(req.max_new - len(req.tokens)
+                            for slot, req in enumerate(self.sched.slots)
+                            if req is not None and act[slot])
+            k_eff = min(self.spec_k, max(remaining - 1, 0))
+            if k_eff:
+                k_eff = 1 << (k_eff.bit_length() - 1)
+            m = k_eff + 1
+            width = self._read_width(int(self.cur_len[act].max()) + m)
+            assert (self.cur_len.dtype == np.int32
+                    and self.last_tok.dtype == np.int32), \
+                "engine host state drifted off the int32 jit contract"
+            out, n_emit, self.cache, self.draft_cache = _spec_block_jit(
+                self.cfg, self.params, self.draft_params, self.cache,
+                self.draft_cache, jnp.asarray(self.last_tok.copy()),
+                jnp.asarray(self.cur_len.copy()), jnp.asarray(act),
+                jnp.asarray(self.pool.tables[:, :width].copy()), sk,
+                k_steps=k_eff, page_size=self.spec.page_size,
+                temperature=self.temperature, top_k=self.top_k)
+            sp.set_metadata(k=k_eff, slots=int(act.sum()), width=width)
+        with spans.span(spans.DECODE_WAIT):
+            out = np.asarray(out)
+            n_emit = np.asarray(n_emit)
         act_idx = np.nonzero(act)[0]
         self.n_spec_rounds += 1
         self.n_decode_steps += 1         # one target forward per round
         self.n_draft_tokens += k_eff * act_idx.size
-        for slot in act_idx:
-            n = int(n_emit[slot])
-            self.spec_accept_sum[slot] += n
-            self.spec_round_count[slot] += 1
-            self.n_spec_emitted += n
-            # the cache holds positions 0..cur_len+n-1 = the old pending
-            # token plus the accepted drafts; the final emitted token stays
-            # unwritten (it is next round's last_tok), and the rejected
-            # tail beyond the new fill is dead by masking
-            self.cur_len[slot] += n
-            for t in range(n):
-                req = self.sched.slots[slot]
-                if req is None:          # retired mid-round (EOS/max_new)
-                    break
-                self._emit(slot, req, int(out[slot, t]), now)
+        with spans.span(spans.EMIT) as sp:
+            emitted = 0
+            for slot in act_idx:
+                n = int(n_emit[slot])
+                self.spec_accept_sum[slot] += n
+                self.spec_round_count[slot] += 1
+                self.n_spec_emitted += n
+                # the cache holds positions 0..cur_len+n-1 = the old pending
+                # token plus the accepted drafts; the final emitted token
+                # stays unwritten (it is next round's last_tok), and the
+                # rejected tail beyond the new fill is dead by masking
+                self.cur_len[slot] += n
+                for t in range(n):
+                    req = self.sched.slots[slot]
+                    if req is None:      # retired mid-round (EOS/max_new)
+                        break
+                    self._emit(slot, req, int(out[slot, t]), now)
+                    emitted += 1
+            self.n_decode_tokens += emitted
+            sp.set_metadata(tokens=emitted)
 
     def spec_stats(self) -> dict:
         """Acceptance accounting for speculative decoding: overall rate,
@@ -1617,8 +1689,10 @@ class ContinuousEngine:
     def _emit(self, slot: int, req: Request, tok: int, now: float) -> None:
         if req.first_token_at is None:
             req.first_token_at = now
+            self._stamped.append(req)
         req.tokens.append(tok)
         self.last_tok[slot] = tok
         if len(req.tokens) >= req.max_new or tok == self.eos_id:
             self.active[slot] = False
             self.sched.retire(slot, now)
+            self._stamped.append(req)

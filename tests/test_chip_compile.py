@@ -12,6 +12,8 @@ all collect the same tests.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -76,10 +78,19 @@ CASES = {
 }
 
 
+# the name each kernel's custom call carries (its `pallas_call` name): a
+# profiler trace names the kernel's op events after it
+KERNEL_NAMES = {"dequant": "dequant_matmul_pallas",
+                "w8a8": "w8a8_matmul_pallas",
+                "paged": "paged_attention_pallas"}
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_main_path_kernel_compiles_for_v5e(one_chip, case):
     kind, params = CASES[case]
     build = _matmul_args if kind == "matmul" else _paged_args
     fn, args = build(one_chip, *params)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    name = KERNEL_NAMES[params[0] if kind == "matmul" else kind]
+    assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\(", text), name
