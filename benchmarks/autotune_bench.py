@@ -110,7 +110,8 @@ def run(rows: list):
         for kind, m, k, n, bits, gs in SHAPES:
             key = autotune.matmul_key(kind, m, k, n, bits, gs)
             table = autotune.fallback_matmul_plan(
-                m, k, n, bits=bits, group_size=gs, bm=128, bn=256, bk=256)
+                m, k, n, kind=kind, bits=bits, group_size=gs, bm=128,
+                bn=256, bk=256)
             n_cands = len(autotune._matmul_candidates(m, k, n, bits, gs,
                                                       table))
             t0 = time.time()

@@ -117,7 +117,8 @@ def run(rows: list):
     qt_a = quantize(jax.random.normal(jax.random.PRNGKey(5),
                                       (ka, na)) * 0.05, bits_a, gs_a)
     xa = jax.random.normal(jax.random.PRNGKey(6), (ma, ka), jnp.float32)
-    table = autotune.fallback_matmul_plan(ma, ka, na, bits=bits_a,
+    table = autotune.fallback_matmul_plan(ma, ka, na, kind="dequant",
+                                          bits=bits_a,
                                           group_size=gs_a, bm=128, bn=256,
                                           bk=256)
     tuned = autotune._search_matmul("dequant", ma, ka, na, bits=bits_a,

@@ -4,9 +4,8 @@ table plus a measured autotuner with a persistent JSON config cache.
 Every kernel dispatch in `kernels/ops.py` asks this module for its tile
 plan. Resolution order, governed by ``REPRO_AUTOTUNE`` (debug_flags):
 
-  * ``"0"``        — always the deterministic fallback table (the former
-    hand heuristics, verbatim). CI and the compile-count sanitizer run
-    here implicitly: with no cache file the default mode degrades to the
+  * ``"0"``        — always the deterministic fallback table. CI and the
+    compile-count sanitizer run here implicitly: with no cache file the default mode degrades to the
     table, so replay-twice sees identical plans and zero new tracings.
   * ``""`` (default) — a warm cache entry for the shape class if the JSON
     cache (``REPRO_AUTOTUNE_CACHE``) is readable and was written by this
@@ -45,13 +44,23 @@ from repro.kernels import template
 _LOG = logging.getLogger(__name__)
 
 # ------------------------------------------------ deterministic fallback
-# (the former hand heuristics from kernels/ops.py, verbatim — the plans the
-# serving stack gets with a cold cache or REPRO_AUTOTUNE=0)
+# (the plans the serving stack gets with a cold cache or REPRO_AUTOTUNE=0)
 
-# decode-shaped tiles: minimal token rows, wide weight tiles
+# decode-shaped tiles of the expert and int8 kinds: minimal token rows,
+# wide weight tiles
 _SKINNY_M = 8
 _SKINNY_BN = 512
 _SKINNY_BK = 512
+
+# dense dequant at one row tile (padded M <= _ROW_TILE_M: every decode call
+# of up to 128 slots, and prefill calls as small): the whole token dim
+# rides in one block, and the weight block takes the most bk*bn values
+# under one budget. At 256x256 targets a decode call spent about half its
+# time in the fixed cost of its grid steps; on a v5e, at the benchmark's
+# W4 and W3 widths, blocks larger than 2**19 values were no faster
+# (PERF.md section 5)
+_ROW_TILE_M = 128
+_DEQUANT_BLOCK_VALUES = 1 << 19
 
 # paged-attention read-width regime: the page walk streams one KV tile per
 # grid step; small pages ride whole (the common serving geometry — page_size
@@ -109,18 +118,59 @@ def matmul_blocks(m: int, bm: int, bn: int, bk: int):
     return bm, bn, bk
 
 
-def fallback_matmul_plan(m: int, k: int, n: int, *, bits: int,
-                         group_size: int, bm: int, bn: int, bk: int):
-    """Tile regime by token count, then concrete (bm, bn, bk) blocks.
-    Returns None when K admits no valid block — callers fall back to the
-    jnp ref."""
+def _table_plan(kind: str, m: int, k: int, n: int, *, bits: int,
+                group_size: int, bm: int, bn: int, bk: int):
+    """(plan, regime name) from the deterministic table: the dense dequant
+    kind at one row tile takes `row_tile_plan`; every other call (and a
+    shape no row-tile plan fits) takes the regime by token count."""
+    if kind == "dequant" and m <= _ROW_TILE_M:
+        plan = row_tile_plan(m, k, n, bits=bits, group_size=group_size)
+        if plan is not None:
+            return plan, "row_tile"
+    regime = "skinny" if m <= _SKINNY_M else "prefill"
     gs = group_size if group_size != -1 else k
     vpg = pack_layout(bits)[1]
     bm, bn, bk = matmul_blocks(m, bm, bn, bk)
     bk_ = pick_bk(k, gs, vpg, bk)
     if bk_ is None:
+        return None, regime
+    return (pick_block(max(m, 8), bm), pick_block(n, bn), bk_), regime
+
+
+def fallback_matmul_plan(m: int, k: int, n: int, *, kind: str, bits: int,
+                         group_size: int, bm: int, bn: int, bk: int):
+    """Tile regime by kernel kind and token count, then concrete
+    (bm, bn, bk) blocks. Returns None when K admits no valid block —
+    callers fall back to the jnp ref."""
+    return _table_plan(kind, m, k, n, bits=bits, group_size=group_size,
+                       bm=bm, bn=bn, bk=bk)[0]
+
+
+def _lane_tiles(dim: int) -> list[int]:
+    """Divisors of `dim` a block may take along a lane axis: multiples of
+    128, or the whole dim."""
+    return sorted({d for d in range(128, dim, 128) if dim % d == 0} | {dim})
+
+
+def row_tile_plan(m: int, k: int, n: int, *, bits: int, group_size: int):
+    """(bm, bn, bk) for the dense dequant kernel when the token dim fits
+    one row tile: bm = M padded to 8, and the legal (bn, bk) holding the
+    most weight values with bk * bn <= _DEQUANT_BLOCK_VALUES; of equal
+    sizes, the wider N block. Divisors are searched directly (halving
+    would turn K = 128 * 107 into 128). Legal: bk divides K, packs whole
+    `pack_layout` groups into whole 32-row uint8 tiles, tiles the scale
+    groups and is a lane multiple (or K); bn divides N and is a lane
+    multiple (or N). None when no plan fits the budget."""
+    gs = group_size if group_size != -1 else k
+    bpg, vpg = pack_layout(bits)
+    bks = [b for b in _lane_tiles(k)
+           if b % vpg == 0 and (b == k or b // vpg * bpg % 32 == 0) and
+           ((gs >= b and gs % b == 0) or (gs < b and b % gs == 0))]
+    best = max(((bk * bn, bn, bk) for bk in bks for bn in _lane_tiles(n)
+                if bk * bn <= _DEQUANT_BLOCK_VALUES), default=None)
+    if best is None:
         return None
-    return pick_block(max(m, 8), bm), pick_block(n, bn), bk_
+    return -(-max(m, 1) // 8) * 8, best[1], best[2]
 
 
 def fallback_paged_tile(page_size: int) -> int:
@@ -156,11 +206,33 @@ def paged_key(page_size: int, kv_dtype: str, m_rows: int) -> str:
 # measured winners land here too (and on disk when a path is set)
 _state: dict = {"path": None, "entries": None}
 
+# every matmul plan issued in this process, by shape key. Plans are chosen
+# at trace time, so this is what a compiled program tiles with
+_issued: dict = {}
+
 
 def reset() -> None:
-    """Drop the in-memory cache (tests; after rewriting the cache file)."""
+    """Drop the in-memory cache and the issued-plan record (tests; after
+    rewriting the cache file)."""
     _state["path"] = None
     _state["entries"] = None
+    _issued.clear()
+
+
+def issued_plans() -> dict:
+    """{shape key: {"bm", "bn", "bk", "regime", "grid_steps"}} of the
+    plans `matmul_plan` has issued (a copy). `regime` is the table's
+    (row_tile, skinny, prefill), or cache / measured; `grid_steps` counts
+    one call's (M/bm, N/bn, K/bk) grid, per expert for the expert kinds."""
+    return {key: dict(v) for key, v in _issued.items()}
+
+
+def _issue(key: str, plan, regime: str, m: int, k: int, n: int):
+    if plan is not None:
+        bm, bn, bk = plan
+        _issued[key] = {"bm": bm, "bn": bn, "bk": bk, "regime": regime,
+                        "grid_steps": -(-m // bm) * (n // bn) * (k // bk)}
+    return plan
 
 
 def load_cache(path: str) -> dict:
@@ -409,20 +481,20 @@ def matmul_plan(kind: str, m: int, k: int, n: int, *, bits: int,
     """(bm, bn, bk) for one quantized-matmul dispatch, or None (no valid
     tiling: the caller takes the jnp reference). kind is the shape-class
     kernel family: dequant / expert_dequant / w8a8 / expert_w8a8."""
-    fallback = fallback_matmul_plan(m, k, n, bits=bits,
-                                    group_size=group_size, bm=bm, bn=bn,
-                                    bk=bk)
+    fallback, regime = _table_plan(kind, m, k, n, bits=bits,
+                                   group_size=group_size, bm=bm, bn=bn,
+                                   bk=bk)
+    key = matmul_key(kind, m, k, n, bits, group_size)
     mode = debug_flags.autotune_mode()
     if mode == "0":
-        return fallback
-    key = matmul_key(kind, m, k, n, bits, group_size)
+        return _issue(key, fallback, regime, m, k, n)
     entries = _entries()
     ent = entries.get(key)
     if ent is not None:
         plan = _valid_matmul_plan(ent, k=k, n=n, bits=bits,
                                   group_size=group_size)
         if plan is not None:
-            return plan
+            return _issue(key, plan, "cache", m, k, n)
         _LOG.warning("autotune entry %s = %r violates the tiling "
                      "constraints; ignoring it", key, ent)
     if mode == "1":
@@ -431,8 +503,8 @@ def matmul_plan(kind: str, m: int, k: int, n: int, *, bits: int,
         if plan is not None:
             entries[key] = {"bm": plan[0], "bn": plan[1], "bk": plan[2]}
             _persist(entries)
-        return plan
-    return fallback
+        return _issue(key, plan, "measured", m, k, n)
+    return _issue(key, fallback, regime, m, k, n)
 
 
 def paged_tile(page_size: int, kv_dtype: str, m_rows: int) -> int:

@@ -5,6 +5,7 @@ import json
 
 import pytest
 
+from repro.core.quant.types import pack_layout
 from repro.kernels import autotune, template
 
 
@@ -29,7 +30,8 @@ def test_cold_cache_resolution_is_the_fallback_table(monkeypatch):
     for kind in ("dequant", "expert_dequant", "w8a8", "expert_w8a8"):
         for m, k, n, bits, gs in shapes:
             want = autotune.fallback_matmul_plan(
-                m, k, n, bits=bits, group_size=gs, bm=128, bn=256, bk=256)
+                m, k, n, kind=kind, bits=bits, group_size=gs, bm=128,
+                bn=256, bk=256)
             got = autotune.matmul_plan(kind, m, k, n, bits=bits,
                                        group_size=gs)
             assert got == want
@@ -47,8 +49,9 @@ def test_mode_zero_ignores_a_warm_cache(tmp_path, monkeypatch):
     autotune.save_cache(path, {key: {"bm": 8, "bn": 128, "bk": 64}})
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", path)
     monkeypatch.setenv("REPRO_AUTOTUNE", "0")
-    want = autotune.fallback_matmul_plan(4, 128, 256, bits=4, group_size=32,
-                                         bm=128, bn=256, bk=256)
+    want = autotune.fallback_matmul_plan(4, 128, 256, kind="dequant", bits=4,
+                                         group_size=32, bm=128, bn=256,
+                                         bk=256)
     assert autotune.matmul_plan("dequant", 4, 128, 256, bits=4,
                                 group_size=32) == want
 
@@ -64,6 +67,124 @@ def test_pick_bk_per_channel_fast_path():
     assert autotune.pick_bk(1012, 1012, 8, 256) is None
     # no >= 8-row divisor under the target: one whole-K block
     assert autotune.pick_bk(1012, 1012, 4, 8) == 1012
+
+
+# ------------------------------------------ row-tile plans at cell widths
+
+# (projection, bits, K, N, most grid steps one call may take) of every
+# quantized projection in a block of the benchmark's two configurations:
+# ChatGLM3-6B at W4 g128 (d 4096, 2 KV heads of 128, d_ff 13696 =
+# 128 * 107) and Granite-20B at W3 g128 (d 6144, one KV head, d_ff 24576)
+GLM_W4 = [("q", 4, 4096, 4096, 32), ("k", 4, 4096, 256, 2),
+          ("v", 4, 4096, 256, 2), ("o", 4, 4096, 4096, 32),
+          ("gate", 4, 4096, 13696, 107), ("up", 4, 4096, 13696, 107),
+          ("down", 4, 13696, 4096, 107)]
+GRANITE_W3 = [("q", 3, 6144, 6144, 72), ("k", 3, 6144, 128, 2),
+              ("v", 3, 6144, 128, 2), ("o", 3, 6144, 6144, 72),
+              ("up", 3, 6144, 24576, 288), ("down", 3, 24576, 6144, 288)]
+CELL_PROJECTIONS = ([("glm",) + p for p in GLM_W4] +
+                    [("granite",) + p for p in GRANITE_W3])
+# dequant grid steps of one block at one row tile (the table gave 5680
+# and 5808 before the row-tile regime)
+MAX_STEPS_PER_LAYER = {"glm": 389, "granite": 724}
+
+
+def _assert_legal(plan, m, k, n, bits, gs):
+    bm, bn, bk = plan
+    bpg, vpg = pack_layout(bits)
+    assert bm >= m and bm % 8 == 0
+    assert n % bn == 0 and (bn % 128 == 0 or bn == n)
+    assert k % bk == 0 and (bk % 128 == 0 or bk == k)
+    assert bk % vpg == 0 and (bk // vpg * bpg % 32 == 0 or bk == k)
+    assert (gs >= bk and gs % bk == 0) or (gs < bk and bk % gs == 0)
+
+
+@pytest.mark.parametrize("m", [64, 128])
+@pytest.mark.parametrize("cell,proj,bits,k,n,max_steps", CELL_PROJECTIONS,
+                         ids=[f"{c[0]}-{c[1]}" for c in CELL_PROJECTIONS])
+def test_row_tile_plan_at_cell_projections(m, cell, proj, bits, k, n,
+                                           max_steps):
+    """A 64-slot decode call, and a 128-row call, at every projection of
+    the benchmark cells takes the row-tile regime: one row tile, a legal
+    weight block within the budget, and at most the stated grid steps."""
+    plan = autotune.matmul_plan("dequant", m, k, n, bits=bits,
+                                group_size=128)
+    _assert_legal(plan, m, k, n, bits, 128)
+    bm, bn, bk = plan
+    assert bm == m
+    assert bk * bn <= autotune._DEQUANT_BLOCK_VALUES
+    rec = autotune.issued_plans()[
+        autotune.matmul_key("dequant", m, k, n, bits, 128)]
+    assert rec == {"bm": bm, "bn": bn, "bk": bk, "regime": "row_tile",
+                   "grid_steps": (n // bn) * (k // bk)}
+    assert rec["grid_steps"] <= max_steps
+
+
+@pytest.mark.parametrize("cell,projs", [("glm", GLM_W4),
+                                        ("granite", GRANITE_W3)])
+def test_row_tile_grid_steps_per_layer(cell, projs):
+    """A decode step's dequant grid steps in one block, from the plans the
+    table issued (read back through `issued_plans`)."""
+    steps = 0
+    for _, bits, k, n, _ in projs:
+        autotune.matmul_plan("dequant", 64, k, n, bits=bits, group_size=128)
+        steps += autotune.issued_plans()[autotune.matmul_key(
+            "dequant", 64, k, n, bits, 128)]["grid_steps"]
+    assert steps <= MAX_STEPS_PER_LAYER[cell]
+
+
+def test_row_tile_searches_divisors_not_halvings():
+    """K = 128 * 107 (ChatGLM3's d_ff): halving from 256 lands on 128 for
+    both axes; the divisor search reaches the whole 4096-wide N block and
+    the whole 4096-deep K block of the transposed shape, and of equal
+    sizes takes the wider N block."""
+    assert autotune.row_tile_plan(64, 13696, 4096, bits=4,
+                                  group_size=128) == (64, 4096, 128)
+    assert autotune.row_tile_plan(64, 4096, 13696, bits=4,
+                                  group_size=128) == (64, 128, 4096)
+    assert autotune.row_tile_plan(64, 4096, 4096, bits=4,
+                                  group_size=128) == (64, 4096, 128)
+    # bm is the token count padded to whole 8-row sublanes
+    assert autotune.row_tile_plan(1, 256, 128, bits=4,
+                                  group_size=64)[0] == 8
+    assert autotune.row_tile_plan(100, 256, 128, bits=4,
+                                  group_size=64)[0] == 104
+    # W3: a 128-value K block is 48 packed rows, not whole 32-row uint8
+    # tiles, so the widest block W4 takes at this shape is not legal
+    assert autotune.row_tile_plan(64, 6144, 4096, bits=3,
+                                  group_size=128) == (64, 2048, 256)
+    assert autotune.row_tile_plan(64, 6144, 4096, bits=4,
+                                  group_size=128) == (64, 4096, 128)
+    # only whole-dim blocks are legal and they exceed the budget: no
+    # row-tile plan, and the table's token-count regime takes the call
+    assert autotune.row_tile_plan(64, 8008, 200, bits=4,
+                                  group_size=-1) is None
+    assert autotune.matmul_plan("dequant", 64, 8008, 200, bits=4,
+                                group_size=-1) == (64, 200, 182)
+    assert autotune.issued_plans()[autotune.matmul_key(
+        "dequant", 64, 8008, 200, 4, -1)]["regime"] == "prefill"
+
+
+# plans of the other kinds and of M > 128, as the table gave them before
+# the row-tile regime existed
+UNCHANGED = [
+    ("w8a8", 64, 4096, 13696, 4, (64, 128, 256)),
+    ("w8a8", 8, 4096, 4096, 4, (8, 512, 512)),
+    ("expert_dequant", 64, 13696, 4096, 4, (64, 256, 128)),
+    ("expert_w8a8", 128, 6144, 24576, 3, (128, 256, 256)),
+    ("dequant", 256, 4096, 13696, 4, (128, 128, 256)),
+    ("dequant", 512, 13696, 4096, 4, (128, 256, 128)),
+    ("dequant", 256, 6144, 24576, 3, (128, 256, 256)),
+]
+
+
+@pytest.mark.parametrize("kind,m,k,n,bits,want", UNCHANGED)
+def test_other_kinds_and_large_m_keep_the_table(kind, m, k, n, bits, want):
+    assert autotune.matmul_plan(kind, m, k, n, bits=bits,
+                                group_size=128) == want
+    rec = autotune.issued_plans()[autotune.matmul_key(kind, m, k, n, bits,
+                                                      128)]
+    assert rec["regime"] == ("skinny" if m <= 8 else "prefill")
 
 
 # ------------------------------------------------------- shape-class keys
@@ -99,8 +220,9 @@ def test_cache_round_trip(tmp_path, monkeypatch):
     assert autotune.matmul_plan("dequant", 4, 128, 256, bits=4,
                                 group_size=32) == (8, 128, 64)
     # shape classes not in the cache still resolve from the table
-    want = autotune.fallback_matmul_plan(4, 128, 256, bits=4, group_size=64,
-                                         bm=128, bn=256, bk=256)
+    want = autotune.fallback_matmul_plan(4, 128, 256, kind="dequant", bits=4,
+                                         group_size=64, bm=128, bn=256,
+                                         bk=256)
     assert autotune.matmul_plan("dequant", 4, 128, 256, bits=4,
                                 group_size=64) == want
 
@@ -108,8 +230,9 @@ def test_cache_round_trip(tmp_path, monkeypatch):
 def test_missing_cache_file_is_cold(tmp_path, monkeypatch):
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", str(tmp_path / "absent.json"))
     monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
-    want = autotune.fallback_matmul_plan(1, 128, 256, bits=4, group_size=32,
-                                         bm=128, bn=256, bk=256)
+    want = autotune.fallback_matmul_plan(1, 128, 256, kind="dequant", bits=4,
+                                         group_size=32, bm=128, bn=256,
+                                         bk=256)
     assert autotune.matmul_plan("dequant", 1, 128, 256, bits=4,
                                 group_size=32) == want
 
@@ -139,8 +262,9 @@ def test_corrupt_cache_falls_back(tmp_path, caplog, content, monkeypatch):
     assert "unreadable" in caplog.text or "template" in caplog.text
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", path)
     monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
-    want = autotune.fallback_matmul_plan(4, 128, 256, bits=4, group_size=32,
-                                         bm=128, bn=256, bk=256)
+    want = autotune.fallback_matmul_plan(4, 128, 256, kind="dequant", bits=4,
+                                         group_size=32, bm=128, bn=256,
+                                         bk=256)
     assert autotune.matmul_plan("dequant", 4, 128, 256, bits=4,
                                 group_size=32) == want
 
@@ -156,8 +280,9 @@ def test_invalid_cached_entry_is_revalidated_away(tmp_path, monkeypatch,
                                pk: {"tile": 13}})
     monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", path)
     monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
-    want = autotune.fallback_matmul_plan(4, 128, 256, bits=4, group_size=32,
-                                         bm=128, bn=256, bk=256)
+    want = autotune.fallback_matmul_plan(4, 128, 256, kind="dequant", bits=4,
+                                         group_size=32, bm=128, bn=256,
+                                         bk=256)
     with caplog.at_level("WARNING"):
         assert autotune.matmul_plan("dequant", 4, 128, 256, bits=4,
                                     group_size=32) == want

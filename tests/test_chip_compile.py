@@ -40,9 +40,9 @@ def one_chip():
 
 
 def _matmul_args(sharding, kind, bits, gs, m, k, n):
-    bm, bn, bk = autotune.fallback_matmul_plan(m, k, n, bits=bits,
-                                               group_size=gs, bm=128,
-                                               bn=256, bk=256)
+    bm, bn, bk = autotune.fallback_matmul_plan(m, k, n, kind=kind,
+                                               bits=bits, group_size=gs,
+                                               bm=128, bn=256, bk=256)
     g = 1 if gs == -1 else k // gs
     x_dtype = jnp.int8 if kind == "w8a8" else jnp.bfloat16
     shapes = [((max(m, bm), k), x_dtype), ((packed_rows(k, bits), n),
@@ -72,6 +72,15 @@ CASES = {
     "dequant-w4-perchannel-m8": ("matmul", ("dequant", 4, -1, 8, D, FF)),
     "dequant-w4-g128-m8": ("matmul", ("dequant", 4, 128, 8, FF, D)),
     "w8a8-w4-g128-m128": ("matmul", ("w8a8", 4, 128, 128, D, FF)),
+    # the row-tile plans at the benchmark cells' widest projections:
+    # ChatGLM3-6B W4 down 13696 -> 4096 and gate 4096 -> 13696 (13696 =
+    # 128 * 107), Granite-20B W3 up 6144 -> 24576
+    "dequant-w4-g128-m64-glm-down": ("matmul",
+                                     ("dequant", 4, 128, 64, 13696, 4096)),
+    "dequant-w4-g128-m128-glm-gate": ("matmul",
+                                      ("dequant", 4, 128, 128, 4096, 13696)),
+    "dequant-w3-g128-m64-granite-up": ("matmul",
+                                       ("dequant", 3, 128, 64, 6144, 24576)),
     "paged-decode-bf16": ("paged", (1, 16)),
     "paged-verify-int8": ("paged", (5, 8)),
     "paged-prefill-m16-bf16": ("paged", (16, 16)),

@@ -19,7 +19,7 @@ import pytest
 from repro.core.quant.types import (compute_scales, dequantize, pack_layout,
                                     quantize, quantize_activation,
                                     quantize_stacked)
-from repro.kernels import ops, ref
+from repro.kernels import autotune, ops, ref
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.paged_harness import (build_paged_case, build_prefill_case,
                                          build_verify_case, gather_oracle,
@@ -30,8 +30,11 @@ from repro.serve.kvcache import gather_dequant_pages, gather_pages
 
 BITS = [2, 3, 4, 8]
 GROUPS = [-1, 32, 64, 128]
-# (M, K, N): M=1/3 decode-skinny rows, ragged (non-pow2-tile) K/N mixes
-DENSE_SHAPES = [(1, 128, 64), (3, 256, 80), (8, 128, 192)]
+# (M, K, N): M=1/3 decode-skinny rows, ragged (non-pow2-tile) K/N mixes;
+# M=64 decode slots at K = 128 * 7, an odd factor like ChatGLM3's
+# d_ff = 128 * 107, whose row-tile plan takes the whole 896-deep K block
+# in four 512-wide N blocks
+DENSE_SHAPES = [(1, 128, 64), (3, 256, 80), (8, 128, 192), (64, 896, 2048)]
 # (E, C, K, N): C=5 forces capacity-dim padding inside the wrapper
 EXPERT_SHAPES = [(2, 5, 128, 64), (3, 8, 256, 96)]
 W8A8_SHAPES = [(1, 128, 96), (7, 256, 64)]
@@ -50,7 +53,11 @@ def test_dense_parity(bits, gs, mkn):
     x = jax.random.normal(kx, (m, k), jnp.float32)
     w = jax.random.normal(kw, (k, n), jnp.float32) * 0.1
     qt = quantize(w, bits, gs)
+    autotune.reset()
     y_pal = ops.dequant_matmul(x, qt)                  # pallas interpret
+    # every dense shape here fits one row tile
+    assert autotune.issued_plans()[autotune.matmul_key(
+        "dequant", m, k, n, bits, gs)]["regime"] == "row_tile"
     y_ref = ref.dequant_matmul_ref(x, qt.qw, qt.scale, bits=bits,
                                    group_size=gs, k=k)
     y_ein = jnp.einsum("mk,kn->mn", x.astype(jnp.bfloat16),
@@ -133,7 +140,9 @@ def test_expert_w8a8_parity(bits, gs, eckn):
 
 def test_skinny_decode_blocks_selected():
     """M <= 8 picks the decode tile regime: bm stays at the minimal 8-row
-    tile while bn/bk widen (no padding up to prefill tiles)."""
+    tile while bn/bk widen (no padding up to prefill tiles). The expert
+    and int8 kinds take it; the dense dequant kind takes its row-tile plan
+    instead, and reaches this regime only where no row-tile plan fits."""
     assert ops._matmul_blocks(1, 128, 256, 256) == (8, 512, 512)
     assert ops._matmul_blocks(8, 128, 256, 256) == (8, 512, 512)
     assert ops._matmul_blocks(9, 128, 256, 256) == (128, 256, 256)
