@@ -8,7 +8,8 @@ logits of that choice. Two readings of the difference
 
     best reference logit - reference logit of the served token
 
-which is 0 where greedy decoding agrees with the reference: its largest
+which is 0 where greedy decoding agrees with the reference (the
+configuration's reference module, its `logits`): its largest
 value over the served tokens (`gap_max`) and its mean over them
 (`gap_mean`). A cell's workload file names the one it compares
 (`compare`) and its limit; both are printed. The control reads the same
@@ -23,8 +24,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from references import dense
 
 # sequences are padded to a multiple of this many tokens, so the
 # reference compiles for a few lengths only
@@ -70,22 +69,23 @@ def _gaps(ref_logits, chosen):
     return best - picked
 
 
-def served_gaps(a: dense.Arch, seed: int, reqs: list[Served],
+def served_gaps(ref, a, seed: int, reqs: list[Served],
                 control: Optional[str] = None) -> dict:
-    """Readings of the served tokens of `reqs`, and with `control` (a
-    dtype name) those of the control's first choices under `control_`
-    names. With no request to compare, no position is read (the position
-    check fails)."""
+    """Readings of the served tokens of `reqs` against the reference
+    module `ref` of the model `a`, and with `control` (a dtype name)
+    those of the control's first choices under `control_` names. With no
+    request to compare, no position is read (the position check fails)."""
     if not reqs:
         return {"positions": 0, "gap_max": 0.0, "gap_mean": 0.0}
     toks, rows, cols, served = _batch(reqs)
-    ref = dense.logits(a, seed, toks, rows, cols)
+    ref_logits = ref.logits(a, seed, toks, rows, cols)
     out = {"positions": int(len(served))}
-    out["gap_max"], out["gap_mean"] = _read(ref, jnp.asarray(served))
+    out["gap_max"], out["gap_mean"] = _read(ref_logits,
+                                             jnp.asarray(served))
     if control is not None:
-        ctl = dense.logits(a, seed, toks, rows, cols, dtype=control)
+        ctl = ref.logits(a, seed, toks, rows, cols, dtype=control)
         out["control_gap_max"], out["control_gap_mean"] = _read(
-            ref, jnp.argmax(ctl, axis=-1).astype(jnp.int32))
+            ref_logits, jnp.argmax(ctl, axis=-1).astype(jnp.int32))
     return out
 
 

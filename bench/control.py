@@ -5,11 +5,11 @@
 For each seed, in one process, a whole run of the cell (weights from the
 seed, pre-roll, window, drain) whose output check puts the control in
 the program's place: the plain reference computed in float8 (e4m3), the
-precision below the configuration's bfloat16 (bench/references/dense.py),
-read for the tokens the control ranks first. The run is judged by the
-cell's own limits through the harness, so a sound limit reports
-`correct: false` for it; the program's readings of the same sample are
-printed beside. A limit goes between the largest program reading and
+precision below the configuration's bfloat16 (the reference module's
+`logits` with `dtype`), read for the tokens the control ranks first.
+The run is judged by the cell's own limits through the harness, so a
+sound limit reports `correct: false` for it; the program's readings of
+the same sample are printed beside. A limit goes between the largest program reading and
 the smallest control reading. Needs the chip, like bench/run.py; the
 benchmark's own runs never run the control.
 """

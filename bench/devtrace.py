@@ -126,18 +126,26 @@ def op_seconds(tr: Trace) -> dict[str, float]:
     return dict(out)
 
 
-def matching(tr: Trace, needle: str, *, modules: bool = False
-             ) -> tuple[int, float]:
-    """(count, device seconds) of the op or program events whose name
-    contains `needle`, over all chips."""
-    src = tr.modules if modules else tr.ops
+def _total(src: dict[str, list[Event]], keep) -> tuple[int, float]:
     n, s = 0, 0.0
     for ev in src.values():
         for e in ev:
-            if needle in e.name:
+            if keep(e.name):
                 n += 1
                 s += e.dur_ns / 1e9
     return n, s
+
+
+def kernel(tr: Trace, name: str) -> tuple[int, float]:
+    """(count, device seconds) of the op events of the kernel `name`
+    (their `op_kind`, compared whole), over all chips."""
+    return _total(tr.ops, lambda op: op_kind(op) == name)
+
+
+def programs(tr: Trace, needle: str) -> tuple[int, float]:
+    """(count, device seconds) of the program events whose name contains
+    `needle`, over all chips."""
+    return _total(tr.modules, lambda prog: needle in prog)
 
 
 def idle_gaps(tr: Trace, top: int = 10) -> list[tuple[str, float]]:

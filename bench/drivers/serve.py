@@ -292,19 +292,20 @@ def window(eng, tracker, items, seconds: float, drain_s: float, annotate,
 
 def prepare(config: dict, workload: dict, seed: int):
     """Weights, engine and warm-up: the set-up of a serving cell.
-    Returns (Arch, engine)."""
+    Returns (reference module, its description of the model, engine)."""
     import jax
 
     from repro.serve.engine import ContinuousEngine
 
-    a = harness.reference(config).arch(config)
+    ref = harness.reference(config)
+    a = ref.arch(config)
     cfg = program_config(config)
     lens = traffic.length_range(workload["traffic"])
-    eng = ContinuousEngine(cfg, weights.build_served(a, cfg, seed),
+    eng = ContinuousEngine(cfg, weights.build_served(ref, a, cfg, seed),
                            max_len=lens[1] + lens[3], **workload["engine"])
     warm(eng, lens, a.vocab)
     jax.block_until_ready(eng.cache)
-    return a, eng
+    return ref, a, eng
 
 
 def run(*, config: dict, workload: dict, seed: int, seconds: float,
@@ -317,7 +318,7 @@ def run(*, config: dict, workload: dict, seed: int, seconds: float,
 
     from repro.analysis import sanitize
 
-    a, eng = prepare(config, workload, seed)
+    ref, a, eng = prepare(config, workload, seed)
     spec = workload["traffic"]
     items = traffic.make(spec, seed, seconds, a.vocab)
     tracker = submit(eng, items, trace)
@@ -376,8 +377,8 @@ def run(*, config: dict, workload: dict, seed: int, seconds: float,
     gc.collect()
 
     cc = workload["check"]
-    reading = check.served_gaps(a, seed, check.sample(served, seed,
-                                                      cc["requests"]),
+    reading = check.served_gaps(ref, a, seed,
+                                check.sample(served, seed, cc["requests"]),
                                 control=control)
     print(f"bench: check readings {reading}", flush=True)
     key = cc["compare"]
@@ -395,8 +396,8 @@ def run(*, config: dict, workload: dict, seed: int, seconds: float,
         setup_s=marks["setup_s"], window_s=window_s,
         attempted=len(attempted), failed=failed, checks=checks,
         memory_peak_bytes=peak, peaks=peaks,
-        data={"records": recs, "tracker": tracker, "arch": a,
-              "seconds": seconds, "drain": drain,
+        data={"records": recs, "tracker": tracker, "reference": ref,
+              "arch": a, "seconds": seconds, "drain": drain,
               "n_slots": workload["engine"]["n_slots"],
               "loop_end_s": end_s, "readings": reading},
         trace=tr)

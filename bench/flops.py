@@ -5,46 +5,11 @@ benchmark reports: the least time a call could take on a chip is
 max(flops / peak FLOP/s, bytes / HBM bytes/s), with the peaks from
 bench/peaks.json. Bytes count each operand read once and each result
 written once, as the algorithm needs them; padding, re-reads and
-dead work count as the kernel's own overhead, not as work.
+dead work count as the kernel's own overhead, not as work. A model's own
+FLOPs, and the kernel calls a step makes, come from the configuration's
+reference module (bench/harness.py lists its yardstick).
 """
 from __future__ import annotations
-
-from references.dense import Arch
-
-
-def projections(a: Arch) -> list[tuple[int, int]]:
-    """(K, N) of every quantized projection of one block."""
-    q, kv = a.heads * a.head_dim, a.kv_heads * a.head_dim
-    mlp = [(a.d, a.ff)] * (2 if a.act == "swiglu" else 1) + [(a.ff, a.d)]
-    return [(a.d, q), (a.d, kv), (a.d, kv), (q, a.d)] + mlp
-
-
-def matmul_params_per_block(a: Arch) -> int:
-    return sum(k * n for k, n in projections(a))
-
-
-def token_flops(a: Arch, context: int) -> float:
-    """Model FLOPs of one token at `context` tokens of attention context
-    (itself included): two per multiply-add of every projection and of
-    the output head, and 4 * head_dim per head and context token for
-    the scores and the weighted values. Embedding lookups are free."""
-    dense = a.layers * matmul_params_per_block(a) + a.d * a.vocab
-    attn = a.layers * 4 * a.heads * a.head_dim * context
-    return 2.0 * dense + attn
-
-
-def prompt_flops(a: Arch, start: int, end: int) -> float:
-    """Model FLOPs of the blocks on prompt positions start..end-1 (causal:
-    position p attends to p + 1 tokens). The head runs on a prompt's last
-    position only: add `head_flops` once per prompt."""
-    n = end - start
-    ctx_sum = (start + 1 + end) * n / 2.0
-    dense = a.layers * matmul_params_per_block(a)
-    return 2.0 * dense * n + a.layers * 4 * a.heads * a.head_dim * ctx_sum
-
-
-def head_flops(a: Arch) -> float:
-    return 2.0 * a.d * a.vocab
 
 
 def dequant_matmul_cost(m: int, k: int, n: int, bits: int,

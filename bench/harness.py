@@ -12,6 +12,39 @@ quantity split by the end-to-end metric it moves, `<q>.<part>` (the same
 reading in cells that report different end-to-end metrics), is read by
 `bench/metrics/<q>.<part>.py` where that file exists, else by
 `bench/metrics/<q>.py`.
+
+The reference module is the one place that knows a model; the harness,
+the drivers, the output check and the metric readers reach the model only
+through these names of it (`REFERENCE`), so a configuration of another
+architecture joins by adding files:
+
+  arch(config)         a frozen, hashable description; the harness reads
+                       only its `vocab`, `bits` and `group_size`
+  check_widths(a, cfg) raise ValueError unless the program's ModelConfig
+                       runs the configuration's shapes
+  base_key(seed)       the PRNG key of any seed
+  global_leaves(a)     path -> (shape, kind) of the leaves outside blocks
+  groups(a)            [(path, layers, leaves)]: each block group of the
+                       program's param tree, "stack/p<j>" (blocks stacked
+                       on a leading axis) or "prefix/<i>" (one block); the
+                       global layer number of each block, prefix blocks
+                       first; path -> (shape, kind) of one block's leaves
+  draw(key, layer, path, shape, kind)
+                       the float32 weight (layer -1: outside the blocks)
+  logits(a, seed, tokens, rows, cols, dtype=None)
+                       the plain float32 reference at `highest` precision
+                       at the (row, col) positions of tokens; with `dtype`
+                       the lower-precision control
+  token_flops(a, context), prompt_flops(a, start, end), head_flops(a)
+                       model FLOPs of a decoded token, a prompt span, the
+                       head (bench/flops.py counts the kernels' own)
+  dequant_projections(a)  [(K, N, calls per step)] of the dense packed
+                       dequant matmuls a step makes, over all layers
+  paged_reads(a)       [(KV heads, group, head dim, calls per step)] of the
+                       paged attention reads a step makes
+
+Weight kinds "linear" (K, N) and "experts" (E, K, N) are served packed
+(bench/weights.py); every other kind in the configuration's param dtype.
 """
 from __future__ import annotations
 
@@ -70,8 +103,18 @@ def cell_metrics(spec: dict, cell: str, trace: bool) -> list[dict]:
                 else m["moves"] in moved)]
 
 
+REFERENCE = ("arch", "check_widths", "base_key", "global_leaves", "groups",
+             "draw", "logits", "token_flops", "prompt_flops", "head_flops",
+             "dequant_projections", "paged_reads")
+
+
 def reference(config: dict):
-    return importlib.import_module(f"references.{config['model_type']}")
+    mod = importlib.import_module(f"references.{config['model_type']}")
+    missing = [n for n in REFERENCE if not hasattr(mod, n)]
+    if missing:
+        raise SystemExit(f"bench: bench/references/{config['model_type']}"
+                         f".py lacks {missing}")
+    return mod
 
 
 def _load_file(kind: str, name: str):

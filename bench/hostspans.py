@@ -274,7 +274,7 @@ def traced_run(*, config: dict, workload: dict, seed: int, seconds: float,
     import traffic
 
     serve = harness.driver(workload["driver"])
-    a, eng = serve.prepare(config, workload, seed)
+    ref, a, eng = serve.prepare(config, workload, seed)
     spec = workload["traffic"]
     items = traffic.make(spec, seed, seconds, a.vocab)
     tracker = serve.submit(eng, items, True)
@@ -319,8 +319,8 @@ def traced_run(*, config: dict, workload: dict, seed: int, seconds: float,
         failed=sum(1 for r in attempted if r.failed), checks=[],
         memory_peak_bytes=int(mem.get("peak_bytes_in_use", 0)),
         peaks=peaks,
-        data={"records": recs, "tracker": tracker, "arch": a,
-              "seconds": seconds, "drain": drain_s > 0,
+        data={"records": recs, "tracker": tracker, "reference": ref,
+              "arch": a, "seconds": seconds, "drain": drain_s > 0,
               "n_slots": workload["engine"]["n_slots"], "loop_end_s": end_s,
               "compiles_in_window": compiles.count},
         trace=tr)

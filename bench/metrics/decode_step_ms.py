@@ -9,7 +9,7 @@ def read(run):
     t = run.data.get("tracker")
     if run.trace is None or t is None or not t.decode_steps:
         return None
-    n, secs = devtrace.matching(run.trace, PROGRAM, modules=True)
+    n, secs = devtrace.programs(run.trace, PROGRAM)
     if not n:
         return None
     return 1e3 * secs / t.decode_steps

@@ -3,7 +3,9 @@
 channels, bias on the QKV projection only, SwiGLU MLP, untied head."""
 from __future__ import annotations
 
-from references.dense import Arch
+from references.dense import (  # noqa: F401  (the reference contract)
+    Arch, base_key, check_widths, dequant_projections, draw, global_leaves,
+    groups, head_flops, logits, paged_reads, prompt_flops, token_flops)
 
 
 def arch(c: dict) -> Arch:

@@ -20,7 +20,9 @@ that the output check must reject.
 
 The layer equations follow the published descriptions of the model
 families in bench/references/<model_type>.py, which map a configuration
-file onto `Arch`.
+file onto `Arch` and re-export what bench/harness.py asks of a reference
+module: the width check, the weight layout and law, `logits`, and the
+FLOPs and kernel shapes of a step (the yardstick of bench/flops.py).
 """
 from __future__ import annotations
 
@@ -74,6 +76,26 @@ def base_key(seed: int) -> jax.Array:
             return k
 
 
+def check_widths(a: Arch, cfg) -> None:
+    """The program's model config must run the configuration's shapes."""
+    want = {"n_repeats": a.layers, "d_model": a.d, "n_heads": a.heads,
+            "n_kv_heads": a.kv_heads, "hd": a.head_dim, "d_ff": a.ff,
+            "vocab_size": a.vocab, "norm": {"rms": "rmsnorm",
+                                            "ln": "layernorm"}[a.norm],
+            "norm_eps": a.eps, "act": {"swiglu": "silu",
+                                       "gelu_tanh": "gelu"}[a.act],
+            "rope": a.rope, "qkv_bias": a.qkv_bias, "o_bias": a.o_bias,
+            "mlp_bias": a.mlp_bias,
+            "pos_emb": "learned" if a.learned_positions else "none"}
+    if a.learned_positions:
+        want["max_position"] = a.learned_positions
+    got = {k: getattr(cfg, k) for k in want}
+    bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
+    if bad or len(cfg.pattern) != 1 or cfg.prefix_pattern:
+        raise ValueError(f"program config {cfg.name} departs from the "
+                         f"configuration file (program, file): {bad}")
+
+
 def layer_leaves(a: Arch) -> dict[str, tuple[tuple[int, ...], str]]:
     """Path -> (shape, kind) of one block's weights, in the program's
     param-tree naming (bench/weights.py places each draw at its path)."""
@@ -93,6 +115,12 @@ def layer_leaves(a: Arch) -> dict[str, tuple[tuple[int, ...], str]]:
         if bias:
             out[path + "/b"] = ((n,), "bias")
     return out
+
+
+def groups(a: Arch) -> list[tuple[str, tuple[int, ...], dict]]:
+    """The param tree's block groups: every block is one entry of the
+    program's scanned stack `stack/p0`, layers 0..L-1."""
+    return [("stack/p0", tuple(range(a.layers)), layer_leaves(a))]
 
 
 def global_leaves(a: Arch) -> dict[str, tuple[tuple[int, ...], str]]:
@@ -261,3 +289,52 @@ def logits(a: Arch, seed: int, tokens: np.ndarray, rows: np.ndarray,
                         dtype=dtype)
     h = x[jnp.asarray(rows), jnp.asarray(cols)]
     return head(a, g, h, dtype=dtype)
+
+
+# ----------------------------------------------------------- yardstick
+
+def projections(a: Arch) -> list[tuple[int, int]]:
+    """(K, N) of every quantized projection of one block."""
+    q, kv = a.heads * a.head_dim, a.kv_heads * a.head_dim
+    mlp = [(a.d, a.ff)] * (2 if a.act == "swiglu" else 1) + [(a.ff, a.d)]
+    return [(a.d, q), (a.d, kv), (a.d, kv), (q, a.d)] + mlp
+
+
+def matmul_params_per_block(a: Arch) -> int:
+    return sum(k * n for k, n in projections(a))
+
+
+def dequant_projections(a: Arch) -> list[tuple[int, int, int]]:
+    """(K, N, calls per step) of the packed dense dequant matmuls a
+    forward step makes: every projection of every block, once."""
+    return [(k, n, a.layers) for k, n in projections(a)]
+
+
+def paged_reads(a: Arch) -> list[tuple[int, int, int, int]]:
+    """(KV heads, query heads per KV head, head dim, calls per step) of
+    the paged attention reads a forward step makes: one per block."""
+    return [(a.kv_heads, a.heads // a.kv_heads, a.head_dim, a.layers)]
+
+
+def token_flops(a: Arch, context: int) -> float:
+    """Model FLOPs of one token at `context` tokens of attention context
+    (itself included): two per multiply-add of every projection and of
+    the output head, and 4 * head_dim per head and context token for
+    the scores and the weighted values. Embedding lookups are free."""
+    dense = a.layers * matmul_params_per_block(a) + a.d * a.vocab
+    attn = a.layers * 4 * a.heads * a.head_dim * context
+    return 2.0 * dense + attn
+
+
+def prompt_flops(a: Arch, start: int, end: int) -> float:
+    """Model FLOPs of the blocks on prompt positions start..end-1 (causal:
+    position p attends to p + 1 tokens). The head runs on a prompt's last
+    position only: add `head_flops` once per prompt."""
+    n = end - start
+    ctx_sum = (start + 1 + end) * n / 2.0
+    dense = a.layers * matmul_params_per_block(a)
+    return 2.0 * dense * n + a.layers * 4 * a.heads * a.head_dim * ctx_sum
+
+
+def head_flops(a: Arch) -> float:
+    return 2.0 * a.d * a.vocab

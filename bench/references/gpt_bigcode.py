@@ -3,7 +3,9 @@ biases on every projection and norm, multi-query attention (one KV head)
 when `multi_query`, learned absolute positions, tanh-GELU MLP."""
 from __future__ import annotations
 
-from references.dense import Arch
+from references.dense import (  # noqa: F401  (the reference contract)
+    Arch, base_key, check_widths, dequant_projections, draw, global_leaves,
+    groups, head_flops, logits, paged_reads, prompt_flops, token_flops)
 
 
 def arch(c: dict) -> Arch:

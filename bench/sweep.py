@@ -42,7 +42,7 @@ def main(argv=None) -> int:
     harness.use_compile_cache()
     harness.device(cell["chips"])
     serve = harness.driver(workload["driver"])
-    a, eng = serve.prepare(config, workload, args.seed)
+    _, a, eng = serve.prepare(config, workload, args.seed)
     drain = float(workload["traffic"].get("drain_seconds", 0)) or 60.0
     for rate in [float(r) for r in args.rates.split(",")]:
         t = copy.deepcopy(workload["traffic"])

@@ -1,4 +1,5 @@
-"""Smoke-size configurations and workloads for the benchmark's CPU tests.
+"""Smoke-size configurations and workloads for the benchmark's CPU tests,
+for every cell and configuration that BENCHMARK.json names.
 
 Importing this module puts the benchmark's directory and the program's
 sources on sys.path: the harness imports its modules by their names in
@@ -13,31 +14,26 @@ for p in (BENCH, os.path.join(os.path.dirname(BENCH), "src")):
 
 import harness  # noqa: E402
 
-SMOKE = {
-    "chatglm3-6b-w4g128": (
-        {"num_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
-         "multi_query_group_num": 2, "kv_channels": 16,
-         "ffn_hidden_size": 128, "padded_vocab_size": 96},
-        {"n_repeats": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
-         "head_dim": 16, "d_ff": 128, "vocab_size": 96}),
-    "granite-20b-w3g128": (
-        {"n_layer": 2, "n_embd": 64, "n_head": 4, "n_inner": 128,
-         "vocab_size": 96, "n_positions": 64},
-        {"n_repeats": 2, "d_model": 64, "n_heads": 4, "head_dim": 16,
-         "d_ff": 128, "vocab_size": 96, "max_position": 64}),
-}
+SPEC = harness.benchmark_spec()
+# every cell and configuration of BENCHMARK.json, by name
+CELLS = {w["name"]: w["config"] for w in SPEC["workloads"]}
+CONFIGS = sorted({c["name"] for c in SPEC["configs"]})
+SERVE_CELLS = sorted(c for c in CELLS
+                     if harness.load_json("workloads", c)["driver"] == "serve")
 
 
 def smoke(name):
-    """The configuration at smoke widths (group 32), and its program
-    config."""
-    file_keys, prog_keys = SMOKE[name]
+    """The configuration at the smoke widths of its file
+    `bench/configs/<name>.smoke.json` (group 32). Returns (configuration,
+    reference module, its description of the model, program config)."""
+    sizes = harness.load_json("configs", f"{name}.smoke")
     cfg = harness.load_json("configs", name)
-    cfg.update(file_keys)
+    cfg.update(sizes["config"])
     cfg["quant"] = dict(cfg["quant"], group_size=32)
-    cfg["program"]["overrides"].update(prog_keys, serve_quant_group=32)
+    cfg["program"]["overrides"].update(sizes["program"], serve_quant_group=32)
+    ref = harness.reference(cfg)
     program_config = harness.driver("serve").program_config
-    return cfg, harness.reference(cfg).arch(cfg), program_config(cfg)
+    return cfg, ref, ref.arch(cfg), program_config(cfg)
 
 
 def smoke_workload(cell: str) -> dict:

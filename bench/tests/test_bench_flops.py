@@ -1,8 +1,10 @@
-"""FLOP and byte functions against counts made by hand."""
+"""FLOP and byte functions, the kernels' and the dense reference's
+yardstick, against counts made by hand."""
 import pytest
 
 import bench_smoke  # noqa: F401
 import flops
+from references import dense
 from references.dense import Arch
 
 # 2 blocks, d 8, 2 heads of 4 with one KV head, SwiGLU 16, vocab 10
@@ -13,18 +15,23 @@ TINY = Arch(layers=2, d=8, heads=2, kv_heads=1, head_dim=4, ff=16,
 
 
 def test_projection_shapes():
-    assert flops.projections(TINY) == [(8, 8), (8, 4), (8, 4), (8, 8),
+    assert dense.projections(TINY) == [(8, 8), (8, 4), (8, 4), (8, 8),
                                        (8, 16), (8, 16), (16, 8)]
     # 64 + 32 + 32 + 64 + 128 + 128 + 128
-    assert flops.matmul_params_per_block(TINY) == 576
+    assert dense.matmul_params_per_block(TINY) == 576
+    # a step calls each projection once in each of the 2 blocks
+    assert dense.dequant_projections(TINY) == [
+        (k, n, 2) for k, n in dense.projections(TINY)]
+    # one read a block: 1 KV head serving 2 query heads of 4
+    assert dense.paged_reads(TINY) == [(1, 2, 4, 2)]
 
 
 def test_token_and_prompt_flops():
     # 2 * (2 blocks * 576 + head 80) + 2 blocks * 4 * 2 heads * 4 * ctx 5
-    assert flops.token_flops(TINY, 5) == 2 * (1152 + 80) + 320
+    assert dense.token_flops(TINY, 5) == 2 * (1152 + 80) + 320
     # positions 3, 4: contexts 4 and 5, no head
-    assert flops.prompt_flops(TINY, 3, 5) == 2 * 1152 * 2 + 64 * (4 + 5)
-    assert flops.head_flops(TINY) == 160
+    assert dense.prompt_flops(TINY, 3, 5) == 2 * 1152 * 2 + 64 * (4 + 5)
+    assert dense.head_flops(TINY) == 160
 
 
 def test_dequant_matmul_cost():

@@ -9,7 +9,7 @@ import types
 
 import pytest
 
-from bench_smoke import smoke, smoke_workload
+from bench_smoke import CELLS, SERVE_CELLS, smoke, smoke_workload
 import devtrace
 import harness
 import hostspans
@@ -130,12 +130,12 @@ def test_queue_wait_lag_and_hold_make_up_the_ttft():
     assert got["steps"] == 2 and "engine_occupancy" not in got
 
 
-@pytest.mark.parametrize("cell", ["glm6b-w4-chat", "granite20b-w3-batch"])
+@pytest.mark.parametrize("cell", SERVE_CELLS)
 def test_engine_counters_agree_with_the_tracker(cell):
-    cfg, _, _ = smoke({"glm6b-w4-chat": "chatglm3-6b-w4g128",
-                       "granite20b-w3-batch": "granite-20b-w3g128"}[cell])
+    cfg, _, _, _ = smoke(CELLS[cell])
+    workload = smoke_workload(cell)
     run, spans, rid_of, before, after = hostspans.traced_run(
-        config=cfg, workload=smoke_workload(cell), seed=2**35 + 21,
+        config=cfg, workload=workload, seed=2**35 + 21,
         seconds=2.0, t0=time.perf_counter(), peaks={})
     got = hostspans.readings(run, spans, rid_of, before, after)
     t = run.data["tracker"]
@@ -145,7 +145,7 @@ def test_engine_counters_agree_with_the_tracker(cell):
     assert got["pool_live_frac"] == pytest.approx(
         harness.metric_reader("kv_live_frac").read(run))
     assert got["steps"] > 0 and got["host_step_ms"] > 0
-    if cell == "glm6b-w4-chat":
+    if workload["traffic"].get("drain_seconds"):
         # every request due in the window and answered in it is split;
         # on the CPU the two clocks' readings lie well inside a step
         assert got["identity_requests"] > 0

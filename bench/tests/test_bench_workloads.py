@@ -30,7 +30,10 @@ def test_every_workload_loads_by_name(cell):
     assert len(conf) == 1
     cfg = harness.load_json("configs", w["config"])
     assert conf[0]["file"] == f"bench/configs/{w['config']}.json"
-    assert harness.reference(cfg).arch(cfg).layers > 0
+    ref = harness.reference(cfg)
+    a = ref.arch(cfg)
+    hash(a)
+    assert a.vocab > 0 and a.bits > 0 and a.group_size
     assert w["chips"] == 1 and len(w["why"]) <= 200
     e2e = harness.cell_metrics(SPEC, cell, trace=False)
     names = {m["name"] for m in e2e}
@@ -111,3 +114,10 @@ def test_warm_up_reaches_every_shape_of_the_cells_traffic(cell):
     for k in ks:
         for c in range(lens[0], lens[1] + lens[3] - k + 1):
             assert (k, eng._read_width(c + k)) in made_dec
+
+
+def test_a_reference_module_without_the_contract_is_refused(monkeypatch):
+    cfg = harness.load_json("configs", SPEC["configs"][0]["name"])
+    monkeypatch.delattr(harness.reference(cfg), "paged_reads")
+    with pytest.raises(SystemExit, match="paged_reads"):
+        harness.reference(cfg)
