@@ -37,7 +37,6 @@ SHAPES = [
     ("dequant", 8, 256, 256, 4, 64),
     ("dequant", 64, 512, 256, 4, 128),
     ("w8a8", 8, 256, 256, 8, -1),
-    ("expert_dequant", 16, 256, 128, 2, 64),
 ]
 
 
@@ -53,13 +52,9 @@ def _timed_plan(kind, m, k, n, bits, gs, plan):
         x = rng.integers(-127, 128, (mb, k)).astype(np.int8)
     else:
         x = rng.normal(size=(mb, k)).astype(np.float32)
-    if kind.startswith("expert_"):
-        x, qw, scale = np.stack([x, x]), np.stack([qw, qw]), \
-            np.stack([scale, scale])
     bm, bn, bk = plan
     pad = (-mb) % bm
-    xp = np.pad(x, ((0, 0), (0, pad), (0, 0))
-                if kind.startswith("expert_") else ((0, pad), (0, 0)))
+    xp = np.pad(x, ((0, pad), (0, 0)))
     return autotune._time_candidate(lambda: kernel_fn(
         xp, qw, scale, bits=bits, group_size=gs, bm=bm, bn=bn, bk=bk,
         interpret=jax.default_backend() != "tpu"))

@@ -5,12 +5,9 @@ timed comparison is ref-vs-ref at different bit widths; the derived column
 reports the *modeled* TPU v5e HBM-traffic advantage of the packed format
 (weight bytes are the decode-time bottleneck for weight-only PTQ serving).
 
-Three kernel families (see DESIGN.md "Quantized serving fast paths"):
+Two kernel families (see DESIGN.md "Quantized serving fast paths"):
 
   * dense dequant matmul          — (M, K) x packed (K, N)
-  * expert-batched dequant matmul — (E, C, K) x stacked packed (E, K, N);
-    the ref baseline column times the old path (dequantize the full float
-    expert stack, then einsum) the kernel removes
   * W8A8 int8 matmul              — per-token int8 activations x packed
     weights on the int8 MXU; the model adds the 2x int8-vs-bf16 MXU rate
 
@@ -28,8 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.quant.types import (dequantize, quantize, quantize_activation,
-                                    quantize_stacked)
+from repro.core.quant.types import quantize, quantize_activation
 from repro.kernels import autotune, ref
 from repro.kernels.paged_harness import build_prefill_case, prefill_oracle
 
@@ -68,28 +64,6 @@ def run(rows: list):
         rows.append((f"kernels/dequant_matmul_w{bits}_{m}x{k}x{n}", t * 1e6,
                      f"bytes={wbytes};modeled_tpu_decode_speedup="
                      f"{speedup:.2f}x"))
-
-    # ---- expert-batched: stacked packed slabs vs float-stack einsum ----
-    e, c, ke, ne = 8, 32, 1024, 1024
-    xe = jax.random.normal(jax.random.PRNGKey(2), (e, c, ke), jnp.float32)
-    we = jax.random.normal(jax.random.PRNGKey(3), (e, ke, ne)) * 0.05
-    for bits, gs in [(4, 128), (2, 64)]:
-        qte = quantize_stacked(we, bits, gs)
-        fused = jax.jit(lambda xx, qw=qte.qw, sc=qte.scale:
-                        ref.expert_dequant_matmul_ref(xx, qw, sc, bits=bits,
-                                                      group_size=gs, k=ke))
-        stack = jax.jit(lambda xx, qt_=qte: jnp.einsum(
-            "eck,ekn->ecn", xx.astype(jnp.bfloat16),
-            dequantize(qt_, jnp.bfloat16),
-            preferred_element_type=jnp.float32))
-        t_fused = _time(fused, xe)
-        t_stack = _time(stack, xe)
-        wbytes = qte.nbytes()
-        speedup = (e * ke * ne * 2) / wbytes
-        rows.append((f"kernels/expert_dequant_w{bits}_{e}x{c}x{ke}x{ne}",
-                     t_fused * 1e6,
-                     f"bytes={wbytes};float_stack_ref_us={t_stack * 1e6:.0f};"
-                     f"modeled_tpu_decode_speedup={speedup:.2f}x"))
 
     # ---- W8A8: int8 MXU path (per-token activation scales) ----
     for bits in (8, 4):

@@ -46,8 +46,8 @@ _LOG = logging.getLogger(__name__)
 # ------------------------------------------------ deterministic fallback
 # (the plans the serving stack gets with a cold cache or REPRO_AUTOTUNE=0)
 
-# decode-shaped tiles of the expert and int8 kinds: minimal token rows,
-# wide weight tiles
+# decode-shaped tiles of the int8 kind: minimal token rows, wide weight
+# tiles
 _SKINNY_M = 8
 _SKINNY_BN = 512
 _SKINNY_BK = 512
@@ -118,12 +118,31 @@ def matmul_blocks(m: int, bm: int, bn: int, bk: int):
     return bm, bn, bk
 
 
+# kinds whose calls run at one row tile: the dense dequant matmul up to
+# _ROW_TILE_M rows, and the grouped expert kinds, whose m is the row tile
+# `grouped_row_tile` gives (the weight block walks the expert's slab)
+_ROW_TILE_KINDS = ("dequant", "grouped_expert", "grouped_w8a8")
+
+
+def grouped_row_tile(rows: int, groups: int) -> int:
+    """Row tile of the grouped expert kernels for `rows` (token, expert)
+    pairs over `groups` experts: the power of two from 16 up that reaches
+    twice the mean rows an expert gets, capped at _ROW_TILE_M. Most
+    experts' rows then fit one tile, so each hit expert's weights stream
+    once a call (64 decode slots at top-6 of 64 experts: 6 rows on
+    average, 16-row tiles)."""
+    bm = 16
+    while bm < min(2.0 * rows / groups, _ROW_TILE_M):
+        bm *= 2
+    return bm
+
+
 def _table_plan(kind: str, m: int, k: int, n: int, *, bits: int,
                 group_size: int, bm: int, bn: int, bk: int):
-    """(plan, regime name) from the deterministic table: the dense dequant
-    kind at one row tile takes `row_tile_plan`; every other call (and a
+    """(plan, regime name) from the deterministic table: the row-tile
+    kinds at one row tile take `row_tile_plan`; every other call (and a
     shape no row-tile plan fits) takes the regime by token count."""
-    if kind == "dequant" and m <= _ROW_TILE_M:
+    if kind in _ROW_TILE_KINDS and m <= _ROW_TILE_M:
         plan = row_tile_plan(m, k, n, bits=bits, group_size=group_size)
         if plan is not None:
             return plan, "row_tile"
@@ -134,7 +153,17 @@ def _table_plan(kind: str, m: int, k: int, n: int, *, bits: int,
     bk_ = pick_bk(k, gs, vpg, bk)
     if bk_ is None:
         return None, regime
-    return (pick_block(max(m, 8), bm), pick_block(n, bn), bk_), regime
+    return (pick_block(max(m, 8), bm), lane_block(n, bn), bk_), regime
+
+
+def lane_block(n: int, target: int) -> int:
+    """The N block of a table plan: the largest lane tile of N (a multiple
+    of 128 that divides it, or N whole) up to `target`, else its smallest
+    lane tile (DeepSeek-V2's latent projection, N = 576, takes it whole;
+    halving would give 64 lanes, which Mosaic refuses)."""
+    tiles = _lane_tiles(n)
+    fit = [t for t in tiles if t <= target]
+    return max(fit) if fit else tiles[0]
 
 
 def fallback_matmul_plan(m: int, k: int, n: int, *, kind: str, bits: int,
@@ -223,7 +252,8 @@ def issued_plans() -> dict:
     """{shape key: {"bm", "bn", "bk", "regime", "grid_steps"}} of the
     plans `matmul_plan` has issued (a copy). `regime` is the table's
     (row_tile, skinny, prefill), or cache / measured; `grid_steps` counts
-    one call's (M/bm, N/bn, K/bk) grid, per expert for the expert kinds."""
+    one call's (M/bm, N/bn, K/bk) grid, per row tile for the grouped
+    expert kinds."""
     return {key: dict(v) for key, v in _issued.items()}
 
 
@@ -369,22 +399,15 @@ def _search_matmul(kind: str, m: int, k: int, n: int, *, bits: int,
     pk = template.packed_tile_rows(k, bits)
     qw = rng.integers(0, 256, (pk, n)).astype(np.uint8)
     scale = rng.uniform(0.01, 0.1, (g, n)).astype(np.float32)
-    expert = kind.startswith("expert_")
-    int8_act = kind.endswith("w8a8")
-    if int8_act:
+    if kind.endswith("w8a8"):
         x = rng.integers(-127, 128, (mb, k)).astype(np.int8)
     else:
         x = rng.normal(size=(mb, k)).astype(np.float32)
-    if expert:
-        x = np.stack([x, x])
-        qw = np.stack([qw, qw])
-        scale = np.stack([scale, scale])
     kernel_fn = _MEASURE_FNS[kind]()
     best, best_t = None, float("inf")
     for bm, bn, bk in cands:
         pad = (-mb) % bm
-        xp = np.pad(x, ((0, 0), (0, pad), (0, 0)) if expert
-                    else ((0, pad), (0, 0)))
+        xp = np.pad(x, ((0, pad), (0, 0)))
         try:
             t = _time_candidate(lambda: kernel_fn(
                 xp, qw, scale, bits=bits, group_size=group_size, bm=bm,
@@ -403,26 +426,16 @@ def _measure_dequant():
     return dequant_matmul_pallas
 
 
-def _measure_expert_dequant():
-    from repro.kernels.expert_dequant_matmul import expert_dequant_matmul_pallas
-    return expert_dequant_matmul_pallas
-
-
 def _measure_w8a8():
     from repro.kernels.w8a8_matmul import w8a8_matmul_pallas
     return w8a8_matmul_pallas
 
 
-def _measure_expert_w8a8():
-    from repro.kernels.expert_w8a8_matmul import expert_w8a8_matmul_pallas
-    return expert_w8a8_matmul_pallas
-
-
+# the kinds the measured search times (the grouped expert kinds keep the
+# table: their calls take a routing layout, not a plain token block)
 _MEASURE_FNS = {
     "dequant": _measure_dequant,
-    "expert_dequant": _measure_expert_dequant,
     "w8a8": _measure_w8a8,
-    "expert_w8a8": _measure_expert_w8a8,
 }
 
 
@@ -480,7 +493,8 @@ def matmul_plan(kind: str, m: int, k: int, n: int, *, bits: int,
                 bk: int = 256):
     """(bm, bn, bk) for one quantized-matmul dispatch, or None (no valid
     tiling: the caller takes the jnp reference). kind is the shape-class
-    kernel family: dequant / expert_dequant / w8a8 / expert_w8a8."""
+    kernel family: dequant / w8a8 / grouped_expert / grouped_w8a8 (the
+    grouped kinds' m is their row tile)."""
     fallback, regime = _table_plan(kind, m, k, n, bits=bits,
                                    group_size=group_size, bm=bm, bn=bn,
                                    bk=bk)
@@ -497,7 +511,7 @@ def matmul_plan(kind: str, m: int, k: int, n: int, *, bits: int,
             return _issue(key, plan, "cache", m, k, n)
         _LOG.warning("autotune entry %s = %r violates the tiling "
                      "constraints; ignoring it", key, ent)
-    if mode == "1":
+    if mode == "1" and kind in _MEASURE_FNS:
         plan = _search_matmul(kind, m, k, n, bits=bits,
                               group_size=group_size, fallback=fallback)
         if plan is not None:

@@ -51,7 +51,7 @@ def dequant_matmul_pallas(x: jax.Array, qw: jax.Array, scale: jax.Array, *,
     dims = dict(k=k, g=g, bm=bm, bn=bn, bk=bk)
     return pl.pallas_call(
         make_matmul_kernel(_SPEC, bits=bits, bk=bk),
-        grid=matmul_grid(_SPEC, e=1, m=m, n=n, k=k, bm=bm, bn=bn, bk=bk),
+        grid=matmul_grid(_SPEC, m=m, n=n, k=k, bm=bm, bn=bn, bk=bk),
         in_specs=matmul_in_specs(_SPEC, bits=bits, **dims),
         out_specs=matmul_out_spec(_SPEC, bm=bm, bn=bn),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),

@@ -28,8 +28,8 @@ from repro.debug_flags import dequant_impl
 from repro.kernels import autotune, ref
 from repro.kernels.channel_stats import channel_stats_pallas
 from repro.kernels.dequant_matmul import dequant_matmul_pallas
-from repro.kernels.expert_dequant_matmul import expert_dequant_matmul_pallas
-from repro.kernels.expert_w8a8_matmul import expert_w8a8_matmul_pallas
+from repro.kernels.grouped_expert_matmul import (grouped_expert_dequant_pallas,
+                                                 grouped_expert_w8a8_pallas)
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.quantize import quantize_pack_pallas
 from repro.kernels.w8a8_matmul import w8a8_matmul_pallas
@@ -48,9 +48,9 @@ KERNEL_CONTRACTS = {
         "ref": "repro.kernels.ref:dequant_matmul_ref",
         "parity": ("tests/test_kernel_parity.py::test_dense_parity",),
     },
-    "expert_dequant_matmul_pallas": {
-        "module": "repro.kernels.expert_dequant_matmul",
-        "ref": "repro.kernels.ref:expert_dequant_matmul_ref",
+    "grouped_expert_dequant_pallas": {
+        "module": "repro.kernels.grouped_expert_matmul",
+        "ref": "repro.kernels.ref:grouped_expert_dequant_ref",
         "parity": ("tests/test_kernel_parity.py::test_expert_parity",),
     },
     "w8a8_matmul_pallas": {
@@ -58,9 +58,9 @@ KERNEL_CONTRACTS = {
         "ref": "repro.kernels.ref:w8a8_matmul_ref",
         "parity": ("tests/test_kernel_parity.py::test_w8a8_parity",),
     },
-    "expert_w8a8_matmul_pallas": {
-        "module": "repro.kernels.expert_w8a8_matmul",
-        "ref": "repro.kernels.ref:expert_w8a8_matmul_ref",
+    "grouped_expert_w8a8_pallas": {
+        "module": "repro.kernels.grouped_expert_matmul",
+        "ref": "repro.kernels.ref:grouped_expert_w8a8_ref",
         "parity": ("tests/test_kernel_parity.py::test_expert_w8a8_parity",),
     },
     "quantize_pack_pallas": {
@@ -162,31 +162,52 @@ def _dequant_matmul_bwd(meta, out_dtype, tiles, res, g):
 _dequant_matmul_vjp.defvjp(_dequant_matmul_fwd, _dequant_matmul_bwd)
 
 
-def expert_dequant_matmul(x: jax.Array, qt: QuantizedTensor, *,
-                          out_dtype=None, bm: int = 128, bn: int = 256,
-                          bk: int = 256) -> jax.Array:
-    """Expert-batched x: (E, C, K) @ packed (E, K, N) -> (E, C, N).
-
-    Consumes the stacked packed layout directly — no float (E, K, N)
-    expert stack is ever materialized. Pads the capacity dim to the tile
-    size; decode-shaped capacities (C <= 8) take the skinny tiles."""
+def grouped_expert_matmul(x: jax.Array, qt: QuantizedTensor,
+                          tile_expert: jax.Array, n_live: jax.Array, *,
+                          bm: int, out_dtype=None) -> jax.Array:
+    """Grouped (dropless) expert matmul: x (R, K) rows in expert groups of
+    whole bm-row tiles, tile i times expert tile_expert[i]'s packed slab
+    of the stacked (E, K, N) `qt` -> (R, N), zero on the tiles from
+    n_live[0] on. Only the experts that hold a tile are read."""
     out_dtype = out_dtype or x.dtype
-    e, c, k = x.shape
-    plan = _plan_tiles(c, k, qt.n, qt, bm, bn, bk, kind="expert_dequant")
+    k = x.shape[1]
+    plan = autotune.matmul_plan("grouped_expert", bm, k, qt.n, bits=qt.bits,
+                                group_size=qt.group_size)
     if plan is None:
-        y = ref.expert_dequant_matmul_ref(x, qt.qw, qt.scale, bits=qt.bits,
-                                          group_size=qt.group_size, k=k)
+        y = ref.grouped_expert_dequant_ref(
+            x, qt.qw, qt.scale, tile_expert, n_live, bits=qt.bits,
+            group_size=qt.group_size, k=k, bm=bm)
         return y.astype(out_dtype)
-    bm_, bn_, bk_ = plan
-    pad_c = (-c) % bm_
-    if pad_c:
-        x = jnp.pad(x, ((0, 0), (0, pad_c), (0, 0)))
-    y = expert_dequant_matmul_pallas(x, qt.qw, qt.scale, bits=qt.bits,
-                                     group_size=qt.group_size, bm=bm_,
-                                     bn=bn_, bk=bk_, interpret=_interpret())
-    if pad_c:
-        y = y[:, :c]
+    _, bn, bk = plan
+    y = grouped_expert_dequant_pallas(
+        x, qt.qw, qt.scale, tile_expert, n_live, bits=qt.bits,
+        group_size=qt.group_size, bm=bm, bn=bn, bk=bk,
+        interpret=_interpret())
     return y.astype(out_dtype)
+
+
+def grouped_expert_w8a8_matmul(x: jax.Array, qt: QuantizedTensor,
+                               tile_expert: jax.Array, n_live: jax.Array, *,
+                               bm: int, out_dtype=None) -> jax.Array:
+    """The grouped expert matmul's true A8 path: each row quantized to
+    int8 on its own per-token grid, int8 x int8 -> int32 MXU dots against
+    its tile's expert slab, per-row rescale. x: (R, K) -> (R, N)."""
+    out_dtype = out_dtype or x.dtype
+    k = x.shape[1]
+    xq, xs = quantize_activation(x, 8)
+    plan = autotune.matmul_plan("grouped_w8a8", bm, k, qt.n, bits=qt.bits,
+                                group_size=qt.group_size)
+    if plan is None:
+        y = ref.grouped_expert_w8a8_ref(
+            xq, qt.qw, qt.scale, tile_expert, n_live, bits=qt.bits,
+            group_size=qt.group_size, k=k, bm=bm)
+    else:
+        _, bn, bk = plan
+        y = grouped_expert_w8a8_pallas(
+            xq, qt.qw, qt.scale, tile_expert, n_live, bits=qt.bits,
+            group_size=qt.group_size, bm=bm, bn=bn, bk=bk,
+            interpret=_interpret())
+    return (y * xs).astype(out_dtype)
 
 
 def w8a8_matmul(x: jax.Array, qt: QuantizedTensor, *, out_dtype=None,
@@ -213,36 +234,6 @@ def w8a8_matmul(x: jax.Array, qt: QuantizedTensor, *, out_dtype=None,
                            bk=bk_, interpret=_interpret())
     if pad_m:
         y = y[:m]
-    return (y * xs).astype(out_dtype)
-
-
-def expert_w8a8_matmul(x: jax.Array, qt: QuantizedTensor, *, out_dtype=None,
-                       bm: int = 128, bn: int = 256, bk: int = 256,
-                       amax_axis: str | None = None) -> jax.Array:
-    """Expert-batched true A8 path: per-token int8 activation quantize over
-    the flattened (E*C) token dim, int8 x int8 -> int32 MXU matmul per
-    expert slab, per-(expert, token) rescale. x: (E, C, K) -> (E, C, N).
-    Replaces the fake-quant + bf16-dequant detour the MoE act_bits=8 path
-    used to take."""
-    out_dtype = out_dtype or x.dtype
-    e, c, k = x.shape
-    xq, xs = quantize_activation(x.reshape(e * c, k), 8, axis_name=amax_axis)
-    xq = xq.reshape(e, c, k)
-    xs = xs.reshape(e, c, 1)
-    plan = _plan_tiles(c, k, qt.n, qt, bm, bn, bk, kind="expert_w8a8")
-    if plan is None:
-        y = ref.expert_w8a8_matmul_ref(xq, qt.qw, qt.scale, bits=qt.bits,
-                                       group_size=qt.group_size, k=k)
-        return (y * xs).astype(out_dtype)
-    bm_, bn_, bk_ = plan
-    pad_c = (-c) % bm_
-    if pad_c:
-        xq = jnp.pad(xq, ((0, 0), (0, pad_c), (0, 0)))
-    y = expert_w8a8_matmul_pallas(xq, qt.qw, qt.scale, bits=qt.bits,
-                                  group_size=qt.group_size, bm=bm_, bn=bn_,
-                                  bk=bk_, interpret=_interpret())
-    if pad_c:
-        y = y[:, :c]
     return (y * xs).astype(out_dtype)
 
 

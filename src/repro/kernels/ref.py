@@ -18,19 +18,6 @@ def dequant_matmul_ref(x: jax.Array, qw: jax.Array, scale: jax.Array, *,
                    preferred_element_type=jnp.float32)
 
 
-def expert_dequant_matmul_ref(x: jax.Array, qw: jax.Array, scale: jax.Array,
-                              *, bits: int, group_size: int,
-                              k: int) -> jax.Array:
-    """x: (E, M, K) @ packed (E, pk, N) -> (E, M, N) f32 (pk = packed
-    rows, see types.pack_layout)."""
-    e = x.shape[0]
-    qt = QuantizedTensor(qw, scale, bits, group_size, (e, k, qw.shape[-1]))
-    w = dequantize(qt, jnp.float32)
-    return jnp.einsum("emk,ekn->emn", x.astype(jnp.bfloat16),
-                      w.astype(jnp.bfloat16),
-                      preferred_element_type=jnp.float32)
-
-
 def w8a8_matmul_ref(xq: jax.Array, qw: jax.Array, scale: jax.Array, *,
                     bits: int, group_size: int, k: int) -> jax.Array:
     """Exact int32 oracle for the W8A8 kernel (pre activation-rescale).
@@ -48,13 +35,44 @@ def w8a8_matmul_ref(xq: jax.Array, qw: jax.Array, scale: jax.Array, *,
                    scale.astype(jnp.float32)[None], axis=1)
 
 
-def expert_w8a8_matmul_ref(xq: jax.Array, qw: jax.Array, scale: jax.Array, *,
-                           bits: int, group_size: int, k: int) -> jax.Array:
-    """Expert-stacked W8A8 oracle: xq (E, C, K) int8 @ packed (E, pk, N)
-    with scale (E, G, N). Returns (E, C, N) f32 (pre activation-rescale),
-    one `w8a8_matmul_ref` per expert."""
-    return jax.vmap(lambda x2, w2, s2: w8a8_matmul_ref(
-        x2, w2, s2, bits=bits, group_size=group_size, k=k))(xq, qw, scale)
+def _tiles(x: jax.Array, bm: int) -> jax.Array:
+    return x.reshape(x.shape[0] // bm, bm, x.shape[-1])
+
+
+def _live_rows(y: jax.Array, n_live: jax.Array) -> jax.Array:
+    """(T, bm, N) tile results -> (T*bm, N), zero from tile n_live[0] on."""
+    live = jnp.arange(y.shape[0]) < n_live[0]
+    return jnp.where(live[:, None, None], y, 0.0).reshape(-1, y.shape[-1])
+
+
+def grouped_expert_dequant_ref(x: jax.Array, qw: jax.Array,
+                               scale: jax.Array, tile_expert: jax.Array,
+                               n_live: jax.Array, *, bits: int,
+                               group_size: int, k: int,
+                               bm: int) -> jax.Array:
+    """x: (R, K) rows in bm-row tiles, tile t times the packed (E, pk, N)
+    slab of expert tile_expert[t], bf16 operands, f32 accumulation ->
+    (R, N) f32, zero on tiles from n_live[0] on."""
+    e = qw.shape[0]
+    w = dequantize(QuantizedTensor(qw, scale, bits, group_size,
+                                   (e, k, qw.shape[-1])), jnp.float32)
+    y = jnp.einsum("tmk,tkn->tmn", _tiles(x, bm).astype(jnp.bfloat16),
+                   w[tile_expert].astype(jnp.bfloat16),
+                   preferred_element_type=jnp.float32)
+    return _live_rows(y, n_live)
+
+
+def grouped_expert_w8a8_ref(xq: jax.Array, qw: jax.Array, scale: jax.Array,
+                            tile_expert: jax.Array, n_live: jax.Array, *,
+                            bits: int, group_size: int, k: int,
+                            bm: int) -> jax.Array:
+    """Grouped W8A8 oracle: one exact `w8a8_matmul_ref` per row tile with
+    its expert's slab. xq: (R, K) int8. Returns (R, N) f32 (pre
+    activation-rescale), zero on dead tiles."""
+    y = jax.vmap(lambda x2, w2, s2: w8a8_matmul_ref(
+        x2, w2, s2, bits=bits, group_size=group_size, k=k))(
+            _tiles(xq, bm), qw[tile_expert], scale[tile_expert])
+    return _live_rows(y, n_live)
 
 
 def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,

@@ -129,27 +129,20 @@ def scale_blockspec(k: int, g: int, bk: int, bn: int):
                         lambda i, j, kk: (kk * bk // (gs * r), 0, j))
 
 
-def lift_expert(s: pl.BlockSpec) -> pl.BlockSpec:
-    """Lift a dense (i, j, kk)-indexed BlockSpec over a leading expert grid
-    axis: same block indexing, stacked (E, ...) layout."""
-    return pl.BlockSpec(
-        (1,) + tuple(s.block_shape),
-        lambda e, i, j, kk: (e,) + tuple(s.index_map(i, j, kk)))
-
-
 # --------------------------------------------------------- matmul templates
 
 @dataclasses.dataclass(frozen=True)
 class MatmulSpec:
     """One packed-matmul kernel variant.
 
-    expert_dim: prepend an expert axis to the grid — operands arrive as
-    stacked (E, ...) slabs and every dense block spec is `lift_expert`ed.
+    grouped: rows arrive in expert groups of whole row tiles, and a
+    scalar-prefetched tile -> expert map picks the stacked (E, ...) packed
+    slab each row tile multiplies (`grouped_grid_spec`).
     epilogue: accumulate stage (see module docstring).
     """
     name: str
     epilogue: str = "dequant_bf16"
-    expert_dim: bool = False
+    grouped: bool = False
 
     def __post_init__(self):
         assert self.epilogue in ("dequant_bf16", "int8_mxu"), self.epilogue
@@ -158,73 +151,111 @@ class MatmulSpec:
 def make_matmul_kernel(spec: MatmulSpec, *, bits: int, bk: int):
     """Kernel body for `spec`: zero-init on the first K step, unpack the
     packed tile, run the epilogue's dot(s), accumulate into the output
-    tile. Op-for-op identical to the former handwritten bodies."""
-    k_axis = 3 if spec.expert_dim else 2
+    tile. Op-for-op identical to the former handwritten bodies. A grouped
+    body skips the dots of row tiles past the live count, whose output
+    stays zero."""
 
-    def kernel(x_ref, qw_ref, scale_ref, o_ref):
-        k_step = pl.program_id(k_axis)
-
-        @pl.when(k_step == 0)
-        def _init():
-            o_ref[...] = jnp.zeros_like(o_ref)
-
-        if spec.expert_dim:
-            x, qw, s = x_ref[0], qw_ref[0], scale_ref[0, 0]
-        else:
-            x, qw, s = x_ref[...], qw_ref[...], scale_ref[0]
+    def accumulate(x, qw, s, o_ref):
         q = unpack_tile(qw, bits, bk)                  # (bk, bn) int32
         if spec.epilogue == "dequant_bf16":
             w = scale_tile(q, s, bk)                   # (bk, bn) f32
             acc = jnp.dot(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
                           preferred_element_type=jnp.float32)
-            if spec.expert_dim:
-                o_ref[0] += acc
-            else:
-                o_ref[...] += acc
+            o_ref[...] += acc
         else:  # int8_mxu
             # unpacked values always fit int8 (|q| <= 127), so the MXU dots
             # below run int8 x int8 -> int32 for any packed bits
             w8 = q.astype(jnp.int8)                    # (bk, bn)
             gb = s.shape[0]
             gsb = bk // gb
-            acc = o_ref[0] if spec.expert_dim else o_ref[...]
+            acc = o_ref[...]
             for gi in range(gb):
                 d = jnp.dot(x[:, gi * gsb:(gi + 1) * gsb],
                             w8[gi * gsb:(gi + 1) * gsb],
                             preferred_element_type=jnp.int32)
                 acc = acc + d.astype(jnp.float32) * s[gi][None, :]
-            if spec.expert_dim:
-                o_ref[0] = acc
-            else:
-                o_ref[...] = acc
+            o_ref[...] = acc
+
+    if spec.grouped:
+        def kernel(tile_expert_ref, n_live_ref, x_ref, qw_ref, scale_ref,
+                   o_ref):
+            @pl.when(pl.program_id(2) == 0)
+            def _init():
+                o_ref[...] = jnp.zeros_like(o_ref)
+
+            @pl.when(pl.program_id(0) < n_live_ref[0])
+            def _live():
+                accumulate(x_ref[...], qw_ref[0], scale_ref[0, 0], o_ref)
+    else:
+        def kernel(x_ref, qw_ref, scale_ref, o_ref):
+            @pl.when(pl.program_id(2) == 0)
+            def _init():
+                o_ref[...] = jnp.zeros_like(o_ref)
+
+            accumulate(x_ref[...], qw_ref[...], scale_ref[0], o_ref)
 
     kernel.__name__ = f"_{spec.name}_kernel"
     return kernel
 
 
-def matmul_grid(spec: MatmulSpec, *, e: int, m: int, n: int, k: int,
-                bm: int, bn: int, bk: int):
-    base = (m // bm, n // bn, k // bk)
-    return (e,) + base if spec.expert_dim else base
+def matmul_grid(spec: MatmulSpec, *, m: int, n: int, k: int, bm: int,
+                bn: int, bk: int):
+    return (m // bm, n // bn, k // bk)
 
 
 def matmul_in_specs(spec: MatmulSpec, *, bits: int, k: int, g: int, bm: int,
                     bn: int, bk: int):
     """[x, packed qw, scale] block specs for the (M, N, K) grid walk."""
-    specs = [
+    return [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((packed_tile_rows(bk, bits), bn),
                      lambda i, j, kk: (kk, j)),
         scale_blockspec(k, g, bk, bn),
     ]
-    if spec.expert_dim:
-        specs = [lift_expert(s) for s in specs]
-    return specs
 
 
 def matmul_out_spec(spec: MatmulSpec, *, bm: int, bn: int):
-    o = pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
-    return lift_expert(o) if spec.expert_dim else o
+    return pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
+
+
+def grouped_grid_spec(spec: MatmulSpec, *, bits: int, n_tiles: int, n: int,
+                      k: int, g: int, bm: int, bn: int, bk: int):
+    """PrefetchScalarGridSpec of the grouped walk over (row tile, N/bn,
+    K/bk), K innermost, with the tile -> expert map and the live tile
+    count scalar-prefetched. Row tile i multiplies the packed slab of
+    expert tile_expert[i], so a call streams only the weight blocks of
+    experts that hold rows. Tiles from the live count on are dead: their
+    index maps stay on the last live step's blocks, so they start no
+    DMA, and their bodies skip the dot."""
+    nk, nn = k // bk, n // bn
+    r = scale_rows(k, g, bk)
+    gs = k // g
+
+    def walk(i, j, kk, n_live):
+        live = i < n_live[0]
+        return (jnp.where(live, i, jnp.maximum(n_live[0] - 1, 0)),
+                jnp.where(live, j, nn - 1), jnp.where(live, kk, nk - 1))
+
+    def x_map(i, j, kk, te, nl):
+        i_, _, kk_ = walk(i, j, kk, nl)
+        return (i_, kk_)
+
+    def w_map(i, j, kk, te, nl):
+        _, j_, kk_ = walk(i, j, kk, nl)
+        return (te[i], kk_, j_)
+
+    def s_map(i, j, kk, te, nl):
+        _, j_, kk_ = walk(i, j, kk, nl)
+        return (te[i], kk_ * bk // (gs * r), 0, j_)
+
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n_tiles, nn, nk),
+        in_specs=[pl.BlockSpec((bm, bk), x_map),
+                  pl.BlockSpec((1, packed_tile_rows(bk, bits), bn), w_map),
+                  pl.BlockSpec((1, 1, r, bn), s_map)],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, te, nl: (i, j)),
+    )
 
 
 # ------------------------------------------------- paged-attention template

@@ -88,8 +88,9 @@ def banded_attention(q, k, v, *, q_pos, kv_pos, window: int,
 def attention_core(q, k, v, *, q_pos, kv_pos, causal=True,
                    window: Optional[int] = None, block_kv: int = 512,
                    banded: bool = False, chunked_decode: bool = False,
-                   scores_dtype=jnp.float32):
-    """q: (B,Sq,H,hd); k,v: (B,Skv,KVH,hd). Returns (B,Sq,H,hd)."""
+                   scores_dtype=jnp.float32, scale: Optional[float] = None):
+    """q: (B,Sq,H,hd); k,v: (B,Skv,KVH,hd). Returns (B,Sq,H,hd). `scale`
+    multiplies the scores (default 1/sqrt(hd))."""
     b, sq, h, hd = q.shape
     skv, kvh = k.shape[1], k.shape[2]
     hd_v = v.shape[-1]  # may differ from hd (MLA)
@@ -100,7 +101,8 @@ def attention_core(q, k, v, *, q_pos, kv_pos, causal=True,
                                 block_q=max(256, min(1024, window)))
     g = h // kvh
     qg = q.reshape(b, sq, kvh, g, hd)
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
 
     single_shot = skv <= block_kv or (sq == 1 and not chunked_decode)
     if single_shot:

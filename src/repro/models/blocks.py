@@ -79,11 +79,14 @@ def apply_block(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jax.Array, *,
                 paged: Optional[dict] = None,
                 taps: Optional[dict] = None, tap_prefix: str = ""):
     """Returns (y, new_cache, aux). mode: train|encode|prefill|decode.
-    `paged` carries block-table indices for paged caches (serve/kvcache.py)."""
+    `paged` carries block-table indices for paged caches (serve/kvcache.py).
+    aux["loss"] is the block's auxiliary loss, aux["load"] (n, 3) int32 the
+    routing counts of its n (0 or 1) MoE calls (see `apply_moe`)."""
     causal = mode != "encode"
     decode = mode == "decode"
     new_cache: dict = dict(cache) if cache is not None else None
-    aux = jnp.zeros((), jnp.float32)
+    aux = {"loss": jnp.zeros((), jnp.float32),
+           "load": jnp.zeros((0, 3), jnp.int32)}
 
     h = apply_norm(cfg, p["ln1"], x)
     if spec.kind == "attn":
@@ -132,18 +135,19 @@ def apply_block(cfg: ModelConfig, spec: LayerSpec, p: dict, x: jax.Array, *,
         x = x + apply_mlp(cfg, p["mlp"], h2, taps, tap_prefix + "mlp/")
     elif spec.mlp == "moe":
         h2 = apply_norm(cfg, p["ln2"], x)
-        # paged serving carries junk tokens that must not compete for
-        # expert capacity (see apply_moe): left-padding in prefill
-        # (pos = -1) and idle slots in decode (kv_len == 0). Unpaged modes
-        # never do — pass None so the shard_map MoE fast path stays
-        # available to them.
+        # paged serving carries junk tokens that must route to no expert
+        # (no wasted expert rows, and no rows in the routing counts):
+        # left-padding in prefill (pos = -1) and idle slots in decode
+        # (kv_len == 0). Unpaged modes never do — pass None so the
+        # shard_map MoE fast path stays available to them.
         if paged is not None and mode == "prefill":
             moe_valid = positions >= 0
         elif paged is not None and mode == "decode" and "kv_len" in paged:
             moe_valid = (paged["kv_len"] > 0)[:, None]
         else:
             moe_valid = None
-        y2, aux = apply_moe(cfg, p["moe"], h2, taps, tap_prefix + "moe/",
-                            valid=moe_valid)
+        y2, moe_aux = apply_moe(cfg, p["moe"], h2, taps,
+                                tap_prefix + "moe/", valid=moe_valid)
+        aux = {"loss": moe_aux["loss"], "load": moe_aux["load"][None]}
         x = x + y2
     return x, new_cache, aux

@@ -33,8 +33,12 @@ class MoEConfig:
     top_k: int = 2
     n_shared: int = 0           # shared (always-on) experts, DeepSeek-style
     d_ff_expert: int = 0        # per-expert hidden dim
+    # the shard_map EP training dispatch (models/moe_shardmap.py) drops
+    # tokens past this capacity; every other path routes dropless
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    norm_topk_prob: bool = True         # renormalise the top-k gates
+    routed_scaling_factor: float = 1.0  # multiplies the routed experts' sum
 
 
 @dataclasses.dataclass(frozen=True)
@@ -44,6 +48,22 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN context extension (arXiv:2309.00071) as DeepSeek-V2 publishes
+    it: rotary frequencies interpolated by `factor` outside a linear ramp
+    between the correction dims of `beta_fast` and `beta_slow` rotations
+    over `original_max_position_embeddings`; cos/sin scaled by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim), and the
+    attention softmax by mscale(factor, mscale_all_dim)^2."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,6 +102,7 @@ class ModelConfig:
     act: str = "silu"           # silu (SwiGLU) | gelu (plain MLP)
     rope: str = "full"          # full | half ("2d") | none
     rope_theta: float = 10000.0
+    rope_scaling: Optional[RopeScaling] = None
     pos_emb: str = "none"       # none | learned | sinusoidal
     max_position: int = 8192    # for learned positions
     # --- attention ---
@@ -131,6 +152,16 @@ class ModelConfig:
                                     # row-parallel linears psum over the
                                     # "model" axis; everywhere else tp == 1
                                     # and no collective is emitted)
+
+    def __post_init__(self):
+        # sub-configs given as plain mappings (a JSON file's overrides)
+        # become their dataclasses
+        for name, kind in (("moe", MoEConfig), ("mla", MLAConfig),
+                           ("mamba", Mamba2Config),
+                           ("rope_scaling", RopeScaling)):
+            value = getattr(self, name)
+            if isinstance(value, dict):
+                object.__setattr__(self, name, kind(**value))
 
     # ------------------------------------------------------------------
     @property

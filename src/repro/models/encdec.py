@@ -45,8 +45,8 @@ def encode(cfg: ModelConfig, params: dict, frames: jax.Array) -> jax.Array:
     b, s, d = frames.shape
     x = frames.astype(ecfg.adtype) + sinusoidal_positions(s, d, ecfg.adtype)[None]
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-    x, _, _ = _run_stack(ecfg, params["enc"], x, positions=positions,
-                         mode="encode", cache=None)
+    x, _, _, _ = _run_stack(ecfg, params["enc"], x, positions=positions,
+                            mode="encode", cache=None)
     return apply_norm(ecfg, params["enc"]["final_norm"], x)
 
 
@@ -58,8 +58,8 @@ def encdec_forward(cfg: ModelConfig, params: dict, frames: jax.Array,
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     x = _embed(dcfg, params["dec"], tokens, None, positions)
-    x, _, aux = _run_stack(dcfg, params["dec"], x, positions=positions,
-                           mode="train", cache=None, enc_out=enc_out)
+    x, _, aux, _ = _run_stack(dcfg, params["dec"], x, positions=positions,
+                              mode="train", cache=None, enc_out=enc_out)
     return _head(dcfg, params["dec"], x), aux
 
 
@@ -87,8 +87,9 @@ def encdec_prefill(cfg: ModelConfig, params: dict, frames: jax.Array,
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     x = _embed(dcfg, params["dec"], tokens, None, positions)
-    x, new_cache, _ = _run_stack(dcfg, params["dec"], x, positions=positions,
-                                 mode="prefill", cache=cache, enc_out=enc_out)
+    x, new_cache, _, _ = _run_stack(dcfg, params["dec"], x,
+                                    positions=positions, mode="prefill",
+                                    cache=cache, enc_out=enc_out)
     logits = _head(dcfg, params["dec"], x[:, -1:, :])
     return logits[:, 0, :], new_cache
 
@@ -98,7 +99,8 @@ def encdec_decode(cfg: ModelConfig, params: dict, tokens: jax.Array,
     """One decoder step against cached self+cross K/V."""
     dcfg = dec_config(cfg)
     x = _embed(dcfg, params["dec"], tokens, None, positions)
-    x, new_cache, _ = _run_stack(dcfg, params["dec"], x, positions=positions,
-                                 mode="decode", cache=cache, enc_out=None)
+    x, new_cache, _, _ = _run_stack(dcfg, params["dec"], x,
+                                    positions=positions, mode="decode",
+                                    cache=cache, enc_out=None)
     logits = _head(dcfg, params["dec"], x)
     return logits[:, 0, :], new_cache

@@ -8,13 +8,15 @@ paths" for the full table):
   * plain array                    -> jnp.einsum (MXU)
   * QuantizedTensor, TPU           -> Pallas fused dequant-matmul kernel
     - (K, N) weight                  -> kernels/dequant_matmul
-    - (E, K, N) stacked experts      -> kernels/expert_dequant_matmul
-      (packed expert slabs consumed directly; no float stack)
+    - (E, K, N) stacked experts      -> kernels/grouped_expert_matmul
+      (rows in expert groups; only hit experts' packed slabs are read,
+      never a float stack)
     - act_bits == 8                  -> kernels/w8a8_matmul (true int8 MXU)
-    - act_bits == 8, stacked experts -> kernels/expert_w8a8_matmul
-      (int8 x int8 MXU dots per expert slab)
-  * QuantizedTensor, CPU           -> reference dequant + einsum / the
-    int32 W8A8 reference (same math)
+    - act_bits == 8, stacked experts -> the grouped kernel's int8 MXU
+      instance
+  * QuantizedTensor, CPU           -> reference dequant + einsum (experts:
+    `jax.lax.ragged_dot`) / the int32 W8A8 reference (same math)
+  * float stacked experts          -> `jax.lax.ragged_dot`
 
 `act_bits == 8` selects the true A8 path: per-token int8 activation
 quantization feeding an int8 x int8 -> int32 matmul (FPTQ's W4A8/W8A8
@@ -22,7 +24,7 @@ regime). Other act_bits values keep the legacy per-tensor fake-quant.
 """
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -115,51 +117,56 @@ def dense(p: dict, x: jax.Array, *, dtype=None,
     return y
 
 
-def dense_experts(p: dict, x: jax.Array, *, dtype=None) -> jax.Array:
-    """Batched expert matmul: x (E, C, K) @ w (E, K, N) -> (E, C, N).
+class ExpertGroups(NamedTuple):
+    """Rows in expert groups (models/mlp_moe.py builds it): expert e's
+    rows are `sizes[e]` consecutive rows, a whole number of `bm`-row
+    tiles, experts in order; `tile_expert` (R // bm,) names each tile's
+    expert and `n_live` (1,) counts the tiles that hold rows."""
 
-    Quantized expert stacks take the expert-batched Pallas kernel: packed
-    (E, packed_rows(K), N) slabs are consumed directly, so the float expert stack is
-    never materialized (the old path dequantized all E experts per call)."""
+    sizes: jax.Array
+    tile_expert: jax.Array
+    n_live: jax.Array
+    bm: int
+
+
+def grouped_experts(p: dict, x: jax.Array, groups: ExpertGroups, *,
+                    dtype=None) -> jax.Array:
+    """Grouped expert matmul: x (R, K) rows in `groups` @ w (E, K, N) ->
+    (R, N), each row through its own expert; rows past the groups are
+    zero. Quantized stacks on the chip run the grouped Pallas kernels,
+    which read only the packed slabs of experts that hold rows."""
     w = p["w"]
     dtype = dtype or x.dtype
     if isinstance(w, QuantizedTensor):
-        if (w.act_bits == 8 and w.qw.ndim == 3
-                and w.bits in _KERNEL_BITS):
-            # true W4A8/W8A8 expert path: per-token int8 activations feed
-            # the int8 x int8 -> int32 MXU dots (no bf16 dequant stack)
-            if _use_pallas():
+        kernel = _use_pallas() and w.bits in _KERNEL_BITS
+        if w.act_bits == 8 and w.bits in _KERNEL_BITS:
+            # true W4A8/W8A8 expert path: per-token int8 rows feed the
+            # int8 x int8 -> int32 MXU dots (no bf16 dequant stack)
+            if kernel:
                 from repro.kernels import ops as kops
 
-                y = kops.expert_w8a8_matmul(x, w, out_dtype=dtype)
-            else:
-                from repro.kernels import ref as kref
+                return kops.grouped_expert_w8a8_matmul(
+                    x, w, groups.tile_expert, groups.n_live, bm=groups.bm,
+                    out_dtype=dtype)
+            from repro.kernels import ref as kref
 
-                e, c, k = x.shape
-                xq, xs = quantize_activation(x.reshape(e * c, k), 8)
-                y = (kref.expert_w8a8_matmul_ref(
-                    xq.reshape(e, c, k), w.qw, w.scale, bits=w.bits,
-                    group_size=w.group_size,
-                    k=w.k) * xs.reshape(e, c, 1)).astype(dtype)
-            if "b" in p and p["b"] is not None:
-                y = y + p["b"][:, None, :].astype(dtype)
-            return y
+            xq, xs = quantize_activation(x, 8)
+            y = kref.grouped_expert_w8a8_ref(
+                xq, w.qw, w.scale, groups.tile_expert, groups.n_live,
+                bits=w.bits, group_size=w.group_size, k=w.k, bm=groups.bm)
+            return (y * xs).astype(dtype)
         if w.act_bits:
             x = fake_quant_activation(x, w.act_bits)
-        if _use_pallas() and w.qw.ndim == 3 and w.bits in _KERNEL_BITS:
+        if kernel:
             from repro.kernels import ops as kops
 
-            y = kops.expert_dequant_matmul(x, w, out_dtype=dtype)
-        else:
-            wm = dequantize(w, dtype)
-            y = jnp.einsum("eck,ekn->ecn", x.astype(dtype), wm,
-                           preferred_element_type=jnp.float32).astype(dtype)
-    else:
-        y = jnp.einsum("eck,ekn->ecn", x.astype(dtype), w.astype(dtype),
-                       preferred_element_type=jnp.float32).astype(dtype)
-    if "b" in p and p["b"] is not None:
-        y = y + p["b"][:, None, :].astype(dtype)
-    return y
+            return kops.grouped_expert_matmul(
+                x, w, groups.tile_expert, groups.n_live, bm=groups.bm,
+                out_dtype=dtype)
+        w = dequantize(w, dtype)
+    return jax.lax.ragged_dot(
+        x.astype(dtype), w.astype(dtype), groups.sizes,
+        preferred_element_type=jnp.float32).astype(dtype)
 
 
 def init_dense(key, k: int, n: int, *, bias: bool = False, dtype=jnp.float32,

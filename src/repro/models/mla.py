@@ -1,9 +1,13 @@
 """Multi-head Latent Attention (DeepSeek-V2) with compressed KV cache.
 
 Train/prefill use the non-absorbed form (materialize K/V from the latent,
-chunked flash attention). Decode uses the *absorbed* form: queries are
-projected into the latent space and attention runs directly against the
-cached (c_kv, k_rope) — the deployment-relevant O(r + rope) cache per token.
+chunked flash attention); a chunked or prefix-sharing prefill materializes
+them from the latent context it reads back through the block table. Decode
+uses the *absorbed* form: queries are projected into the latent space and
+attention runs directly against the cached (c_kv, k_rope) — the
+deployment-relevant O(r + rope) cache per token. Both forms scale scores
+by 1/sqrt(qk head dim) times YaRN's mscale(factor, mscale_all_dim)^2 when
+the config extends its context (`rope_scaling`).
 
 The latent RMSNorm ("kvnorm") is a tweakable norm for the paper's pipeline.
 """
@@ -19,7 +23,7 @@ from repro.models.config import ModelConfig
 from repro.models.attention import attention_core, _cache_write, _paged_update
 from repro.models.linear import dense, init_dense, materialize
 from repro.models.norms import apply_norm, init_norm
-from repro.models.rope import apply_rope
+from repro.models.rope import apply_rope, yarn_mscale
 from repro.serve.kvcache import PageSpec
 
 
@@ -68,8 +72,19 @@ def _project_latent(cfg, p, x, positions):
     c_kv, k_pe = jnp.split(ckv_kpe, [m.kv_lora_rank], axis=-1)
     c_kv = apply_norm(cfg, p["kvnorm"], c_kv)
     k_pe = apply_rope(k_pe[:, :, None, :], positions, theta=cfg.rope_theta,
-                      variant="full")[:, :, 0, :]
+                      variant="full", scaling=cfg.rope_scaling)[:, :, 0, :]
     return c_kv, k_pe
+
+
+def softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk head dim), times mscale(factor, mscale_all_dim)^2 under
+    YaRN, as DeepSeek-V2 scales its scores."""
+    m = cfg.mla
+    scale = 1.0 / ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5)
+    rs = cfg.rope_scaling
+    if rs is not None and rs.mscale_all_dim:
+        scale *= yarn_mscale(rs.factor, rs.mscale_all_dim) ** 2
+    return scale
 
 
 def _queries(cfg, p, x, positions):
@@ -79,7 +94,8 @@ def _queries(cfg, p, x, positions):
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
     q = dense(p["wq"], x).reshape(b, s, h, qk)
     q_nope, q_pe = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_pe = apply_rope(q_pe, positions, theta=cfg.rope_theta, variant="full")
+    q_pe = apply_rope(q_pe, positions, theta=cfg.rope_theta, variant="full",
+                      scaling=cfg.rope_scaling)
     return q_nope, q_pe
 
 
@@ -104,13 +120,6 @@ def apply_mla(cfg: ModelConfig, p: dict, x: jax.Array, *,
     new_cache = cache
     paged_view = None
     if cache is not None and "k_pool" in cache:
-        if not decode and paged is not None and "kv_len" in paged:
-            # chunked / prefix-suffix prefill hands back a gathered latent
-            # context, but the non-absorbed prefill below attends only to
-            # the current chunk's materialized K/V — silently wrong, so
-            # refuse (the engine gates MLA off these features already)
-            raise NotImplementedError(
-                "chunked/prefix-shared prefill is not supported for MLA")
         new_cache, paged_view = _paged_update(
             cache, c_kv[:, :, None, :], k_pe[:, :, None, :], positions, paged)
     elif cache is not None:
@@ -132,7 +141,7 @@ def apply_mla(cfg: ModelConfig, p: dict, x: jax.Array, *,
         wuk, wuv = wukv[:, :, :m.qk_nope_head_dim], wukv[:, :, m.qk_nope_head_dim:]
         # absorb: q_latent = q_nope @ W_uk  -> (B, S, H, r)
         ql = jnp.einsum("bshn,rhn->bshr", q_nope.astype(jnp.float32), wuk)
-        scale = 1.0 / ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5)
+        scale = softmax_scale(cfg)
         sc = (jnp.einsum("bshr,btr->bhst", ql, ckv_all.astype(jnp.float32)) +
               jnp.einsum("bshp,btp->bhst", q_pe.astype(jnp.float32),
                          kpe_all.astype(jnp.float32))) * scale
@@ -144,19 +153,29 @@ def apply_mla(cfg: ModelConfig, p: dict, x: jax.Array, *,
         o = jnp.einsum("bshr,rhv->bshv", ctx, wuv)               # (B,S,H,v)
         o = o.astype(x.dtype).reshape(b, s, h * m.v_head_dim)
     else:
-        # non-absorbed: materialize per-head K/V (MHA), chunked attention
+        # non-absorbed: materialize per-head K/V (MHA), chunked attention.
+        # A chunked / prefix-suffix prefill (paged "kv_len") attends to the
+        # whole latent context read back through its block-table rows —
+        # earlier chunks and shared prefix pages included; a fresh prompt
+        # attends to its own latents
+        kv_pos = positions
+        if paged_view is not None and "kv_len" in paged:
+            ckv_g, kpe_g, kv_pos = paged_view
+            c_kv, k_pe = ckv_g[:, :, 0, :], kpe_g[:, :, 0, :]
+        t = c_kv.shape[1]
         kv = dense(p["wukv"], c_kv).reshape(
-            b, s, h, m.qk_nope_head_dim + m.v_head_dim)
+            b, t, h, m.qk_nope_head_dim + m.v_head_dim)
         k_nope, v = jnp.split(kv, [m.qk_nope_head_dim], axis=-1)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_pe[:, :, None, :],
-                                      (b, s, h, m.qk_rope_head_dim))], axis=-1)
+                                      (b, t, h, m.qk_rope_head_dim))], axis=-1)
         q = jnp.concatenate([q_nope, q_pe], axis=-1)
         q = lc(q, "batch", "seq", "heads", "qk_dim")
         k = lc(k, "batch", "kv_seq", "heads", "qk_dim")
         v = lc(v, "batch", "kv_seq", "heads", "head_dim")
-        o = attention_core(q, k, v, q_pos=positions, kv_pos=positions,
-                           causal=True, block_kv=cfg.attn_block_kv)
+        o = attention_core(q, k, v, q_pos=positions, kv_pos=kv_pos,
+                           causal=True, block_kv=cfg.attn_block_kv,
+                           scale=softmax_scale(cfg))
         o = o.reshape(b, s, h * m.v_head_dim)
 
     if taps is not None:

@@ -1,15 +1,21 @@
-"""Dense MLP (SwiGLU / GELU) and capacity-based top-k MoE.
+"""Dense MLP (SwiGLU / GELU) and dropless top-k MoE.
 
-MoE dispatch is the XLA-friendly scatter/gather formulation: tokens are
-scattered into a per-expert (E, C, d) buffer (C = capacity), experts run as
-one batched einsum (sharded over the `expert`/model axis -> expert
-parallelism), and results are gathered back with router gates. Overflowing
-tokens are dropped (tracked in aux stats), as in Switch/GShard.
+Routing is dropless: every (token, expert) pair the router picks is
+computed. The pairs are sorted by expert and each expert's group is
+padded to whole row tiles of the grouped expert kernel, inside a static
+bound of T·k + E·(bm − 1) rows, so shapes never depend on the routing and
+a token's result never depends on which other tokens share its call (no
+capacity, no cross-token dropping). The experts then run as one grouped
+product per projection (`linear.grouped_experts`): quantized stacks on the
+chip through kernels/grouped_expert_matmul, which reads only the packed
+slabs of experts that received rows; float stacks (calibration) and the
+CPU through `jax.lax.ragged_dot`. Results are combined with the router's
+gates, renormalised over the top k unless the config says otherwise
+(DeepSeek-V2 keeps the softmax probabilities), and times the config's
+routed scaling factor.
 
-Quantized expert stacks (`QuantizedTensor` with (E, K, N) shape) run the
-(E, C) buffer through the expert-batched Pallas dequant kernel via
-`dense_experts` — the packed slabs are consumed in place, never expanded
-to a float (E, K, N) stack (see DESIGN.md "Quantized serving fast paths").
+The shard_map expert-parallel training dispatch (models/moe_shardmap.py)
+keeps its own capacity rule; it serves float experts under a mesh only.
 """
 from __future__ import annotations
 
@@ -20,7 +26,9 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import TP_AXIS, lc
 from repro.models.config import ModelConfig
-from repro.models.linear import dense, dense_experts, init_dense, init_dense_experts
+from repro.kernels.autotune import grouped_row_tile
+from repro.models.linear import (ExpertGroups, dense, grouped_experts,
+                                 init_dense, init_dense_experts)
 
 
 def init_mlp(cfg: ModelConfig, key, d_ff: int, d_in: int = 0) -> dict:
@@ -58,7 +66,7 @@ def init_moe(cfg: ModelConfig, key) -> dict:
     d, ff = cfg.d_model, m.d_ff_expert
     ks = jax.random.split(key, 5)
     p = {
-        "router": init_dense(ks[0], d, m.n_experts, dtype=jnp.float32),
+        "router": init_dense(ks[0], d, m.n_experts, dtype=cfg.pdtype),
         "experts": {
             "wi": init_dense_experts(ks[1], m.n_experts, d, ff, dtype=cfg.pdtype),
             "wg": init_dense_experts(ks[2], m.n_experts, d, ff, dtype=cfg.pdtype),
@@ -70,25 +78,49 @@ def init_moe(cfg: ModelConfig, key) -> dict:
     return p
 
 
-def moe_capacity(cfg: ModelConfig, n_tokens: int) -> int:
-    m = cfg.moe
-    c = int(m.capacity_factor * n_tokens * m.top_k / m.n_experts)
-    # round up to 8: the minimal sublane tile, so quantized expert stacks hit
-    # kernels/expert_dequant_matmul without capacity-dim padding (decode-time
-    # capacities land exactly on its skinny bm=8 tile)
-    return max(8, -(-c // 8) * 8)
+def expert_groups(flat_e: jax.Array, n_experts: int, bm: int):
+    """Lay out (token, expert) pairs in expert groups of whole `bm`-row
+    tiles. flat_e: (P,) expert of each pair, `n_experts` for a pair that
+    routes nowhere. Returns (ExpertGroups, dest (P,) row of each pair —
+    past the buffer for the unrouted, counts (E,) real rows an expert
+    got). The buffer has the static T·k + E·(bm − 1) rows, in tiles."""
+    e = n_experts
+    n_pairs = flat_e.shape[0]
+    n_rows = -(-(n_pairs + e * (bm - 1)) // bm) * bm
+    counts = jnp.zeros((e + 1,), jnp.int32).at[flat_e].add(1)[:e]
+    sizes = -(-counts // bm) * bm
+    ends = jnp.cumsum(sizes)
+    order = jnp.argsort(flat_e, stable=True)
+    sorted_e = flat_e[order]
+    ex = jnp.minimum(sorted_e, e - 1)
+    first = jnp.searchsorted(sorted_e, jnp.arange(e), side="left")
+    rank = jnp.arange(n_pairs, dtype=jnp.int32) - first[ex].astype(jnp.int32)
+    dest_sorted = jnp.where(sorted_e < e, ends[ex] - sizes[ex] + rank,
+                            n_rows)
+    dest = jnp.zeros((n_pairs,), jnp.int32).at[order].set(dest_sorted)
+    n_tiles = n_rows // bm
+    n_live = ends[-1] // bm
+    starts = jnp.arange(n_tiles, dtype=jnp.int32) * bm
+    tile_e = jnp.searchsorted(ends, starts, side="right")
+    # dead tiles repeat the last live tile's expert: the kernel's index
+    # maps then stay on blocks it already holds
+    last = tile_e[jnp.maximum(n_live - 1, 0)]
+    tile_e = jnp.where(jnp.arange(n_tiles) < n_live, tile_e, last)
+    groups = ExpertGroups(sizes.astype(jnp.int32),
+                          jnp.minimum(tile_e, e - 1).astype(jnp.int32),
+                          n_live.reshape(1).astype(jnp.int32), bm)
+    return groups, dest, counts
 
 
 def apply_moe(cfg: ModelConfig, p: dict, x: jax.Array,
               taps: Optional[dict] = None, tap_prefix: str = "",
               valid: Optional[jax.Array] = None):
-    """x: (B, S, d). Returns (y, aux_loss_scalar).
+    """x: (B, S, d). Returns (y, aux): aux["loss"] is the router's
+    load-balancing loss, aux["load"] (3,) int32 the call's routing counts
+    [rows routed, experts that got rows, the busiest expert's rows].
 
-    `valid` (B, S) marks real tokens; False = left-padding (continuous-
-    batching prefill). Pad tokens are routed straight to the overflow bin so
-    they neither consume expert capacity nor shift real tokens' dispatch
-    positions — without this, junk pads can displace real tokens whenever
-    capacity binds.
+    `valid` (B, S) marks real tokens; False = padding (continuous-batching
+    prefill pads, idle decode slots), which routes to no expert.
     """
     m = cfg.moe
     b, s, d = x.shape
@@ -106,10 +138,10 @@ def apply_moe(cfg: ModelConfig, p: dict, x: jax.Array,
                 dp *= mesh.shape.get(a, 1)
             if b % dp == 0 and (b // dp) * s % mesh.shape["model"] == 0:
                 from repro.models.moe_shardmap import moe_ep_shardmap
-                return moe_ep_shardmap(cfg, p, x, mesh)
+                y, loss = moe_ep_shardmap(cfg, p, x, mesh)
+                return y, {"loss": loss, "load": jnp.zeros(3, jnp.int32)}
     t = b * s
     e, k = m.n_experts, m.top_k
-    cap = moe_capacity(cfg, t)
     xf = x.reshape(t, d)
 
     if taps is not None:
@@ -118,51 +150,51 @@ def apply_moe(cfg: ModelConfig, p: dict, x: jax.Array,
     logits = dense(p["router"], xf.astype(jnp.float32))          # (T, E)
     probs = jax.nn.softmax(logits, axis=-1)
     gate, idx = jax.lax.top_k(probs, k)                          # (T, k)
-    gate = gate / jnp.maximum(jnp.sum(gate, axis=-1, keepdims=True), 1e-9)
+    if m.norm_topk_prob:
+        gate = gate / jnp.maximum(jnp.sum(gate, axis=-1, keepdims=True),
+                                  1e-9)
+    if m.routed_scaling_factor != 1.0:
+        gate = gate * m.routed_scaling_factor
 
-    # slot positions within each expert — sort-based (O(T·k) memory; the
-    # one-hot/cumsum formulation is O(T·k·E) and blows up at pod scale)
-    flat_idx = idx.reshape(t * k)
+    flat_e = idx.reshape(t * k)
     if valid is not None:
-        flat_idx = jnp.where(jnp.repeat(valid.reshape(t), k), flat_idx, e)
-    order = jnp.argsort(flat_idx, stable=True)
-    sorted_e = flat_idx[order]
-    starts = jnp.searchsorted(sorted_e, jnp.arange(e), side="left")
-    pos_sorted = jnp.arange(t * k, dtype=jnp.int32) - \
-        starts[sorted_e].astype(jnp.int32)
-    pos = jnp.zeros((t * k,), jnp.int32).at[order].set(pos_sorted)
+        flat_e = jnp.where(jnp.repeat(valid.reshape(t), k), flat_e, e)
+    groups, dest, counts = expert_groups(flat_e, e,
+                                         grouped_row_tile(t * k, e))
 
     # load-balancing aux loss (Switch): E * sum_e f_e * p_e
     me = jnp.mean(probs, axis=0)
-    counts = jnp.zeros((e,), jnp.float32).at[flat_idx].add(1.0)
-    ce = counts / t
-    aux = m.router_aux_weight * e * jnp.sum(me * ce)
+    aux = m.router_aux_weight * e * jnp.sum(me * counts / t)
+    load = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0),
+                      jnp.max(counts)]).astype(jnp.int32)
 
-    keep = pos < cap
-    safe_e = jnp.where(keep, flat_idx, e)                        # overflow -> bin E
-    safe_pos = jnp.where(keep, pos, 0)
-
-    # dispatch: (E+1, C, d) scatter (unique (e,pos) per slot -> add == set)
-    xk = jnp.repeat(xf[:, None, :], k, axis=1).reshape(t * k, d)
-    buf = jnp.zeros((e + 1, cap, d), x.dtype).at[safe_e, safe_pos].add(xk)
-    buf = lc(buf[:e], "expert", "capacity", "embed")
-
+    # dispatch: each pair's token row at its place in its expert's group
+    rows = jnp.zeros((groups.tile_expert.shape[0] * groups.bm, d), x.dtype)
+    rows = rows.at[dest].set(xf[jnp.arange(t * k) // k], mode="drop")
     if taps is not None:
-        taps[tap_prefix + "experts"] = buf
+        # per-expert calibration inputs (E, T, d): a token's row where it
+        # routes to the expert, zero elsewhere (zero rows add nothing to a
+        # GPTQ Hessian)
+        hit = (flat_e[None, :] == jnp.arange(e)[:, None]).reshape(e, t, k)
+        taps[tap_prefix + "experts"] = jnp.where(
+            hit.any(-1)[..., None], xf[None], 0).astype(x.dtype)
 
-    h = jax.nn.silu(dense_experts(p["experts"]["wg"], buf)) * \
-        dense_experts(p["experts"]["wi"], buf)
-    h = lc(h, "expert", "capacity", "mlp")
+    ex = p["experts"]
+    h = jax.nn.silu(grouped_experts(ex["wg"], rows, groups)) * \
+        grouped_experts(ex["wi"], rows, groups)
     if taps is not None:
-        taps[tap_prefix + "experts_out"] = h
-    out = dense_experts(p["experts"]["wo"], h)                   # (E, C, d)
-    out = lc(out, "expert", "capacity", "embed")
+        hp = jnp.take(h, dest, axis=0, mode="fill", fill_value=0)
+        taps[tap_prefix + "experts_out"] = jnp.zeros(
+            (e + 1, t, h.shape[-1]), h.dtype).at[
+                flat_e, jnp.arange(t * k) // k].set(hp)[:e]
+    out = grouped_experts(ex["wo"], h, groups)                   # (R, d)
 
-    # combine
-    gathered = out[jnp.minimum(safe_e, e - 1), safe_pos]         # (T*k, d)
-    gathered = gathered * (keep[:, None] & (safe_e < e)[:, None])
-    gathered = gathered * gate.reshape(t * k, 1).astype(x.dtype)
-    y = jnp.sum(gathered.reshape(t, k, d), axis=1)
+    # combine: each pair's expert row times its gate (unrouted pairs read
+    # zero), summed over the token's k pairs
+    pairs = jnp.take(out, dest, axis=0, mode="fill", fill_value=0)
+    y = jnp.sum((pairs.astype(jnp.float32)
+                 * gate.reshape(t * k, 1)).reshape(t, k, d), axis=1)
+    y = y.astype(x.dtype).reshape(b, s, d)
 
     if "shared" in p:
         if taps is not None:
@@ -172,7 +204,5 @@ def apply_moe(cfg: ModelConfig, p: dict, x: jax.Array,
                 taps[tap_prefix + "shared/" + kk] = vv
         else:
             ysh = apply_mlp(cfg, p["shared"], x)
-        y = y.reshape(b, s, d) + ysh
-    else:
-        y = y.reshape(b, s, d)
-    return lc(y, "batch", "seq", "embed"), aux
+        y = y + ysh
+    return lc(y, "batch", "seq", "embed"), {"loss": aux, "load": load}

@@ -79,6 +79,10 @@ def num_blocks(cfg: ModelConfig) -> int:
     return cfg.n_layers
 
 
+def has_moe(cfg: ModelConfig) -> bool:
+    return any(s.mlp == "moe" for s in cfg.all_layer_specs())
+
+
 def block_spec(cfg: ModelConfig, i: int):
     np_ = len(cfg.prefix_pattern)
     if i < np_:
@@ -131,10 +135,13 @@ def _run_stack(cfg: ModelConfig, params: dict, x: jax.Array, *,
                positions: jax.Array, mode: str, cache: Optional[dict],
                enc_out: Optional[jax.Array] = None,
                paged: Optional[dict] = None):
-    """Prefix blocks then scanned pattern repeats. Returns (x, new_cache, aux).
+    """Prefix blocks then scanned pattern repeats. Returns (x, new_cache,
+    aux, load): aux the summed auxiliary loss, load (L_moe, 3) int32 the
+    routing counts of every MoE block (see `apply_moe`).
     `paged` (block-table indices) is loop-invariant across layers — each
     block's page pool is indexed by the same per-slot tables."""
     aux_total = jnp.zeros((), jnp.float32)
+    loads = []
     new_cache: dict = {} if cache is not None else None
 
     for i, spec in enumerate(cfg.prefix_pattern):
@@ -142,7 +149,8 @@ def _run_stack(cfg: ModelConfig, params: dict, x: jax.Array, *,
         x, nc, aux = apply_block(cfg, spec, params["prefix"][str(i)], x,
                                  positions=positions, mode=mode, cache=c,
                                  enc_out=enc_out, paged=paged)
-        aux_total += aux
+        aux_total += aux["loss"]
+        loads.append(aux["load"])
         if cache is not None:
             new_cache.setdefault("prefix", {})[str(i)] = nc
 
@@ -153,15 +161,16 @@ def _run_stack(cfg: ModelConfig, params: dict, x: jax.Array, *,
 
     def one_repeat(x, slices, cslices):
         aux_sum = jnp.zeros((), jnp.float32)
-        ncs = []
+        ncs, lds = [], []
         for j, spec in enumerate(pat):
             x, nc, aux = apply_block(
                 cfg, spec, slices[j], x, positions=positions, mode=mode,
                 cache=cslices[j] if cslices is not None else None,
                 enc_out=enc_out, paged=paged)
-            aux_sum += aux
+            aux_sum += aux["loss"]
             ncs.append(nc)
-        return x, tuple(ncs), aux_sum
+            lds.append(aux["load"])
+        return x, tuple(ncs), aux_sum, jnp.concatenate(lds)
 
     if cfg.remat and mode == "train":
         policy = {
@@ -175,13 +184,14 @@ def _run_stack(cfg: ModelConfig, params: dict, x: jax.Array, *,
             x, aux_sum = carry
             slices = xs[0]
             cslices = xs[1] if cache is not None else None
-            x, ncs, aux = one_repeat(x, slices, cslices)
-            return (x, aux_sum + aux), ncs
+            x, ncs, aux, ld = one_repeat(x, slices, cslices)
+            return (x, aux_sum + aux), (ncs, ld)
 
-        (x, aux_scan), ncs_stacked = jax.lax.scan(
+        (x, aux_scan), (ncs_stacked, ld_stacked) = jax.lax.scan(
             body, (x, jnp.zeros((), jnp.float32)),
             (stacks, cstacks if cache is not None else None))
         aux_total += aux_scan
+        loads.append(ld_stacked.reshape(-1, 3))
         if cache is not None:
             new_cache["stack"] = {f"p{j}": ncs_stacked[j]
                                   for j in range(len(pat))}
@@ -190,8 +200,9 @@ def _run_stack(cfg: ModelConfig, params: dict, x: jax.Array, *,
             slices = tuple(tree_index(s, r) for s in stacks)
             cslices = (tuple(tree_index(c, r) for c in cstacks)
                        if cache is not None else None)
-            x, ncs, aux = one_repeat(x, slices, cslices)
+            x, ncs, aux, ld = one_repeat(x, slices, cslices)
             aux_total += aux
+            loads.append(ld)
             if cache is not None:
                 for j in range(len(pat)):
                     new_cache.setdefault("stack", {}).setdefault(
@@ -199,7 +210,7 @@ def _run_stack(cfg: ModelConfig, params: dict, x: jax.Array, *,
         if cache is not None and "stack" in new_cache:
             new_cache["stack"] = {k: tree_stack(v)
                                   for k, v in new_cache["stack"].items()}
-    return x, new_cache, aux_total
+    return x, new_cache, aux_total, jnp.concatenate(loads)
 
 
 def _head(cfg: ModelConfig, params: dict, x: jax.Array) -> jax.Array:
@@ -223,16 +234,17 @@ def lm_forward(cfg: ModelConfig, params: dict, tokens: jax.Array,
     s = tokens.shape[1] + (ext_embeds.shape[1] if ext_embeds is not None else 0)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     x = _embed(cfg, params, tokens, ext_embeds, positions)
-    x, _, aux = _run_stack(cfg, params, x, positions=positions,
-                           mode="train", cache=None)
+    x, _, aux, _ = _run_stack(cfg, params, x, positions=positions,
+                              mode="train", cache=None)
     return _head(cfg, params, x), aux
 
 
 def lm_prefill(cfg: ModelConfig, params: dict, tokens: jax.Array, cache: dict,
                ext_embeds: Optional[jax.Array] = None,
                positions: Optional[jax.Array] = None,
-               paged: Optional[dict] = None):
-    """Prompt ingestion. Returns (last-token logits (B, V), new_cache).
+               paged: Optional[dict] = None, with_load: bool = False):
+    """Prompt ingestion. Returns (last-token logits (B, V), new_cache), and
+    with `with_load` the MoE routing counts (L_moe, 3) (`_run_stack`).
 
     `positions` (B, S) overrides the default arange for continuous batching:
     left-padded prompts mark pads with -1 (masked everywhere, routed to the
@@ -245,22 +257,29 @@ def lm_prefill(cfg: ModelConfig, params: dict, tokens: jax.Array, cache: dict,
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None],
                                      (b, s))
     x = _embed(cfg, params, tokens, ext_embeds, positions)
-    x, new_cache, _ = _run_stack(cfg, params, x, positions=positions,
-                                 mode="prefill", cache=cache, paged=paged)
+    x, new_cache, _, load = _run_stack(cfg, params, x, positions=positions,
+                                       mode="prefill", cache=cache,
+                                       paged=paged)
     logits = _head(cfg, params, x[:, -1:, :])
+    if with_load:
+        return logits[:, 0, :], new_cache, load
     return logits[:, 0, :], new_cache
 
 
 def lm_decode(cfg: ModelConfig, params: dict, tokens: jax.Array,
               cache: dict, positions: jax.Array,
-              paged: Optional[dict] = None):
+              paged: Optional[dict] = None, with_load: bool = False):
     """One decode step. tokens: (B, 1); positions: (B, 1) absolute. With a
     paged cache, B is the slot count and `paged` holds per-slot write
-    targets plus the block tables for gather-based reads."""
+    targets plus the block tables for gather-based reads. With
+    `with_load`, also the MoE routing counts (L_moe, 3) (`_run_stack`)."""
     x = _embed(cfg, params, tokens, None, positions)
-    x, new_cache, _ = _run_stack(cfg, params, x, positions=positions,
-                                 mode="decode", cache=cache, paged=paged)
+    x, new_cache, _, load = _run_stack(cfg, params, x, positions=positions,
+                                       mode="decode", cache=cache,
+                                       paged=paged)
     logits = _head(cfg, params, x)
+    if with_load:
+        return logits[:, 0, :], new_cache, load
     return logits[:, 0, :], new_cache
 
 
@@ -276,8 +295,8 @@ def lm_verify(cfg: ModelConfig, params: dict, tokens: jax.Array,
     new_cache); row m is the next-token distribution after the prefix plus
     tokens[:, :m+1]."""
     x = _embed(cfg, params, tokens, None, positions)
-    x, new_cache, _ = _run_stack(cfg, params, x, positions=positions,
-                                 mode="prefill", cache=cache, paged=paged)
+    x, new_cache, _, _ = _run_stack(cfg, params, x, positions=positions,
+                                    mode="prefill", cache=cache, paged=paged)
     return _head(cfg, params, x), new_cache
 
 

@@ -6,7 +6,7 @@ engines accept `quant_bits=...` to pack a float tree in place via
 `quantize_params_for_serving`). Decode steps present M = n_slots (or
 batch) token rows per linear, which rides the decode-shaped skinny-M
 kernel tiles picked by kernels/ops.py; quantized MoE experts run the
-expert-batched kernel without materializing float expert stacks. Two
+grouped expert kernel without materializing float expert stacks. Two
 engines share the model code:
 
   * ServeEngine        — static batch: one prompt length, lockstep decode to
@@ -40,8 +40,10 @@ launch/serve.py; admission policy lives in serve/scheduler.py.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
+import itertools
 import zlib
 from typing import Optional, Sequence
 
@@ -60,8 +62,8 @@ from repro.distributed.partitioning import (paged_pool_pspecs,
                                             serve_tp_widths, tp_local_cfg)
 from repro.distributed.sharding import TP_AXIS, sharding_ctx
 from repro.models.config import ModelConfig
-from repro.models.transformer import (init_cache, lm_decode, lm_forward,
-                                      lm_prefill, lm_verify)
+from repro.models.transformer import (has_moe, init_cache, lm_decode,
+                                      lm_forward, lm_prefill, lm_verify)
 from repro.serve.faults import FaultInjected, FaultPlan, KernelFaultInjected
 from repro.serve.kvcache import (POOL_KEYS, PagePool, PageSpec,
                                  default_page_spec, pool_head_dim)
@@ -163,6 +165,16 @@ class ServeEngine:
 
 # ------------------------------------------------------- continuous batching
 
+# The MoE routing counts of every step of every ContinuousEngine in this
+# process, newest last, one entry per prefill call and per decode block:
+# (engine serial, the step's `now`, "prefill" | "decode", MoE layer calls,
+# rows routed, experts that got rows, the busiest expert's rows summed
+# over the calls). It outlives the engine, so a benchmark can read the
+# counts of a window of steps after it has freed the engine.
+MOE_LOG: collections.deque = collections.deque(maxlen=1 << 16)
+_ENGINE_SERIAL = itertools.count()
+
+
 def _params_sig(params) -> str:
     """Coarse weight signature for sanitizer trace keys: the quantized
     bit-widths present in the tree ("w2", "w4/8"), or "f32". Target and
@@ -182,7 +194,7 @@ def _paged_prefill_jit(cfg, params, tokens, cache, positions, paged):
                bucket=tokens.shape[1], impl=cfg.paged_attn_impl,
                w=_params_sig(params))
     return lm_prefill(cfg, params, tokens, cache, positions=positions,
-                      paged=paged)
+                      paged=paged, with_load=True)
 
 
 @functools.partial(jax.jit, static_argnames=("temperature", "top_k"))
@@ -308,17 +320,20 @@ def _decode_scan(cfg, params, cache, last_tok, cur_len, active,
     The alive mask is the graceful-degradation sentinel: one cheap (S,)
     isfinite reduction over each step's logits. A slot whose logits go
     non-finite is *deactivated inside the scan* — its token freezes, its
-    fill count stops, and its rows stop feeding the model — so a poisoned
-    slot cannot perturb co-batched slots through cross-token paths
-    (capacity-MoE routing) on later steps of the same block. The host
-    reads alive, drops the garbage token, and quarantines the request.
+    fill count stops, and its rows stop feeding the model on later steps
+    of the same block. The host reads alive, drops the garbage token, and
+    quarantines the request.
     `poison` (S,) bool is the fault-injection hook: marked slots get NaN
     logits on the first step, exercising exactly the real failure path.
 
     Shared by the single-device jit and the shard_map TP jit below — under
     TP, `cfg` is the head-localized per-shard view and `params`/`cache`
     are the shard-local slices (tokens, lengths, tables, key replicated).
+    An MoE model's scan also returns each step's routing counts, (K,
+    L_moe, 3) (`apply_moe`), ahead of the cache; other models return None
+    there.
     """
+    moe = has_moe(cfg)
     n_slots, max_pages = block_table.shape
     sl = jnp.arange(n_slots)
     if poison is None:
@@ -337,8 +352,10 @@ def _decode_scan(cfg, params, cache, last_tok, cur_len, active,
             "kv_len": jnp.where(act, clen + 1, 0),
         }
         pos = jnp.where(act, clen, 0)[:, None]
-        logits, cache = lm_decode(cfg, params, tok[:, None], cache, pos,
-                                  paged=paged)
+        out = lm_decode(cfg, params, tok[:, None], cache, pos, paged=paged,
+                        with_load=moe)
+        logits, cache = out[:2]
+        load = out[2] if moe else None
         logits = jnp.where((first & poison)[:, None],
                            jnp.float32(jnp.nan).astype(logits.dtype), logits)
         # sentinel: a slot dies the step its logits stop being finite
@@ -349,15 +366,14 @@ def _decode_scan(cfg, params, cache, last_tok, cur_len, active,
         tok = jnp.where(keep, nxt, tok)
         clen = clen + keep.astype(clen.dtype)
         return (cache, tok, clen, key, alive), (
-            (nxt, logits, alive) if with_logits else (nxt, alive))
+            (nxt, logits, alive, load) if with_logits
+            else (nxt, alive, load))
 
     first = jnp.zeros(k_steps, bool).at[0].set(True)
     (cache, _, _, _, _), ys = jax.lax.scan(
         body, (cache, last_tok, cur_len, key, jnp.ones(n_slots, bool)),
         first, length=k_steps)
-    if with_logits:
-        return ys[0], ys[1], ys[2], cache
-    return ys[0], ys[1], cache
+    return ys + (cache,)
 
 
 @functools.partial(jax.jit,
@@ -412,9 +428,9 @@ def _spec_block_jit(cfg, params, draft_params, cache, draft_cache, last_tok,
                          page_size=page_size, temperature=temperature,
                          top_k=top_k, with_logits=(temperature > 0.0))
     if temperature > 0.0:
-        draft_toks, draft_logits, _, draft_cache = draft
+        draft_toks, draft_logits, _, _, draft_cache = draft
     else:
-        (draft_toks, _, draft_cache), draft_logits = draft, None
+        (draft_toks, _, _, draft_cache), draft_logits = draft, None
     # verify rows: [last_tok, d_1..d_k] at absolute positions cur_len..
     # cur_len+k (inactive slots parked at -1 / kv_len 0 — their writes land
     # on the scratch page and their rows read as garbage we never emit)
@@ -497,10 +513,11 @@ def _paged_decode_scan_tp_jit(cfg, mesh, params, cache, last_tok, cur_len,
              poison):
         params = localize_quantized(params)
         with sharding_ctx(None):
-            return _decode_scan(lcfg, params, cache, last_tok, cur_len,
-                                active, block_table, key, k_steps=k_steps,
-                                page_size=page_size, temperature=temperature,
-                                top_k=top_k, poison=poison)
+            toks, alive, _, cache = _decode_scan(
+                lcfg, params, cache, last_tok, cur_len, active, block_table,
+                key, k_steps=k_steps, page_size=page_size,
+                temperature=temperature, top_k=top_k, poison=poison)
+            return toks, alive, cache
 
     return shard_map(body, mesh=mesh,
                      in_specs=(pspecs, cspecs, rep, rep, rep, rep, rep, rep),
@@ -538,8 +555,9 @@ class ContinuousEngine:
     page multiple), spreading a long prompt across `step()` ticks so
     decode slots keep stepping instead of stalling behind it. Both
     features need the gathered-context prefill read path and per-page
-    prompt state, so they cover attention-only decoders (no SSM state, no
-    MLA latent prefill). See DESIGN.md "Prefix cache & chunked prefill".
+    prompt state, so they cover attention decoders, latent (MLA) ones
+    included, and no SSM state. See DESIGN.md "Prefix cache & chunked
+    prefill".
 
     `spec_decode=True` swaps the decode block for self-speculative
     rounds: a W2/W3 draft packed from the *same* params proposes up to
@@ -621,9 +639,8 @@ class ContinuousEngine:
                           for spec in cfg.all_layer_specs())
             if has_ssm or cfg.attention == "mla":
                 # SSM recurrence state is slot-indexed, not page-addressed
-                # (a spill snapshot of pages misses it), and a mid-prefill
-                # MLA victim would need the gathered-context suffix
-                # prefill MLA doesn't have — same wall as chunked prefill
+                # (a spill snapshot of pages misses it); spilling and
+                # restoring MLA's latent pools has no test yet
                 raise NotImplementedError(
                     "preempt covers attention-only decoders "
                     "(no SSM blocks, no MLA)")
@@ -636,16 +653,12 @@ class ContinuousEngine:
                     "preempt + spec_decode is an open item (the draft "
                     "cache would need spilling in lockstep)")
         if prefix_share or chunked_prefill:
-            has_ssm = any(spec.kind != "attn"
-                          for spec in cfg.all_layer_specs())
-            if has_ssm or cfg.attention == "mla":
+            if any(spec.kind != "attn" for spec in cfg.all_layer_specs()):
                 # SSM state is not page-addressed (a shared page carries no
-                # recurrence state) and MLA's non-absorbed prefill never
-                # reads the paged latent back — both would be silently
-                # wrong, so refuse up front
+                # recurrence state) — silently wrong, so refuse up front
                 raise NotImplementedError(
-                    "prefix_share/chunked_prefill cover attention-only "
-                    "decoders (no SSM blocks, no MLA)")
+                    "prefix_share/chunked_prefill cover attention decoders "
+                    "(no SSM blocks)")
         if paged_attn is not None:
             # per-engine override of the decode attention path: "fused"
             # (paged-attention kernel) or "gather" (oracle). Threaded via
@@ -666,12 +679,15 @@ class ContinuousEngine:
                     "verify forward rides the paged gathered/fused read "
                     "(no SSM recurrence rewind, no MLA latent prefill)")
             if any(s.mlp == "moe" for s in specs):
-                # capacity routing is cross-token: an (S, M) verify batch
-                # can route tokens differently from M single-token decode
-                # steps, so draft/target parity (and greedy losslessness)
-                # would silently break
+                # routing is per token (dropless), but the grouped expert
+                # kernel's row tile, and with it its K blocking and f32
+                # summation order, follows a call's row count: an (S, M)
+                # verify batch can round an expert product differently
+                # from M single-token decode steps, and greedy speculation
+                # would no longer be bit-identical to plain decode. No
+                # verify-against-decode parity test covers MoE yet
                 raise NotImplementedError(
-                    "spec_decode does not cover capacity-routed MoE")
+                    "spec_decode does not cover MoE")
             if tp > 1 or prefix_share:
                 raise NotImplementedError(
                     "spec_decode is single-device and unshared for now "
@@ -702,9 +718,9 @@ class ContinuousEngine:
         self.n_slots = n_slots
         self.eos_id = eos_id
         self.prefill_bucket = max(1, prefill_bucket)
-        # prefill_batch=1 avoids co-batched prefills entirely: capacity-MoE
-        # routing is cross-token, so co-batched requests can perturb each
-        # other's expert assignment when capacity binds (see DESIGN.md)
+        # co-batched prefills are exact for every architecture: MoE
+        # routing is dropless and per token (see DESIGN.md), so a request's
+        # tokens do not depend on which requests share its prefill call
         self.prefill_batch = max(1, prefill_batch)
         self.decode_block = max(1, decode_block)
         self.temperature = temperature
@@ -784,6 +800,16 @@ class ContinuousEngine:
         self.n_pages_live = 0
         self.n_prefills = 0
         self.n_prefill_tokens = 0    # real prompt tokens actually prefilled
+        # MoE routing, summed over every MoE layer call of every prefill
+        # and decode step: calls, (token, expert) rows routed, experts
+        # that received rows, and the busiest expert's rows
+        self.n_moe_calls = 0
+        self.n_moe_rows = 0
+        self.n_moe_experts_hit = 0
+        self.n_moe_busiest_rows = 0
+        self._moe = has_moe(cfg)
+        self._moe_pending: list = []   # (phase, now, device counts)
+        self.serial = next(_ENGINE_SERIAL)
         self.n_shared_tokens = 0     # prompt tokens served from the prefix cache
         # preemption accounting: pages actually moved (kept-by-reference
         # shared pages never count — spill must not duplicate them)
@@ -1030,6 +1056,8 @@ class ContinuousEngine:
     # -------------------------------------------- engine snapshot / restore
     _SNAP_COUNTERS = ("n_decode_steps", "n_decode_tokens", "n_pages_held",
                       "n_pages_live", "n_prefills", "n_prefill_tokens",
+                      "n_moe_calls", "n_moe_rows", "n_moe_experts_hit",
+                      "n_moe_busiest_rows",
                       "n_shared_tokens", "n_spilled_pages",
                       "n_restored_pages", "n_spec_rounds", "n_draft_tokens",
                       "n_spec_emitted", "n_kernel_fallbacks",
@@ -1300,7 +1328,7 @@ class ContinuousEngine:
                 if self.spec_decode:
                     self._spec_block(self.active.copy(), now)
                 else:
-                    toks, alive = self._decode_block()        # (K, n_slots)
+                    toks, alive = self._decode_block(now)     # (K, n_slots)
                     self._emit_block(toks, alive, act, now)
             occupied = np.asarray([r is not None for r in self.sched.slots])
             self.n_pages_held += int((self.pool.tables[occupied] >= 0).sum())
@@ -1479,14 +1507,16 @@ class ContinuousEngine:
                     self.cfg, self.mesh, self.params, jnp.asarray(toks),
                     self.cache, jnp.asarray(pos), paged)
             else:
-                logits, self.cache = _paged_prefill_jit(
+                logits, self.cache, load = _paged_prefill_jit(
                     self.cfg, self.params, jnp.asarray(toks), self.cache,
                     jnp.asarray(pos), paged)
+                if self._moe:
+                    self._moe_pending.append(("prefill", now, load))
             if self.spec_decode:
                 # mirror the chunk into the draft cache so draft decode
                 # starts from the same fill state (its logits are discarded
                 # — the first token is always the target's)
-                _, self.draft_cache = _paged_prefill_jit(
+                _, self.draft_cache, _ = _paged_prefill_jit(
                     self.cfg, self.draft_params, jnp.asarray(toks),
                     self.draft_cache, jnp.asarray(pos), paged)
             self.n_prefills += 1
@@ -1507,7 +1537,7 @@ class ContinuousEngine:
                 temperature=self.temperature, top_k=self.top_k)
         with spans.span(spans.PREFILL_WAIT,
                         rids=spans.ids(items[row][1].rid for row in finish)):
-            first, fin_ok = np.asarray(first), np.asarray(fin_ok)
+            first, fin_ok = self._fetch(first, fin_ok)
         for tok, okf, row in zip(first, fin_ok, finish):
             slot, req, _, _ = items[row]
             del self._prefilling[slot]
@@ -1524,7 +1554,7 @@ class ContinuousEngine:
                 self.pool.register_prefix(req.prompt, slot)
             self._emit(slot, req, int(tok), now)
 
-    def _decode_block(self) -> tuple[np.ndarray, np.ndarray]:
+    def _decode_block(self, now: float) -> tuple[np.ndarray, np.ndarray]:
         """One fused block of decode steps; returns ((K, n_slots) tokens,
         (K, n_slots) alive) — alive[t, s] False marks s's tokens from step
         t on as garbage (non-finite logits; the caller quarantines).
@@ -1582,12 +1612,13 @@ class ContinuousEngine:
                         raise KernelFaultInjected(
                             "injected kernel dispatch failure")
                 if self.tp > 1:
-                    return _paged_decode_scan_tp_jit(
+                    toks, alive, cache = _paged_decode_scan_tp_jit(
                         self.cfg, self.mesh, *args, **kw)
+                    return toks, alive, None, cache
                 return _paged_decode_scan_jit(self.cfg, *args, **kw)
 
             try:
-                toks, alive, self.cache = dispatch()
+                toks, alive, load, self.cache = dispatch()
             except KernelFaultInjected:
                 # the injected kernel_fault (raised before the donated cache
                 # is consumed, so the retry is safe): permanently fall back
@@ -1598,12 +1629,32 @@ class ContinuousEngine:
                 # propagates.
                 self.cfg = self.cfg.replace(paged_attn_impl="gather")
                 self.n_kernel_fallbacks += 1
-                toks, alive, self.cache = dispatch()
+                toks, alive, load, self.cache = dispatch()
+            if self._moe:
+                self._moe_pending.append(("decode", now, load))
             self.cur_len[act] += k_steps
             self.n_decode_steps += k_steps
             sp.set_metadata(k=k_steps, slots=int(act.sum()), width=width)
         with spans.span(spans.DECODE_WAIT):
-            return np.asarray(toks), np.asarray(alive)
+            return self._fetch(toks, alive)
+
+    def _fetch(self, *arrays) -> list:
+        """The host copies of `arrays`, fetched in one transfer with the
+        routing counts of the MoE calls dispatched since the last fetch,
+        which the MoE counters and MOE_LOG then take up."""
+        pending, self._moe_pending = self._moe_pending, []
+        host = jax.device_get((arrays, [c for _, _, c in pending]))
+        for (phase, now, _), counts in zip(pending, host[1]):
+            counts = np.asarray(counts).reshape(-1, 3)
+            n, (rows, hit, _) = len(counts), counts.sum(axis=0)
+            busiest = int(counts[:, 2].sum())
+            self.n_moe_calls += n
+            self.n_moe_rows += int(rows)
+            self.n_moe_experts_hit += int(hit)
+            self.n_moe_busiest_rows += busiest
+            MOE_LOG.append((self.serial, now, phase, n, int(rows), int(hit),
+                            busiest))
+        return [np.asarray(a) for a in host[0]]
 
     def _spec_block(self, act: np.ndarray, now: float) -> None:
         """One speculative round over all decoding slots.

@@ -27,7 +27,7 @@ def test_cold_cache_resolution_is_the_fallback_table(monkeypatch):
     monkeypatch.delenv("REPRO_AUTOTUNE_CACHE", raising=False)
     shapes = [(1, 128, 256, 4, 32), (64, 256, 512, 2, -1),
               (9, 128, 128, 8, 64)]
-    for kind in ("dequant", "expert_dequant", "w8a8", "expert_w8a8"):
+    for kind in ("dequant", "w8a8", "grouped_expert", "grouped_w8a8"):
         for m, k, n, bits, gs in shapes:
             want = autotune.fallback_matmul_plan(
                 m, k, n, kind=kind, bits=bits, group_size=gs, bm=128,
@@ -170,8 +170,8 @@ def test_row_tile_searches_divisors_not_halvings():
 UNCHANGED = [
     ("w8a8", 64, 4096, 13696, 4, (64, 128, 256)),
     ("w8a8", 8, 4096, 4096, 4, (8, 512, 512)),
-    ("expert_dequant", 64, 13696, 4096, 4, (64, 256, 128)),
-    ("expert_w8a8", 128, 6144, 24576, 3, (128, 256, 256)),
+    ("w8a8", 64, 13696, 4096, 4, (64, 256, 128)),
+    ("w8a8", 128, 6144, 24576, 3, (128, 256, 256)),
     ("dequant", 256, 4096, 13696, 4, (128, 128, 256)),
     ("dequant", 512, 13696, 4096, 4, (128, 256, 128)),
     ("dequant", 256, 6144, 24576, 3, (128, 256, 256)),
@@ -185,6 +185,43 @@ def test_other_kinds_and_large_m_keep_the_table(kind, m, k, n, bits, want):
     rec = autotune.issued_plans()[autotune.matmul_key(kind, m, k, n, bits,
                                                       128)]
     assert rec["regime"] == ("skinny" if m <= 8 else "prefill")
+
+
+@pytest.mark.parametrize("n,want", [
+    (4096, 256), (13696, 128),    # lane tiles up to the target
+    (576, 576), (80, 80),         # no lane tile fits: N whole
+])
+def test_table_n_blocks_are_lane_tiles(n, want):
+    """A table plan's N block is a multiple of 128 that divides N, or N
+    whole: halving 576 would give a 64-lane block Mosaic refuses."""
+    assert autotune.lane_block(n, 256) == want
+    assert autotune.fallback_matmul_plan(
+        1024, 2048, n, kind="dequant", bits=4, group_size=128, bm=128,
+        bn=256, bk=256)[1] == want
+
+
+@pytest.mark.parametrize("rows,experts,want", [
+    (384, 64, 16),      # 64 decode slots, top-6 of 64 experts
+    (768, 64, 32),      # a 128-token prefill
+    (6144, 64, 128),    # a 1024-token prefill: capped at one row tile
+    (8, 4, 16),         # the minimal tile
+])
+def test_grouped_row_tile_holds_an_expert_in_one_tile(rows, experts, want):
+    assert autotune.grouped_row_tile(rows, experts) == want
+
+
+def test_grouped_kinds_take_the_row_tile_plan():
+    """The grouped expert kinds walk one row tile per call step: their
+    weight blocks are the row-tile plan's (DeepSeek-V2-Lite's expert
+    widths at 16-row tiles, W4 g128)."""
+    for kind in ("grouped_expert", "grouped_w8a8"):
+        assert autotune.matmul_plan(kind, 16, 2048, 1408, bits=4,
+                                    group_size=128) == (16, 1408, 256)
+        assert autotune.matmul_plan(kind, 16, 1408, 2048, bits=4,
+                                    group_size=128) == (16, 256, 1408)
+        rec = autotune.issued_plans()[autotune.matmul_key(
+            kind, 16, 1408, 2048, 4, 128)]
+        assert rec["regime"] == "row_tile" and rec["grid_steps"] == 8
 
 
 # ------------------------------------------------------- shape-class keys

@@ -3,8 +3,9 @@
 Interpret mode cannot see Mosaic's tiling rules or its VMEM limits, so
 every kernel below is also compiled — not run — by the TPU compiler for a
 v5e chip that is described, not attached, at Llama-3.2-1B widths (d_model
-2048, d_ff 8192, 32 query / 8 KV heads of 64, page 16). A block shape the
-chip refuses fails here at no chip time.
+2048, d_ff 8192, 32 query / 8 KV heads of 64, page 16), and the grouped
+expert kernels at DeepSeek-V2-Lite's (64 experts of 2048 -> 1408 -> 2048).
+A block shape the chip refuses fails here at no chip time.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and pytest-xdist workers must
@@ -22,6 +23,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.quant.types import packed_rows
 from repro.kernels import autotune
 from repro.kernels.dequant_matmul import dequant_matmul_pallas
+from repro.kernels.grouped_expert_matmul import (grouped_expert_dequant_pallas,
+                                                 grouped_expert_w8a8_pallas)
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.w8a8_matmul import w8a8_matmul_pallas
 
@@ -68,6 +71,26 @@ def _paged_args(sharding, m, kv_bits, slots=8, pages=129, width=16):
              for s, d in shapes])
 
 
+def _grouped_args(sharding, kind, bits, rows, k, n, experts=64):
+    """A grouped expert call of `rows` (token, expert) pairs at the row
+    tile and weight blocks the serving path picks."""
+    bm = autotune.grouped_row_tile(rows, experts)
+    _, bn, bk = autotune.matmul_plan(kind, bm, k, n, bits=bits,
+                                     group_size=128)
+    n_tiles = -(-(rows + experts * (bm - 1)) // bm)
+    x_dtype = jnp.int8 if kind == "grouped_w8a8" else jnp.bfloat16
+    shapes = [((n_tiles * bm, k), x_dtype),
+              ((experts, packed_rows(k, bits), n), jnp.uint8),
+              ((experts, k // 128, n), jnp.float32),
+              ((n_tiles,), jnp.int32), ((1,), jnp.int32)]
+    fn = (grouped_expert_w8a8_pallas if kind == "grouped_w8a8"
+          else grouped_expert_dequant_pallas)
+    return (lambda *a: fn(*a, bits=bits, group_size=128, bm=bm, bn=bn,
+                          bk=bk, interpret=False),
+            [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+             for s, d in shapes])
+
+
 CASES = {
     "dequant-w4-perchannel-m8": ("matmul", ("dequant", 4, -1, 8, D, FF)),
     "dequant-w4-g128-m8": ("matmul", ("dequant", 4, 128, 8, FF, D)),
@@ -81,6 +104,20 @@ CASES = {
                                       ("dequant", 4, 128, 128, 4096, 13696)),
     "dequant-w3-g128-m64-granite-up": ("matmul",
                                        ("dequant", 3, 128, 64, 6144, 24576)),
+    # DeepSeek-V2-Lite's latent projection (N = 576 = 4.5 lane tiles) in a
+    # 1024-token prefill: the table takes N whole
+    "dequant-w4-g128-m1024-dsv2-wdkv": ("matmul",
+                                        ("dequant", 4, 128, 1024, 2048, 576)),
+    # DeepSeek-V2-Lite's routed experts: 64 decode slots at top-6 (16-row
+    # tiles), and a 1024-token prefill (128-row tiles)
+    "grouped-w4-decode-gate": ("grouped", ("grouped_expert", 4, 384, 2048,
+                                           1408)),
+    "grouped-w4-decode-down": ("grouped", ("grouped_expert", 4, 384, 1408,
+                                           2048)),
+    "grouped-w3-prefill-gate": ("grouped", ("grouped_expert", 3, 6144, 2048,
+                                            1408)),
+    "grouped-w8a8-w4-decode-down": ("grouped", ("grouped_w8a8", 4, 384,
+                                                1408, 2048)),
     "paged-decode-bf16": ("paged", (1, 16)),
     "paged-verify-int8": ("paged", (5, 8)),
     "paged-prefill-m16-bf16": ("paged", (16, 16)),
@@ -91,15 +128,18 @@ CASES = {
 # profiler trace names the kernel's op events after it
 KERNEL_NAMES = {"dequant": "dequant_matmul_pallas",
                 "w8a8": "w8a8_matmul_pallas",
+                "grouped_expert": "grouped_expert_dequant_pallas",
+                "grouped_w8a8": "grouped_expert_w8a8_pallas",
                 "paged": "paged_attention_pallas"}
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_main_path_kernel_compiles_for_v5e(one_chip, case):
     kind, params = CASES[case]
-    build = _matmul_args if kind == "matmul" else _paged_args
+    build = {"matmul": _matmul_args, "grouped": _grouped_args,
+             "paged": _paged_args}[kind]
     fn, args = build(one_chip, *params)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
-    name = KERNEL_NAMES[params[0] if kind == "matmul" else kind]
+    name = KERNEL_NAMES[kind if kind == "paged" else params[0]]
     assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\(", text), name

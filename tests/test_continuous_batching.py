@@ -114,26 +114,27 @@ def test_eos_retires_slot_early(tiny_lm):
 
 
 def test_moe_pad_tokens_do_not_shift_routing():
-    """Left-pad junk must not consume expert capacity or displace real
-    tokens' dispatch slots (same capacity => identical real-row outputs)."""
+    """Left-pad junk routes to no expert and never changes real tokens'
+    results: dropless routing gives identical real-row outputs, and the
+    pads add no rows to the routing counts."""
     import jax.numpy as jnp
 
     from repro.models.config import MoEConfig
-    from repro.models.mlp_moe import apply_moe, init_moe, moe_capacity
+    from repro.models.mlp_moe import apply_moe, init_moe
 
     cfg = CFG.replace(moe=MoEConfig(n_experts=4, top_k=2, d_ff_expert=64,
                                     capacity_factor=1.0))
     p = init_moe(cfg, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 12, CFG.d_model)) * 0.3
-    assert moe_capacity(cfg, 12) == moe_capacity(cfg, 16)  # same cap bucket
-    y_ref, _ = apply_moe(cfg, p, x)
+    y_ref, aux_ref = apply_moe(cfg, p, x)
     pad = jax.random.normal(jax.random.PRNGKey(2), (1, 4, CFG.d_model)) * 5.0
     xp = jnp.concatenate([pad, x], axis=1)
     valid = jnp.concatenate([jnp.zeros((1, 4), bool),
                              jnp.ones((1, 12), bool)], axis=1)
-    y_pad, _ = apply_moe(cfg, p, xp, valid=valid)
+    y_pad, aux_pad = apply_moe(cfg, p, xp, valid=valid)
     np.testing.assert_array_equal(np.asarray(y_pad[:, 4:]),
                                   np.asarray(y_ref))
+    assert int(aux_pad["load"][0]) == int(aux_ref["load"][0]) == 12 * 2
 
 
 def test_token_equivalence_mla_and_hybrid():
@@ -177,9 +178,6 @@ def test_quantized_moe_token_equivalence():
     from repro.models.config import LayerSpec, MoEConfig
     from repro.utils.tree import tree_get
 
-    # capacity_factor=1.0 keeps moe_capacity in the same 8-bucket for the
-    # static (unpadded t) and bucketed-prefill (padded t) token counts —
-    # capacity is cross-token, so differing caps would legitimately diverge
     cfg = CFG.replace(
         pattern=(LayerSpec(kind="attn", mlp="moe"),),
         moe=MoEConfig(n_experts=4, top_k=2, d_ff_expert=64,

@@ -4,7 +4,8 @@ Every Pallas kernel is run in interpret mode and checked against two
 independent oracles per case: the pure-jnp reference in kernels/ref.py and
 a plain dequantize-then-einsum. The matrix sweeps bits x group_size x shape
 — including M=1 decode rows (skinny-M tile regime), ragged K/N, and
-expert-stacked weights — so new kernels and block-dispatch changes cannot
+grouped expert rows (uneven, empty and single-expert groups) — so new
+kernels and block-dispatch changes cannot
 silently diverge from the packed-format math.
 
 Runs identically under REPRO_DEQUANT_IMPL=pallas (CI's interpret-mode
@@ -35,8 +36,9 @@ GROUPS = [-1, 32, 64, 128]
 # d_ff = 128 * 107, whose row-tile plan takes the whole 896-deep K block
 # in four 512-wide N blocks
 DENSE_SHAPES = [(1, 128, 64), (3, 256, 80), (8, 128, 192), (64, 896, 2048)]
-# (E, C, K, N): C=5 forces capacity-dim padding inside the wrapper
-EXPERT_SHAPES = [(2, 5, 128, 64), (3, 8, 256, 96)]
+# grouped expert cases: (rows each expert receives, K, N) — uneven groups
+# with an empty expert, and every row on one expert of four
+GROUPED_CASES = [((5, 0, 19), 128, 64), ((0, 0, 23, 0), 256, 96)]
 W8A8_SHAPES = [(1, 128, 96), (7, 256, 64)]
 
 
@@ -69,25 +71,57 @@ def test_dense_parity(bits, gs, mkn):
                                rtol=2e-2, atol=2e-2)
 
 
+def _grouped_case(counts, k, *salts):
+    """Rows of a grouped expert call: pairs routed to expert e as often as
+    counts[e] (shuffled), laid out by the MoE's own `expert_groups` in
+    16-row tiles. Returns (x rows, groups, expert of each real row's
+    pair, its row)."""
+    from repro.models.mlp_moe import expert_groups
+
+    e = len(counts)
+    flat_e = np.repeat(np.arange(e), counts)
+    flat_e = np.random.default_rng(sum(salts)).permutation(flat_e)
+    groups, dest, got = expert_groups(jnp.asarray(flat_e, jnp.int32), e, 16)
+    assert np.asarray(got).tolist() == list(counts)
+    kx, _ = _key(*salts)
+    src = jax.random.normal(kx, (len(flat_e), k), jnp.float32)
+    x = jnp.zeros((groups.tile_expert.shape[0] * 16, k)).at[dest].set(src)
+    return x, groups, flat_e, np.asarray(dest)
+
+
+def _per_row(x, w, flat_e, dest):
+    """Each real row through its own expert's dequantized weight."""
+    xr = x[dest].astype(jnp.bfloat16)
+    return jnp.einsum("pk,pkn->pn", xr, w[flat_e].astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+
+
 @pytest.mark.parametrize("bits", BITS)
 @pytest.mark.parametrize("gs", GROUPS)
-@pytest.mark.parametrize("eckn", EXPERT_SHAPES)
-def test_expert_parity(bits, gs, eckn):
-    e, c, k, n = eckn
-    kx, kw = _key(bits, gs, e, c, k, n)
-    x = jax.random.normal(kx, (e, c, k), jnp.float32)
-    w = jax.random.normal(kw, (e, k, n), jnp.float32) * 0.1
+@pytest.mark.parametrize("case", GROUPED_CASES)
+def test_expert_parity(bits, gs, case):
+    """The grouped expert dequant kernel against its oracle, and each real
+    row against its own expert's dequantized weight; rows outside the
+    experts' groups, and every dead tile, come out zero."""
+    counts, k, n = case
+    x, groups, flat_e, dest = _grouped_case(counts, k, bits, gs, k, n)
+    _, kw = _key(bits, gs, k, n)
+    w = jax.random.normal(kw, (len(counts), k, n), jnp.float32) * 0.1
     qt = quantize_stacked(w, bits, gs)
-    y_pal = ops.expert_dequant_matmul(x, qt)           # pallas interpret
-    y_ref = ref.expert_dequant_matmul_ref(x, qt.qw, qt.scale, bits=bits,
-                                          group_size=gs, k=k)
-    y_ein = jnp.einsum("eck,ekn->ecn", x.astype(jnp.bfloat16),
-                       dequantize(qt, jnp.bfloat16),
-                       preferred_element_type=jnp.float32)
+    y_pal = ops.grouped_expert_matmul(x, qt, groups.tile_expert,
+                                      groups.n_live, bm=16,
+                                      out_dtype=jnp.float32)
+    y_ref = ref.grouped_expert_dequant_ref(
+        x, qt.qw, qt.scale, groups.tile_expert, groups.n_live, bits=bits,
+        group_size=gs, k=k, bm=16)
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ein),
+    y_row = _per_row(x, dequantize(qt, jnp.float32), flat_e, dest)
+    np.testing.assert_allclose(np.asarray(y_pal)[dest], np.asarray(y_row),
                                rtol=2e-2, atol=2e-2)
+    other = np.ones(y_pal.shape[0], bool)
+    other[dest] = False
+    assert not np.asarray(y_pal)[other].any()
 
 
 @pytest.mark.parametrize("bits", BITS)
@@ -114,25 +148,29 @@ def test_w8a8_parity(bits, gs, mkn):
 
 @pytest.mark.parametrize("bits", BITS)
 @pytest.mark.parametrize("gs", GROUPS)
-@pytest.mark.parametrize("eckn", EXPERT_SHAPES)
-def test_expert_w8a8_parity(bits, gs, eckn):
-    """The expert-stacked W4A8/W8A8 kernel (per-expert int8 x int8 -> int32
-    MXU dots) matches the vmapped int32 reference and tracks the
-    float-activation expert dequant matmul to A8 quantization noise."""
-    e, c, k, n = eckn
-    kx, kw = _key(bits, gs, e, c, k, n, 7)
-    x = jax.random.normal(kx, (e, c, k), jnp.float32)
-    w = jax.random.normal(kw, (e, k, n), jnp.float32) * 0.1
+@pytest.mark.parametrize("case", GROUPED_CASES)
+def test_expert_w8a8_parity(bits, gs, case):
+    """The grouped W4A8/W8A8 expert kernel (int8 x int8 -> int32 MXU dots
+    per row tile against its expert's slab) matches the per-tile int32
+    reference and tracks each row's float-activation expert matmul to A8
+    quantization noise."""
+    counts, k, n = case
+    x, groups, flat_e, dest = _grouped_case(counts, k, bits, gs, k, n, 7)
+    _, kw = _key(bits, gs, k, n, 7)
+    w = jax.random.normal(kw, (len(counts), k, n), jnp.float32) * 0.1
     qt = quantize_stacked(w, bits, gs, act_bits=8)
-    y_pal = ops.expert_w8a8_matmul(x, qt)              # pallas interpret
-    xq, xs = quantize_activation(x.reshape(e * c, k), 8)
-    y_ref = ref.expert_w8a8_matmul_ref(
-        xq.reshape(e, c, k), qt.qw, qt.scale, bits=bits, group_size=gs,
-        k=k) * xs.reshape(e, c, 1)
+    y_pal = ops.grouped_expert_w8a8_matmul(x, qt, groups.tile_expert,
+                                           groups.n_live, bm=16,
+                                           out_dtype=jnp.float32)
+    xq, xs = quantize_activation(x, 8)
+    y_ref = ref.grouped_expert_w8a8_ref(
+        xq, qt.qw, qt.scale, groups.tile_expert, groups.n_live, bits=bits,
+        group_size=gs, k=k, bm=16) * xs
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_ref),
                                rtol=1e-4, atol=1e-4)
-    y_f = jnp.einsum("eck,ekn->ecn", x, dequantize(qt, jnp.float32))
-    np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_f),
+    y_f = jnp.einsum("pk,pkn->pn", x[dest],
+                     dequantize(qt, jnp.float32)[flat_e])
+    np.testing.assert_allclose(np.asarray(y_pal)[dest], np.asarray(y_f),
                                rtol=5e-2, atol=5e-2 * float(jnp.max(jnp.abs(y_f))))
 
 
@@ -140,9 +178,10 @@ def test_expert_w8a8_parity(bits, gs, eckn):
 
 def test_skinny_decode_blocks_selected():
     """M <= 8 picks the decode tile regime: bm stays at the minimal 8-row
-    tile while bn/bk widen (no padding up to prefill tiles). The expert
-    and int8 kinds take it; the dense dequant kind takes its row-tile plan
-    instead, and reaches this regime only where no row-tile plan fits."""
+    tile while bn/bk widen (no padding up to prefill tiles). The int8
+    kind takes it; the dense dequant and grouped expert kinds take their
+    row-tile plan instead, and reach this regime only where no row-tile
+    plan fits."""
     assert ops._matmul_blocks(1, 128, 256, 256) == (8, 512, 512)
     assert ops._matmul_blocks(8, 128, 256, 256) == (8, 512, 512)
     assert ops._matmul_blocks(9, 128, 256, 256) == (128, 256, 256)
@@ -488,7 +527,7 @@ if _HAVE_HYPOTHESIS:
 
 def test_quantized_moe_forward_uses_expert_kernel(monkeypatch):
     """A quantized MoE block must route its expert matmuls through the
-    expert-batched kernel and never dequantize the full expert stack."""
+    grouped expert kernel and never dequantize the full expert stack."""
     from repro.configs import TINY
     from repro.models import linear as linear_mod
     from repro.models.config import MoEConfig
@@ -503,13 +542,13 @@ def test_quantized_moe_forward_uses_expert_kernel(monkeypatch):
             p["experts"][name]["w"], 4, 32)
 
     calls = []
-    real = ops.expert_dequant_matmul
+    real = ops.grouped_expert_matmul
 
     def spy(*a, **kw):
         calls.append(a[1].shape)
         return real(*a, **kw)
 
-    monkeypatch.setattr(ops, "expert_dequant_matmul", spy)
+    monkeypatch.setattr(ops, "grouped_expert_matmul", spy)
 
     def no_dequant(*a, **kw):
         raise AssertionError("quantized expert stack was dequantized")

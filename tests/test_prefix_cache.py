@@ -194,15 +194,27 @@ def test_chunked_prefill_interleaves_decode(tiny_lm):
 
 
 def test_chunked_rejects_unsupported_archs(tiny_lm):
+    """SSM state is not page-addressed, so an SSM hybrid refuses prefix
+    sharing and chunked prefill. Latent attention (MLA) serves both: its
+    later chunks and shared-prefix suffixes attend to the latent context
+    read back through the block table, and decode the same greedy tokens
+    as monolithic, unshared prefill."""
     from repro.configs import get_smoke_config
 
-    for arch in ("deepseek-v2-lite-16b", "jamba-1.5-large-398b"):
-        cfg = get_smoke_config(arch)
-        params = init_lm(cfg, jax.random.PRNGKey(1))
-        for kw in (dict(prefix_share=True), dict(chunked_prefill=16)):
-            with pytest.raises(NotImplementedError):
-                ContinuousEngine(cfg, params, n_slots=2, max_len=64,
-                                 page_size=8, **kw)
+    cfg = get_smoke_config("jamba-1.5-large-398b")
+    params = init_lm(cfg, jax.random.PRNGKey(1))
+    for kw in (dict(prefix_share=True), dict(chunked_prefill=16)):
+        with pytest.raises(NotImplementedError):
+            ContinuousEngine(cfg, params, n_slots=2, max_len=64,
+                             page_size=8, **kw)
+    cfg = get_smoke_config("deepseek-v2-lite-16b")
+    params = init_lm(cfg, jax.random.PRNGKey(1))
+    reqs = _shared_prefix_requests(np.random.default_rng(4))
+    _, base = _run(cfg, params, reqs)
+    for kw in (dict(prefix_share=True), dict(chunked_prefill=16)):
+        eng, got = _run(cfg, params, reqs, **kw)
+        assert got == base, kw
+    assert eng.n_prefills > len(reqs)              # prompts went in chunks
 
 
 # --------------------------------------------------------- pool invariants

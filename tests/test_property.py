@@ -82,20 +82,28 @@ def test_ring_cache_holds_last_window_positions(window, n):
 def test_property_packed_grid_survives_expert_kernel_exactly(bits, e, k, n,
                                                              seed):
     """Random int2/int4 grids round-trip quantize_stacked -> the Pallas
-    expert dequant kernel bit-exactly: with every column's amax pinned to
-    qmax the scale is exactly 1.0, so pack/unpack, the bf16 cast (integers
-    <= 127 are exact), and the one-hot identity matmul add no error."""
+    grouped expert dequant kernel bit-exactly: with every column's amax
+    pinned to qmax the scale is exactly 1.0, so pack/unpack, the bf16 cast
+    (integers <= 127 are exact), and the one-hot identity rows (k of them
+    per expert, one 16-row tile each) add no error."""
     qmax = qmax_for_bits(bits)
     rng = np.random.default_rng(seed)
     q = rng.integers(-qmax, qmax + 1, size=(e, k, n))
     q[:, 0, :] = qmax                                  # pin scale to 1.0
     w = jnp.asarray(q, jnp.float32)
     qt = quantize_stacked(w, bits, -1)
-    eye = jnp.broadcast_to(jnp.eye(k, dtype=jnp.float32), (e, k, k))
-
     from repro.kernels import ops
-    deq = ops.expert_dequant_matmul(eye, qt, out_dtype=jnp.float32)
-    assert np.array_equal(np.asarray(deq), np.asarray(w))
+    from repro.models.mlp_moe import expert_groups
+
+    # expert i's group: k identity rows, padded to whole 16-row tiles
+    groups, dest, _ = expert_groups(jnp.repeat(jnp.arange(e), k), e, 16)
+    rows = jnp.zeros((groups.tile_expert.shape[0] * 16, k)).at[dest].set(
+        jnp.tile(jnp.eye(k, dtype=jnp.float32), (e, 1)))
+    deq = ops.grouped_expert_matmul(rows, qt, groups.tile_expert,
+                                    groups.n_live, bm=16,
+                                    out_dtype=jnp.float32)
+    assert np.array_equal(np.asarray(deq)[np.asarray(dest)].reshape(e, k, n),
+                          np.asarray(w))
     # the jnp unpack path agrees bit-exactly too
     assert np.array_equal(np.asarray(dequantize(qt)), np.asarray(w))
 
