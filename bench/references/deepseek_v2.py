@@ -15,10 +15,10 @@ Nothing here imports the program under test. Each block is pre-RMSNorm:
     mscale(f, mscale_all_dim), with mscale(s, m) = 0.1 m ln s + 1;
   * the first `first_k_dense_replace` blocks end in a SwiGLU MLP; the
     others in a mixture of experts: softmax router, greedy top-k, the
-    chosen probabilities as gates (renormalised only if `norm_topk_prob`)
-    times `routed_scaling_factor`, every routed expert a SwiGLU of width
-    `moe_intermediate_size`, plus `n_shared_experts` shared experts as
-    one SwiGLU of n times that width. The routed experts are computed
+    chosen probabilities as gates (renormalised only if `norm_topk_prob`;
+    a `routed_scaling_factor` other than 1 is refused), every routed
+    expert a SwiGLU of width `moe_intermediate_size`, plus
+    `n_shared_experts` shared experts as one SwiGLU of n times that width. The routed experts are computed
     plainly and dropless: every expert on every token, weighted by a gate
     that is 0 where the token did not choose it.
 
@@ -65,7 +65,6 @@ class Arch:
     expert_ff: int
     shared: int
     norm_topk: bool
-    routed_scale: float
     vocab: int
     eps: float
     theta: float
@@ -78,6 +77,9 @@ class Arch:
 def arch(c: dict) -> Arch:
     if c.get("q_lora_rank"):
         raise ValueError("this reference computes MLA without q-LoRA")
+    if c["routed_scaling_factor"] != 1:
+        raise ValueError("this reference scales the routed experts' sum "
+                         "by 1 only")
     if (c["scoring_func"], c["topk_method"], c["hidden_act"]) != (
             "softmax", "greedy", "silu"):
         raise ValueError("the reference computes a softmax router, greedy "
@@ -99,7 +101,6 @@ def arch(c: dict) -> Arch:
         ff=c["intermediate_size"], experts=c["n_routed_experts"],
         top_k=c["num_experts_per_tok"], expert_ff=c["moe_intermediate_size"],
         shared=c["n_shared_experts"], norm_topk=bool(c["norm_topk_prob"]),
-        routed_scale=float(c["routed_scaling_factor"]),
         vocab=c["vocab_size"], eps=c["rms_norm_eps"],
         theta=float(c["rope_theta"]), yarn=yarn,
         bits=c["quant"]["bits"], group_size=c["quant"]["group_size"])
@@ -129,10 +130,8 @@ def check_widths(a: Arch, cfg) -> None:
     want["mla"] = (a.kv_lora, 0, a.nope, a.rope, a.v)
     if moe is not None:
         got["moe"] = (moe.n_experts, moe.top_k, moe.n_shared,
-                      moe.d_ff_expert, moe.norm_topk_prob,
-                      moe.routed_scaling_factor)
-    want["moe"] = (a.experts, a.top_k, a.shared, a.expert_ff, a.norm_topk,
-                   a.routed_scale)
+                      moe.d_ff_expert, moe.norm_topk_prob)
+    want["moe"] = (a.experts, a.top_k, a.shared, a.expert_ff, a.norm_topk)
     rs = cfg.rope_scaling
     got["yarn"] = () if rs is None else (
         rs.factor, rs.original_max_position_embeddings, rs.beta_fast,
@@ -369,7 +368,6 @@ def _gates(a: Arch, w: dict, h):
     top, idx = jax.lax.top_k(probs, a.top_k)
     if a.norm_topk:
         top = top / jnp.sum(top, -1, keepdims=True)
-    top = top * a.routed_scale
     rows = jnp.arange(h.shape[0])[:, None]
     return jnp.zeros_like(probs).at[rows, idx].set(top)
 

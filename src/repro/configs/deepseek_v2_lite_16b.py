@@ -35,7 +35,7 @@ CONFIG = ModelConfig(
     mla=MLAConfig(kv_lora_rank=512, q_lora_rank=0, qk_nope_head_dim=128,
                   qk_rope_head_dim=64, v_head_dim=128),
     moe=MoEConfig(n_experts=64, top_k=6, n_shared=2, d_ff_expert=1408,
-                  norm_topk_prob=False, routed_scaling_factor=1.0),
+                  norm_topk_prob=False),
     serve_quant_bits=4,
     fsdp=True,  # 16B: replicated fp32 params+Adam exceed v5e HBM (see §Perf)
     moe_impl="shard_map",  # explicit all-to-all EP dispatch (§Perf: -88% coll.)

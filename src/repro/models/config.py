@@ -37,8 +37,7 @@ class MoEConfig:
     # tokens past this capacity; every other path routes dropless
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
-    norm_topk_prob: bool = True         # renormalise the top-k gates
-    routed_scaling_factor: float = 1.0  # multiplies the routed experts' sum
+    norm_topk_prob: bool = True   # renormalise the top-k gates
 
 
 @dataclasses.dataclass(frozen=True)

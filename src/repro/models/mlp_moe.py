@@ -153,8 +153,6 @@ def apply_moe(cfg: ModelConfig, p: dict, x: jax.Array,
     if m.norm_topk_prob:
         gate = gate / jnp.maximum(jnp.sum(gate, axis=-1, keepdims=True),
                                   1e-9)
-    if m.routed_scaling_factor != 1.0:
-        gate = gate * m.routed_scaling_factor
 
     flat_e = idx.reshape(t * k)
     if valid is not None:

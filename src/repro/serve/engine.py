@@ -170,7 +170,10 @@ class ServeEngine:
 # (engine serial, the step's `now`, "prefill" | "decode", MoE layer calls,
 # rows routed, experts that got rows, the busiest expert's rows summed
 # over the calls). It outlives the engine, so a benchmark can read the
-# counts of a window of steps after it has freed the engine.
+# counts of a window of steps after it has freed the engine. It goes, with
+# `_ENGINE_SERIAL`, once the serving benchmark takes the window's deltas
+# of the `n_moe_*` counters at the window's edges, as it already does for
+# `n_decode_steps`: they hold the same counts.
 MOE_LOG: collections.deque = collections.deque(maxlen=1 << 16)
 _ENGINE_SERIAL = itertools.count()
 
@@ -198,17 +201,19 @@ def _paged_prefill_jit(cfg, params, tokens, cache, positions, paged):
 
 
 @functools.partial(jax.jit, static_argnames=("temperature", "top_k"))
-def _sample_first_jit(logits, keys, *, temperature, top_k):
-    """Per-request first-token sampling: logits (B, V), keys (B, 2).
+def _sample_first_jit(logits, first_key, rids, *, temperature, top_k):
+    """Per-request first-token sampling: logits (B, V), request ids (B,).
 
-    Each row draws from its own key (folded from the request id by the
-    engine), so the result does not depend on how admitted requests were
-    grouped into prefill batches — the same seed gives the same tokens at
-    prefill_batch=1 and prefill_batch=8. Also returns the per-row isfinite
-    sentinel so a prompt whose prefill produced non-finite logits is
-    quarantined before it ever enters the decode set."""
+    Each row draws from its own key, `first_key` folded with the row's
+    request id, so the result does not depend on how admitted requests
+    were grouped into prefill batches — the same seed gives the same
+    tokens at prefill_batch=1 and prefill_batch=8. Also returns the
+    per-row isfinite sentinel so a prompt whose prefill produced
+    non-finite logits is quarantined before it ever enters the decode
+    set."""
     note_trace("sample_first", batch=logits.shape[0],
                temperature=temperature, top_k=top_k)
+    keys = jax.vmap(lambda r: jax.random.fold_in(first_key, r))(rids)
     toks = jax.vmap(lambda l, k: sample(l[None], k, temperature=temperature,
                                         top_k=top_k)[0])(logits, keys)
     return toks, jnp.all(jnp.isfinite(logits), axis=-1)
@@ -548,6 +553,13 @@ class ContinuousEngine:
          mid-block overshoot onto the scratch page and the surplus tokens
          are dropped.
 
+    `precompile_prefill=True` compiles, at the first prefill of each
+    (bucket, has-context, width) key, the programs of every pow2 batch
+    size up to `prefill_batch`: requests warmed one at a time then warm
+    every co-batched shape, and no prefill compiles mid-serve once each
+    key has been seen (at up to log2(prefill_batch) + 1 times the prefill
+    compile time).
+
     `prefix_share=True` turns on the pool's prefix cache: a prompt whose
     full-page prefix was already prefilled by an earlier request reuses
     those pages by reference and prefills only the unshared suffix.
@@ -590,7 +602,7 @@ class ContinuousEngine:
                  max_len: int = 512, page_size: int = 16,
                  n_pages: Optional[int] = None, eos_id: int = -1,
                  prefill_bucket: int = 16, prefill_batch: int = 8,
-                 decode_block: int = 8,
+                 precompile_prefill: bool = False, decode_block: int = 8,
                  temperature: float = 0.0, top_k: int = 0, seed: int = 0,
                  quant_bits: int = 0, quant_group: int = 0,
                  act_bits: int = 0, paged_attn: Optional[str] = None,
@@ -722,6 +734,8 @@ class ContinuousEngine:
         # routing is dropless and per token (see DESIGN.md), so a request's
         # tokens do not depend on which requests share its prefill call
         self.prefill_batch = max(1, prefill_batch)
+        self.precompile_prefill = bool(precompile_prefill)
+        self._prefill_keys: set = set()   # (bucket, has_ctx, width) compiled
         self.decode_block = max(1, decode_block)
         self.temperature = temperature
         self.top_k = top_k
@@ -1487,6 +1501,10 @@ class ContinuousEngine:
                  else self.spec.max_pages)
         with spans.span(spans.PREFILL, rows=batch, bucket=padded,
                         ctx=int(has_ctx), width=width):
+            if (self.precompile_prefill
+                    and (padded, has_ctx, width) not in self._prefill_keys):
+                self._prefill_keys.add((padded, has_ctx, width))
+                self._compile_prefill(padded, has_ctx, width, skip=batch)
             toks = np.zeros((batch, padded), np.int32)
             pos = np.full((batch, padded), -1, np.int32)
             for row, (slot, req, start, end) in enumerate(items):
@@ -1529,16 +1547,17 @@ class ContinuousEngine:
                     finish.append(row)
             if not finish:
                 return
-            keys = jnp.stack([jax.random.fold_in(self._first_key,
-                                                 items[row][1].rid)
-                              for row in finish])
+            # every row is sampled, so the program's shape is the batch's;
+            # rows whose prompt goes on are dropped on the host
+            rids = np.asarray([req.rid for _, req, _, _ in items], np.int32)
             first, fin_ok = _sample_first_jit(
-                logits[jnp.asarray(finish)], keys,
+                logits, self._first_key, jnp.asarray(rids),
                 temperature=self.temperature, top_k=self.top_k)
         with spans.span(spans.PREFILL_WAIT,
                         rids=spans.ids(items[row][1].rid for row in finish)):
             first, fin_ok = self._fetch(first, fin_ok)
-        for tok, okf, row in zip(first, fin_ok, finish):
+        for row in finish:
+            tok, okf = first[row], fin_ok[row]
             slot, req, _, _ = items[row]
             del self._prefilling[slot]
             if not okf:
@@ -1553,6 +1572,45 @@ class ContinuousEngine:
                 # the slot (an immediate EOS/max_new=1 would unmap it)
                 self.pool.register_prefix(req.prompt, slot)
             self._emit(slot, req, int(tok), now)
+
+    def _compile_prefill(self, padded: int, has_ctx: bool, width: int,
+                         skip: int) -> None:
+        """Compile the prefill and first-token programs of every pow2 batch
+        size up to `prefill_batch` but `skip` (the call about to run) at
+        one (bucket, has-context, width) key, the first time a prefill
+        reaches that key. Rows co-batched later then compile nothing
+        mid-serve, and requests warmed one at a time warm every batch."""
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        b = 1
+        while b <= self.prefill_batch:
+            if b != skip:
+                paged = {"bt_rows": i32(b, width), "slots": i32(b)}
+                if has_ctx:
+                    paged["kv_len"] = i32(b)
+                args = (i32(b, padded), self.cache, i32(b, padded), paged)
+                if self.tp > 1:
+                    low = _paged_prefill_tp_jit.lower(
+                        self.cfg, self.mesh, self.params, *args)
+                else:
+                    low = _paged_prefill_jit.lower(self.cfg, self.params,
+                                                   *args)
+                    if self.spec_decode:
+                        _paged_prefill_jit.lower(
+                            self.cfg, self.draft_params, args[0],
+                            self.draft_cache, *args[2:]).compile()
+                comp = low.compile()
+                # a TP program's logits are committed to its mesh; a
+                # single-device program's stay uncommitted
+                out = low.out_info[0]
+                logits = jax.ShapeDtypeStruct(
+                    out.shape, out.dtype, sharding=(
+                        comp.output_shardings[0] if self.tp > 1 else None))
+                _sample_first_jit.lower(
+                    logits, self._first_key, i32(b),
+                    temperature=self.temperature, top_k=self.top_k).compile()
+            b *= 2
 
     def _decode_block(self, now: float) -> tuple[np.ndarray, np.ndarray]:
         """One fused block of decode steps; returns ((K, n_slots) tokens,

@@ -32,7 +32,6 @@ def _per_token(cfg, p, xf):
     gate, idx = _routing(cfg, p, xf)
     if m.norm_topk_prob:
         gate = gate / gate.sum(-1, keepdims=True)
-    gate = gate * m.routed_scaling_factor
     out = []
     for t in range(xf.shape[0]):
         y = jnp.zeros(xf.shape[1])
@@ -49,15 +48,14 @@ def _per_token(cfg, p, xf):
     return jnp.stack(out)
 
 
-@pytest.mark.parametrize("norm,scale,shared", [
-    (True, 1.0, 0),         # Mixtral: renormalised top-k gates
-    (False, 1.0, 2),        # DeepSeek-V2: softmax probabilities as gates
-    (False, 2.5, 1),        # a routed scaling factor
+@pytest.mark.parametrize("norm,shared", [
+    (True, 0),          # Mixtral: renormalised top-k gates
+    (False, 2),         # DeepSeek-V2: softmax probabilities as gates
+    (True, 1),          # renormalised gates beside a shared expert
 ])
-def test_dropless_moe_matches_a_per_token_loop(norm, scale, shared):
+def test_dropless_moe_matches_a_per_token_loop(norm, shared):
     cfg = CFG.replace(moe=MoEConfig(n_experts=8, top_k=3, n_shared=shared,
-                                    d_ff_expert=16, norm_topk_prob=norm,
-                                    routed_scaling_factor=scale))
+                                    d_ff_expert=16, norm_topk_prob=norm))
     p = init_moe(cfg, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 9, cfg.d_model))
     y, aux = apply_moe(cfg, p, x)
@@ -163,6 +161,44 @@ def test_co_batched_moe_prefill_is_token_identical(arch):
     eng1, want = _serve(cfg, params, prompts, prefill_batch=1)
     assert got == want
     assert eng4.n_prefills == 1 and eng1.n_prefills == 4
+
+
+@pytest.mark.parametrize("arch,tp", [
+    ("deepseek-v2-lite-16b", 1),
+    ("dense", 1),
+    pytest.param("dense", 2, marks=pytest.mark.skipif(
+        len(jax.devices()) < 2, reason="TP needs 2 local devices (run with "
+        "XLA_FLAGS=--xla_force_host_platform_device_count=2)")),
+])
+def test_prefill_alone_warms_every_co_batched_shape(arch, tp):
+    """With precompile_prefill, the first prefill at a bucket compiles the
+    bucket's pow2 batch sizes up to prefill_batch, so once one request
+    alone has prefilled in a bucket, four prompts co-batched in it
+    compile nothing, on one device or under TP."""
+    cfg = (TINY.replace(n_repeats=2, d_model=64, head_dim=16, d_ff=128)
+           if arch == "dense" else get_smoke_config(arch))
+    params = init_lm(cfg, jax.random.PRNGKey(2))
+    eng = ContinuousEngine(cfg, params, n_slots=4, max_len=64, page_size=8,
+                           prefill_bucket=8, prefill_batch=4,
+                           precompile_prefill=True, tp=tp)
+    rng = np.random.default_rng(7)
+    eng.submit(rng.integers(0, cfg.vocab_size, 12), max_new=1)
+    eng.run(max_steps=100)
+    compiles, on = [], [True]
+
+    def listen(name, secs, **_):
+        if on[0] and name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for n in (9, 10, 14, 16):
+            eng.submit(rng.integers(0, cfg.vocab_size, n), max_new=1)
+        done = eng.run(max_steps=100)
+    finally:
+        on[0] = False
+    assert [len(r.tokens) for r in done] == [1] * 4
+    assert eng.n_prefills == 2 and compiles == []
 
 
 def test_engine_moe_counters_count_the_routed_rows(monkeypatch):
